@@ -46,9 +46,11 @@ _ALLTOALL = frozenset({"all_to_all"})
 _SCATTER = frozenset({"reduce_scatter"})
 COLLECTIVE_PRIMS = _REDUCTIONS | _GATHERS | _PERMUTES | _ALLTOALL | _SCATTER
 
+# `debug_print` is what jax.debug.print lowers to on the installed JAX
+# (it was a `debug_callback` before).
 _CALLBACK_PRIMS = frozenset({
-    "io_callback", "debug_callback", "pure_callback", "callback",
-    "outside_call", "host_callback_call"})
+    "io_callback", "debug_callback", "debug_print", "pure_callback",
+    "callback", "outside_call", "host_callback_call"})
 
 # Passes 5-7 (graft-flow, ISSUE 9) live in analysis/flow.py on the
 # dependence-graph layer and passes 8-10 (graft-sound, ISSUE 20) in

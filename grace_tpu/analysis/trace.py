@@ -49,9 +49,7 @@ _DEFAULT_PARAMS = (("w", (60, 8)), ("b", (32,)))
 
 
 def abstract_mesh(world: int, axis_name: str = DEFAULT_AXIS):
-    """An ``AbstractMesh`` across JAX versions (0.4.37 takes one
-    ``((name, size), ...)`` tuple; newer releases take separate shape and
-    axis-name tuples)."""
+    """A 1-D ``AbstractMesh`` of ``world`` ranks."""
     return abstract_mesh_nd(((axis_name, world),))
 
 
@@ -60,12 +58,8 @@ def abstract_mesh_nd(axes: Sequence[Tuple[str, int]]):
     dp×fsdp audit meshes trace through this."""
     from jax.sharding import AbstractMesh
 
-    axes = tuple((str(n), int(s)) for n, s in axes)
-    try:
-        return AbstractMesh(axes)
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(s for _, s in axes),
-                            tuple(n for n, _ in axes))
+    return AbstractMesh(tuple(int(s) for _, s in axes),
+                        tuple(str(n) for n, _ in axes))
 
 
 def default_param_structs() -> Dict[str, jax.ShapeDtypeStruct]:

@@ -475,6 +475,9 @@ def _shard_compress(compressor: Compressor, chunks: jax.Array,
     treedef, static)`` with payloads and ctx arrays stacked along the
     shard axis."""
     w = chunks.shape[0]
+    refuse = getattr(compressor, "refuse_per_shard_compress", None)
+    if refuse is not None:
+        refuse(comm_name)
 
     def enc(chunk, key):
         if shared is None:

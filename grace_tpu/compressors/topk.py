@@ -89,6 +89,38 @@ class TopKCompressor(Compressor):
             return True, jax.default_backend() != "tpu"
         return False, False            # 'auto' == staged (measured faster)
 
+    def _staged(self, interpret: bool, why: str):
+        """A fused path cannot take this buffer: go staged (return None) —
+        unless the config DEMANDED the kernel (``use_pallas=True``) and
+        this is a TPU, where the compiled kernel is what was asked for;
+        then say which shape refused instead of silently timing the
+        staged path under the kernel's name. Off-TPU ``True`` means
+        interpret mode, a test vehicle, and keeps the quiet fallback."""
+        if self.use_pallas is True and not interpret:
+            raise ValueError(
+                f"TopKCompressor(use_pallas=True): the fused chunk kernel "
+                f"cannot run here — {why}. Use use_pallas='auto' to let the "
+                "staged path take such buffers.")
+        return None
+
+    def refuse_per_shard_compress(self, comm_name: str) -> None:
+        """Hook of ``comm._shard_compress``. The shard-parallel
+        communicators (two-shot, ring, rscatter, hier) encode each shard
+        with plain :meth:`compress`; the fused chunk kernels exist only on
+        the whole-buffer step path (:meth:`fused_feedback_compress`,
+        :meth:`fused_aggregate_decompress`). A kernel DEMANDED on a TPU
+        cannot be honoured there, and is refused rather than quietly
+        replaced by the staged select."""
+        enabled, interpret = self._pallas_mode()
+        if self.use_pallas is True and enabled and not interpret:
+            raise TypeError(
+                f"TopKCompressor(use_pallas=True) under {comm_name}: the "
+                "fused chunk Top-K kernel runs only on the whole-buffer "
+                "step path (allgather/broadcast/identity); shard-parallel "
+                "communicators compress per shard through the staged "
+                "select. Use use_pallas='auto' (staged) with this "
+                "communicator, or Allgather with the kernel.")
+
     def _fused_chunk_gate(self, numel: int, dtype, world):
         """Shared guard for both fused fast paths. Returns (k, interpret)
         or None when the staged path must run: non-chunk algorithm, Pallas
@@ -98,20 +130,25 @@ class TopKCompressor(Compressor):
         (interpreter Pallas deadlocks inside a multi-device shard_map
         program on CPU — observed: one 8-device step hangs >7 min where
         the 1-device step takes milliseconds; the compiled TPU kernel has
-        no such restriction). ``world`` is a zero-arg thunk so the check
-        works outside shard_map too."""
+        no such restriction, and on a TPU ``interpret`` is False so the
+        guard cannot fire there). The shape/dtype refusals raise instead
+        when the kernel was demanded on a TPU (:meth:`_staged`).
+        ``world`` is a zero-arg thunk so the check works outside shard_map
+        too."""
         if self.algorithm != "chunk":
             return None
         enabled, interpret = self._pallas_mode()
         if not enabled:
             return None
         if dtype != jnp.float32:
-            return None
+            return self._staged(interpret, f"data dtype {dtype} is not "
+                                "float32")
         if interpret and world() > 1:
             return None
         k = static_k(numel, self.compress_ratio)
         if numel < 2 * k:
-            return None
+            return self._staged(interpret, f"{numel} elements at k={k} "
+                                "leave fewer than two rows per chunk")
         return k, interpret
 
     def fused_feedback_compress(self, x: jax.Array, state, coeffs,
@@ -126,15 +163,20 @@ class TopKCompressor(Compressor):
         budget check for the row count).
         """
         gate = self._fused_chunk_gate(x.size, x.dtype, world)
-        if gate is None or (state is not None
-                            and state.dtype != jnp.float32):
+        if gate is None:
             return None
         k, interpret = gate
+        if state is not None and state.dtype != jnp.float32:
+            return self._staged(interpret, f"residual dtype {state.dtype} "
+                                "is not float32")
         shape, numel = x.shape, x.size
         from grace_tpu.ops.pallas_topk import (chunk_compress_feedback,
                                                compress_block_cols)
         if compress_block_cols(numel // k) <= 0:
-            return None                     # tiny ratio => too many rows
+            # tiny ratio => too many rows for one VMEM block
+            return self._staged(
+                interpret, f"shape {shape}: {numel // k} rows per chunk "
+                f"(k={k}) do not fit the VMEM block budget")
         beta, gamma = coeffs
         resid = None if state is None else state.reshape(-1)
         values, win_row, new_resid = chunk_compress_feedback(
@@ -214,11 +256,17 @@ class TopKCompressor(Compressor):
         k, interpret = gate
         values, indices = gathered
         if values.shape != (world, k):
-            return None              # sub-k payloads lose chunk structure
+            # sub-k payloads lose chunk structure
+            return self._staged(
+                interpret, f"gathered payload {values.shape} is not "
+                f"(world={world}, k={k})")
         from grace_tpu.ops.pallas_topk import (aggregate_block_cols,
                                                chunk_aggregate_dense)
         if aggregate_block_cols(numel // k, world) <= 0:
-            return None              # pod-scale W inflates the input blocks
+            # pod-scale W inflates the input blocks
+            return self._staged(
+                interpret, f"shape {shape}: {numel // k} rows x "
+                f"world={world} do not fit the VMEM block budget")
         win = (indices // k).astype(jnp.int32)
         out = chunk_aggregate_dense(values.astype(jnp.float32), win, k,
                                     numel, average=self.average,

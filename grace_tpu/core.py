@@ -382,15 +382,8 @@ SINGLE_SLICE = Topology()
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis, across JAX versions.
-
-    ``lax.axis_size`` only exists on newer JAX; on older releases (e.g.
-    0.4.37) ``lax.psum(1, axis)`` of a Python int constant-folds to a static
-    int at trace time, which is exactly the same value.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a bound mesh axis (:func:`jax.lax.axis_size`)."""
+    return lax.axis_size(axis_name)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -18,13 +18,10 @@ def _env_true(name: str) -> bool:
 
 def pallas_disabled(explicit: bool = False, kernel: str = "") -> bool:
     """Operational escape hatch: GRACE_DISABLE_PALLAS forces every Pallas
-    kernel off (set by tools/tpu_watch.sh when the on-chip smoke test
-    fails) so a Mosaic compile failure cannot take down a whole run.
+    kernel off. Nothing in the repository sets it (chip_smoke.py refuses
+    to start under it; ROADMAP D4 decides its fate).
     ``kernel`` scopes the check: GRACE_DISABLE_PALLAS_<KERNEL> (e.g.
-    ``_QUANT``, ``_TOPK``) disables only that kernel family, so one
-    failing Mosaic compile does not force unrelated kernels onto their
-    staged paths (the round-4 smoke failure in the quant kernel disabled
-    the headline Top-K kernels too). Warns when it defeats an explicit
+    ``_QUANT``, ``_TOPK``) disables only that kernel family. Warns when it defeats an explicit
     ``use_pallas=True`` — a forgotten export would otherwise turn the
     kernel equivalence tests into vacuous staged-vs-staged comparisons.
     Conventional false spellings ('', '0', 'false', 'no', 'off') mean NOT
@@ -46,6 +43,10 @@ def pallas_disabled(explicit: bool = False, kernel: str = "") -> bool:
 def pallas_mode(use_pallas, kernel: str = "quant"):
     """The ONE fused-kernel selection rule: ``(enabled, interpret)`` for a
     ``use_pallas`` knob (True / False / 'auto') and a kernel family.
+    ``True`` on a TPU is the compiled kernel; ``True`` elsewhere is
+    interpret mode (a test vehicle); ``'auto'`` is the kernel on a TPU and
+    the staged XLA path elsewhere. Which one ran is read from the compiled
+    program (``tpu_custom_call``), as chip_smoke.py does.
 
     Every fused-kernel call site — the encode kernels
     (:mod:`grace_tpu.ops.pallas_quant`, family ``"quant"``) AND the

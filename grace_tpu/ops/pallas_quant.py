@@ -42,13 +42,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret_mode(interpret: bool):
-    """pallas_call interpret= across JAX versions: newer Pallas wants a
-    pltpu.InterpretParams() instance, older (e.g. 0.4.37) a plain bool."""
-    if not interpret:
-        return False
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams()
-    return True
+    """The ``interpret=`` argument of ``pallas_call``: the TPU interpreter's
+    parameter object, or False for a Mosaic compile."""
+    return pltpu.InterpretParams() if interpret else False
 
 
 LANES = 256          # last-dim tile (2 × 128 lanes)
@@ -99,8 +95,8 @@ def _signed_levels(x, scale, block_seed, hw_prng: bool):
 
 def _make_quantize_kernel(hw_prng: bool):
     def kernel(seed_ref, scale_ref, x_ref, out_ref):
-        block_seed = seed_ref[0] + pl.program_id(0)
-        signed = _signed_levels(x_ref[:], scale_ref[0], block_seed, hw_prng)
+        block_seed = seed_ref[0, 0] + pl.program_id(0)
+        signed = _signed_levels(x_ref[:], scale_ref[0, 0], block_seed, hw_prng)
         out_ref[:] = signed.astype(out_ref.dtype)
 
     return kernel
@@ -138,7 +134,7 @@ def quantize_stochastic(flat: jax.Array, norm: jax.Array, seed: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
         interpret=_interpret_mode(interpret),
-    )(seed.reshape(1).astype(jnp.int32), scale.reshape(1), x2d)
+    )(seed.reshape(1, 1).astype(jnp.int32), scale.reshape(1, 1), x2d)
     return out.reshape(-1)[:n]
 
 
@@ -224,14 +220,14 @@ def _pack_lanes3(codes, packw_ref):
 
 def _make_quantize_pack_kernel(hw_prng: bool, width: int):
     def kernel(seed_ref, scale_ref, q_ref, packw_ref, x_ref, out_ref):
-        block_seed = seed_ref[0] + pl.program_id(0)
-        signed = _signed_levels(x_ref[:], scale_ref[0], block_seed, hw_prng)
+        block_seed = seed_ref[0, 0] + pl.program_id(0)
+        signed = _signed_levels(x_ref[:], scale_ref[0, 0], block_seed, hw_prng)
         # Two's-complement field: clamp to ±quantum_num (stochastic
         # overshoot past +q would not fit the field's 2^(width-1)-1
         # ceiling), then fold negatives into the upper half of the code
         # range. First element lands in the lowest bits — the
         # packing.pack_{2,3,4}bit layouts.
-        q = q_ref[0].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)
         signed = jnp.clip(signed, -q, q)
         codes = signed + float(1 << width) * (signed < 0).astype(jnp.float32)
         if width == 3:
@@ -302,8 +298,8 @@ def quantize_pack_stochastic(flat: jax.Array, norm: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, out_lanes), jnp.uint8),
         interpret=_interpret_mode(interpret),
-    )(seed.reshape(1).astype(jnp.int32), scale.reshape(1),
-      jnp.asarray(quantum_num, jnp.int32).reshape(1), packw, x2d)
+    )(seed.reshape(1, 1).astype(jnp.int32), scale.reshape(1, 1),
+      jnp.asarray(quantum_num, jnp.int32).reshape(1, 1), packw, x2d)
     return out.reshape(-1)[: -(-n * width // 8)]
 
 

@@ -44,15 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_mode(interpret: bool):
-    """pallas_call interpret= across JAX versions: newer Pallas wants a
-    pltpu.InterpretParams() instance, older (e.g. 0.4.37) a plain bool."""
-    if not interpret:
-        return False
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams()
-    return True
+from grace_tpu.ops.pallas_quant import _interpret_mode
 
 # Per-block VMEM budget across ALL of a kernel's f32 block buffers (Mosaic
 # pads each buffer's sublane count to 8 and double-buffers; the 4 MiB

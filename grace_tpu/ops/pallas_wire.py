@@ -133,7 +133,7 @@ def _make_decode_accum_kernel(width: int, k_payloads: int, sign: bool,
                 val = code * 2.0 - 1.0
             else:
                 level = code - mask * (code >= half).astype(jnp.float32)
-                val = scale_ref[k] * level
+                val = scale_ref[0, k] * level
             acc = val if acc is None else acc + val
         if vote:
             acc = (acc >= 0).astype(jnp.float32) * 2.0 - 1.0
@@ -221,7 +221,7 @@ def decode_accumulate(stacked: jax.Array, scales: jax.Array, numel: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=_interpret_mode(interpret),
-    )(scales.reshape(-1).astype(jnp.float32), dec, c3, x3d)
+    )(scales.reshape(1, -1).astype(jnp.float32), dec, c3, x3d)
     return out.reshape(-1)[:numel]
 
 

@@ -33,39 +33,17 @@ __all__ = ["DEFAULT_AXIS", "data_parallel_mesh", "make_mesh",
 
 
 def set_cpu_device_count(n: int) -> None:
-    """Request ``n`` simulated XLA:CPU host devices, across JAX versions.
-
-    Newer JAX spells this ``jax.config.update('jax_num_cpu_devices', n)``;
-    older releases (e.g. 0.4.37) only honor the
-    ``--xla_force_host_platform_device_count`` XLA flag. Either way it must
-    run before the CPU backend initializes (before the first
-    ``jax.devices()``/array creation) — importing jax earlier is fine.
-    """
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n}").strip()
+    """Request ``n`` virtual XLA:CPU devices. Must run before the CPU
+    backend initializes (before the first ``jax.devices()``/array
+    creation) — importing jax earlier is fine."""
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across JAX versions.
-
-    JAX promoted shard_map to the top-level namespace (with the replication
-    check renamed ``check_vma``) after 0.4.x; on older releases (e.g. the
-    0.4.37 this image ships) the only spelling is
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``. Every
-    shard_map in grace-tpu goes through this wrapper so the rest of the
-    codebase can use the modern keyword unconditionally.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """:func:`jax.shard_map` with the replication check off by default —
+    the one spelling every shard_map in grace-tpu goes through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def relax_cpu_collective_timeouts(warn_s: int = 300,
@@ -73,7 +51,7 @@ def relax_cpu_collective_timeouts(warn_s: int = 300,
     """Raise XLA:CPU's in-process collective rendezvous timeouts.
 
     The simulated N-device CPU mesh runs each "device" as a host thread; on
-    a host with few cores (this dev image has ONE) a heavy step can keep
+    a host with few cores a heavy step can keep
     half the device threads from reaching an all-reduce rendezvous within
     XLA's default 20s warn / 40s terminate window, which kills the process
     mid-collective (seen: LeNet/MNIST on the 8-device mesh). XLA reads
@@ -81,22 +59,6 @@ def relax_cpu_collective_timeouts(warn_s: int = 300,
     before the first `jax.devices()` — importing jax earlier is fine.
     No-op for flags the caller already set explicitly.
     """
-    import os
-
-    import jaxlib
-
-    try:
-        jaxlib_ver = tuple(int(p) for p in
-                           jaxlib.__version__.split(".")[:2])
-    except Exception:
-        jaxlib_ver = (0, 0)
-    if jaxlib_ver < (0, 5):
-        # XLA:CPU in jaxlib < 0.5 does not know these flags, and XLA
-        # hard-aborts the whole process on unknown XLA_FLAGS entries
-        # (parse_flags_from_env F-check) — worse than the stuck-collective
-        # warning the flags would relax. Skip on old runtimes.
-        return
-
     flags = os.environ.get("XLA_FLAGS", "")
     extra = []
     if "--xla_cpu_collective_call_warn_stuck_timeout_seconds" not in flags:

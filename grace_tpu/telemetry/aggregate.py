@@ -153,13 +153,10 @@ def watch_init(config: WatchConfig) -> WatchState:
 
 
 def _axis_size(axis_name: str) -> int:
-    """Static size of the bound mesh axis. A local copy of
-    ``grace_tpu.core.axis_size`` — this package must not import ``core``
-    (which imports :mod:`scopes`; see the package docstring): on old JAX
-    ``lax.psum(1, axis)`` of a Python int constant-folds to a static int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of the bound mesh axis. Not ``grace_tpu.core.axis_size``:
+    this package must not import ``core`` (which imports :mod:`scopes`;
+    see the package docstring)."""
+    return lax.axis_size(axis_name)
 
 
 def watch_gather_bytes(world: int) -> int:

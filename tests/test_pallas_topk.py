@@ -18,7 +18,8 @@ from grace_tpu.parallel import shard_map
 from grace_tpu.comm import Identity
 from grace_tpu.compressors import TopKCompressor
 from grace_tpu.memories import EFSignSGDMemory, ResidualMemory
-from grace_tpu.ops.pallas_topk import chunk_compress_feedback
+from grace_tpu.ops.pallas_topk import (chunk_compress_feedback,
+                                       compress_block_cols)
 
 
 def _step(compressor, memory, x, resid, rng):
@@ -200,3 +201,52 @@ def test_non_chunk_and_tiny_k_fall_back():
     off = TopKCompressor(compress_ratio=0.1, algorithm="chunk",
                          use_pallas=False)
     assert off.fused_feedback_compress(x, mem_state, (1.0, 1.0), rng) is None
+
+
+def test_demanded_kernel_raises_on_tpu_instead_of_going_staged(monkeypatch):
+    """use_pallas=True is a demand: on a TPU (compiled kernel, interpret
+    False) a buffer the kernel cannot take raises with the shape — it does
+    not silently time the staged path under the kernel's name. 'auto' may
+    still choose, and off-TPU True keeps the quiet interpret-mode
+    fallback (pinned above)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = jax.random.key(0)
+    demanded = TopKCompressor(compress_ratio=0.0001, algorithm="chunk",
+                              use_pallas=True)
+    x = jnp.ones((100_000,), jnp.float32)    # k=10: 10,000 rows per chunk
+    assert compress_block_cols(x.size // max(1, int(x.size * 0.0001))) == 0
+    with pytest.raises(ValueError, match="VMEM block budget"):
+        demanded.fused_feedback_compress(x, jnp.zeros_like(x), (1.0, 1.0),
+                                         rng)
+    with pytest.raises(ValueError, match="not float32"):
+        demanded.fused_feedback_compress(x.astype(jnp.bfloat16), None,
+                                         (1.0, 1.0), rng)
+    k = max(1, int(x.size * 0.0001))
+    with pytest.raises(ValueError, match=r"is not \(world=8"):
+        demanded.fused_aggregate_decompress(
+            (jnp.ones((8, k // 2)), jnp.zeros((8, k // 2), jnp.int32)),
+            (x.size, x.shape, jnp.float32), 8)
+    auto = TopKCompressor(compress_ratio=0.0001, algorithm="chunk")
+    assert auto.fused_feedback_compress(x, jnp.zeros_like(x), (1.0, 1.0),
+                                        rng) is None
+
+
+def test_demanded_kernel_refused_under_shard_parallel_communicators(
+        monkeypatch):
+    """The fused kernel lives on the whole-buffer step path only: ring,
+    two-shot, rscatter and hier compress per shard through the staged
+    select, so use_pallas=True on a TPU is a clear TypeError there (found
+    by PR 21's described-chip sweep: those pairs lowered with 0 kernels).
+    Off-TPU, and with 'auto', nothing changes."""
+    from grace_tpu.comm import _shard_compress
+
+    chunks = jnp.ones((4, 4096), jnp.float32)
+    demanded = TopKCompressor(compress_ratio=0.01, algorithm="chunk",
+                              use_pallas=True)
+    _shard_compress(demanded, chunks, jax.random.key(0), "RingAllreduce")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(TypeError, match="under RingAllreduce"):
+        _shard_compress(demanded, chunks, jax.random.key(0),
+                        "RingAllreduce")
+    auto = TopKCompressor(compress_ratio=0.01, algorithm="chunk")
+    _shard_compress(auto, chunks, jax.random.key(0), "RingAllreduce")

@@ -650,7 +650,10 @@ def test_chaos_smoke_fsdp_scenario(tmp_path):
     import chaos_smoke
 
     out = tmp_path / "fsdp_telemetry.jsonl"
-    rc = chaos_smoke.main(["--fsdp", "--steps", "60",
+    # nan_prob 0.05: at the default 0.01 a 60-step run has a 30% chance of
+    # drawing no implant at all, and which seeds do depends on the
+    # installed JAX's PRNG stream (seed 0 drew none on 0.9.0).
+    rc = chaos_smoke.main(["--fsdp", "--steps", "60", "--nan-prob", "0.05",
                            "--telemetry-out", str(out)])
     assert rc == 0
     rows = [json.loads(line) for line in out.read_text().splitlines()]

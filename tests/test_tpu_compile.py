@@ -1,0 +1,209 @@
+"""Mosaic compile tests: the kernels of the main path, and the exchange
+paths that call them, compiled for a TPU v5e that is described and not
+attached (``on-chip-measurement`` guide, section 2.3).
+
+Interpret mode accepts programs Mosaic refuses — a squeezed SMEM block, an
+unaligned slice, too much VMEM — so every CPU test of the kernels can pass
+while the chip's compiler rejects them. These compiles run the chip's own
+compiler (libtpu is installed; no chip is needed) at ResNet-50's flat
+gradient size, and pin the refusal PR 21 found: the per-shard compress of
+the ring family batched the quantize kernels' SMEM scalars into a shape
+Mosaic rejects, so QSGD × {ring, twoshot, hier, rscatter} could not be
+lowered for more than one chip.
+
+This is the only file that describes the chip. The topology is described
+inside a module-scoped fixture (never at import, in a ``skipif`` or in
+``parametrize`` arguments): the process that describes it holds libtpu's
+lock until it exits, so every xdist worker must collect the same tests and
+only the worker that runs this file may load the library. A compile that
+passes here is not a chip run and is never reported as one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import grace_tpu.ops
+from grace_tpu import grace_from_params
+from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
+                                        quantize_stochastic, sign_pack)
+from grace_tpu.ops.pallas_topk import (chunk_aggregate_dense,
+                                       chunk_compress_feedback)
+from grace_tpu.ops.pallas_wire import (decode_accumulate,
+                                       packed_int_accumulate)
+from grace_tpu.parallel import shard_map
+
+N = 25_557_032            # ResNet-50's flat gradient
+K = N // 100              # top-k 1 %
+# The two 3-bit kernels that WRITE packed bytes take XLA:TPU 15 s each at N
+# (the (rows, 96) uint8 output's flatten, not Mosaic); they compile at 64
+# kernel blocks instead. chip_smoke.py runs them at N on the chip itself.
+N_3BIT_OUT = 64 * 16384
+SHARD = 1 << 20           # the per-shard compress regression's buffer
+# The four-chip exchanges take a buffer of one kernel block per shard: the
+# staged unpack_4bit of the final decode (a (n/2, 2) -> (n,) uint8
+# relayout) takes XLA:TPU 25 s to compile at 256 Ki elements and 1 s here.
+EXCHANGE = 4 * 16384
+Q_FOR_WIDTH = {2: 1, 3: 3, 4: 7}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip; keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """Steer the ONE selection rule as a TPU would resolve it: the code
+    under test asks ``jax.default_backend()``, which still says cpu here."""
+    def as_on_tpu(use_pallas, kernel="quant"):
+        if grace_tpu.ops.pallas_disabled(explicit=use_pallas is True,
+                                         kernel=kernel):
+            return False, False
+        return use_pallas is True or use_pallas == "auto", False
+    monkeypatch.setattr(grace_tpu.ops, "pallas_mode", as_on_tpu)
+
+
+def compile_text(fn, *avals) -> str:
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def packed_bytes(width: int, n: int = N) -> int:
+    return -(-n * width // 8)
+
+
+# ---------------------------------------------------------------------------
+# every Pallas entry point at n = 25,557,032
+# ---------------------------------------------------------------------------
+
+def _kernel_cases():
+    f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    cases = {
+        "chunk_compress_feedback": (
+            lambda x, r: chunk_compress_feedback(x, r, K),
+            [((N,), f32), ((N,), f32)]),
+        "quantize_stochastic": (
+            lambda x, nrm, s: quantize_stochastic(x, nrm, s, 64),
+            [((N,), f32), ((), f32), ((), i32)]),
+        "sign_pack": (lambda x: sign_pack(x), [((N,), f32)]),
+        "decode_accumulate-sign": (
+            lambda p, s: decode_accumulate(p, s, N, 1, sign=True),
+            [((2, packed_bytes(1)), u8), ((2,), f32)]),
+        "decode_accumulate-vote": (
+            lambda p, s: decode_accumulate(p, s, N, 1, sign=True, vote=True),
+            [((3, packed_bytes(1)), u8), ((3,), f32)]),
+    }
+    for world in (1, 4):
+        cases[f"chunk_aggregate_dense-W{world}"] = (
+            lambda v, w: chunk_aggregate_dense(v, w, K, N),
+            [((world, K), f32), ((world, K), i32)])
+    for width, q in Q_FOR_WIDTH.items():
+        n = N_3BIT_OUT if width == 3 else N
+        cases[f"quantize_pack_stochastic-w{width}"] = (
+            lambda x, nrm, s, q=q, width=width: quantize_pack_stochastic(
+                x, nrm, s, q, width=width),
+            [((n,), f32), ((), f32), ((), i32)])
+        cases[f"decode_accumulate-w{width}"] = (
+            lambda p, s, width=width: decode_accumulate(p, s, N, width),
+            [((2, packed_bytes(width)), u8), ((2,), f32)])
+        cases[f"packed_int_accumulate-w{width}"] = (
+            lambda p, width=width, n=n: packed_int_accumulate(p, n, width),
+            [((2, packed_bytes(width, n)), u8)])
+    return cases
+
+
+KERNEL_CASES = _kernel_cases()
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CASES))
+def test_kernel_compiles_under_mosaic(one_chip, name):
+    fn, shapes = KERNEL_CASES[name]
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    assert "tpu_custom_call" in compile_text(fn, *avals), name
+
+
+# ---------------------------------------------------------------------------
+# the refusal found in PR 21: batched SMEM scalars in the per-shard compress
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("quantum_num", [64, 7], ids=["qsgd8", "qsgd4packed"])
+def test_per_shard_compress_lowers_with_kernels(one_chip, kernels_on,
+                                                quantum_num):
+    """The per-shard compress of a ring hop (comm._shard_compress: vmap of
+    ``compress`` over the W shard rows) over W=4 shards of a 1 Mi-element
+    buffer, for one described chip. On the parent commit Mosaic refuses the
+    vmapped kernel: its (1,)-shaped SMEM scalars become (4, 1) operands
+    with a squeezed block."""
+    from grace_tpu.comm import _shard_compress
+    from grace_tpu.compressors import QSGDCompressor
+
+    codec = QSGDCompressor(quantum_num=quantum_num)     # use_pallas='auto'
+
+    def per_shard(chunks):
+        payloads, ctx_arrays, _, _ = _shard_compress(
+            codec, chunks, jax.random.key(0), "ring")
+        return payloads, ctx_arrays
+
+    chunks = jax.ShapeDtypeStruct((4, SHARD // 4), jnp.float32,
+                                  sharding=one_chip)
+    assert "tpu_custom_call" in compile_text(per_shard, chunks)
+
+
+@pytest.mark.parametrize("communicator", [
+    {"communicator": "ring"},
+    {"communicator": "twoshot"},
+    {"communicator": "hier", "slice_size": 2},
+    {"communicator": "rscatter"},
+], ids=lambda p: p["communicator"])
+@pytest.mark.parametrize("quantum_num", [64, 7], ids=["qsgd8", "qsgd4packed"])
+def test_qsgd_auto_exchange_lowers_for_four_chips(topo, kernels_on,
+                                                  communicator, quantum_num):
+    """QSGD with its DEFAULT ``use_pallas='auto'`` through every
+    shard-compressing communicator, one program over the four described
+    chips: the kernels are in the compiled text and the collectives
+    partition."""
+    grace = grace_from_params({"compressor": "qsgd",
+                               "quantum_num": quantum_num,
+                               "memory": "none", **communicator})
+    comm, codec, memory = grace.communicator, grace.compressor, grace.memory
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+
+    def body(x):
+        x = x[0]
+        out, _, _ = comm.step(x, memory.init_state(x), codec.init_state(x),
+                              memory, codec, jax.random.key(0))
+        return out[None]
+
+    step = shard_map(body, mesh=mesh, in_specs=P("data"),
+                     out_specs=P("data"))
+    x = jax.ShapeDtypeStruct((4, EXCHANGE), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    text = compile_text(step, x)
+    assert "tpu_custom_call" in text
+    assert any(op in text for op in ("collective-permute", "all-to-all",
+                                     "all-gather", "all-reduce"))

@@ -226,3 +226,43 @@ def test_wire_report_powersgd_analytic():
     # w: (20+8)*4 floats; b rides dense: 8 floats
     assert rep.wire_bytes == ((20 + 8) * 4 + 8) * 4
     assert rep.dense_bytes == (20 * 8 + 8) * 4
+
+
+# ---------------------------------------------------------------------------
+# compile cache placed from outside (PR 21)
+# ---------------------------------------------------------------------------
+
+class TestCompileCachePlacement:
+    def _updates(self, monkeypatch):
+        import jax
+        seen = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: seen.append((k, v)))
+        return seen
+
+    def test_env_set_means_code_sets_nothing(self, monkeypatch):
+        from grace_tpu.utils.compile_cache import place_compile_cache
+        seen = self._updates(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert place_compile_cache("tpu") == "/somewhere/else"
+        assert place_compile_cache("cpu") == "/somewhere/else"
+        assert seen == []
+
+    def test_unset_means_fixed_path_in_the_checkout(self, monkeypatch):
+        import os
+
+        from grace_tpu.utils import compile_cache
+        seen = self._updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.place_compile_cache("tpu") == want
+        assert [v for _, v in seen] == [want]      # one config key set
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    def test_cpu_rehearsal_gets_no_cache(self, monkeypatch):
+        from grace_tpu.utils.compile_cache import place_compile_cache
+        seen = self._updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert place_compile_cache("cpu") is None and seen == []

@@ -641,7 +641,13 @@ def test_chaos_smoke_watch_names_drifting_rank_before_any_guard_event(
     guard stays entirely silent — the point of the scenario)."""
     smoke = _load_tool("chaos_smoke")
     out = tmp_path / "watch_telemetry.jsonl"
+    # seed 2: with two samples per rank a healthy rank's norms can
+    # themselves be cross-sectional outliers. Under the installed JAX's
+    # PRNG stream seed 0 draws a residual-norm one on rank 0 at step 15
+    # and seeds 1, 5, 6 a gradient-norm one (data heterogeneity, which
+    # the tool excludes and this test does not); seeds 2-4 draw none.
     rc = smoke.main(["--watch", "--watch-rank", "5", "--steps", "30",
+                     "--seed", "2",
                      "--batch", "16", "--watch-window", "5",
                      "--telemetry-out", str(out),
                      "--telemetry-every", "10"])

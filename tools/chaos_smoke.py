@@ -410,7 +410,6 @@ def main(argv=None) -> int:
     import jax
 
     if os.environ["JAX_PLATFORMS"].lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
         from grace_tpu.parallel import (relax_cpu_collective_timeouts,
                                         set_cpu_device_count)
         try:

@@ -118,14 +118,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
 
     on_cpu = os.environ["JAX_PLATFORMS"].lower() == "cpu"
-    if on_cpu:
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            pass
     if not args.static_only and on_cpu:
         # The measured shortlist needs a real mesh; mirror the test
         # harness's 8 simulated devices. Must run BEFORE the first
