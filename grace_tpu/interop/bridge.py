@@ -104,19 +104,19 @@ class GraceBridge:
         self._state = jax.jit(init_fn)(
             jnp.zeros((self.world, self.n), self.dtype))
 
-        def device_step(state, local):
+        def bridge_step(state, local):
             # local: this device's (1, n) row of the (world, n) gradient
             out, new_state = tx.update([local[0]], strip_world_axis(state))
             return add_world_axis(new_state), out[0]
 
         sharded = shard_map(
-            device_step, mesh=self.mesh,
+            bridge_step, mesh=self.mesh,
             in_specs=(specs, P(self.axis)),
             out_specs=(specs, P()),
             check_vma=False)
         self._fn = jax.jit(sharded, donate_argnums=(0,))
 
-        def device_step_row(state, row):
+        def bridge_step_row(state, row):
             # row: the full (n,) gradient, replicated — the single-process
             # case where every "rank" carries this process's gradient. Avoids
             # materializing world× duplicated rows over the host link.
@@ -124,7 +124,7 @@ class GraceBridge:
             return add_world_axis(new_state), out[0]
 
         sharded_row = shard_map(
-            device_step_row, mesh=self.mesh,
+            bridge_step_row, mesh=self.mesh,
             in_specs=(specs, P()),
             out_specs=(specs, P()),
             check_vma=False)

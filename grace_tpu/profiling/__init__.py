@@ -28,7 +28,6 @@ captures on the chip and reports through the same analyzer offline.
 
 from grace_tpu.profiling.recorder import (ProfileRecorder,
                                           check_state_footprint,
-                                          compile_count,
                                           device_memory_watermarks,
                                           expected_state_footprint,
                                           grace_state_footprint)
@@ -47,7 +46,7 @@ from grace_tpu.profiling.trace_export import (chrome_trace_doc,
                                               write_chrome_trace)
 
 __all__ = [
-    "ProfileRecorder", "check_state_footprint", "compile_count",
+    "ProfileRecorder", "check_state_footprint",
     "device_memory_watermarks", "expected_state_footprint",
     "grace_state_footprint",
     "Span", "TraceAnalysis", "analyze_spans", "analyze_trace",

@@ -4,7 +4,7 @@
 (:mod:`grace_tpu.profiling.trace_analysis`) and the dynamic twin of
 graft-lint's ``signature_stability`` pass: where the static pass proves the
 state signature is a fixed point *of the traced update*, the recorder
-watches the live jit cache and catches whatever escapes static analysis
+watches the program's lowerings and catches whatever escapes static analysis
 (a data-dependent shape, a host wrapper rebuilding closures) the moment it
 recompiles. It promotes :class:`grace_tpu.utils.profiling.StepTimer` from a
 bench-local helper into the long-run observability stack:
@@ -13,11 +13,13 @@ bench-local helper into the long-run observability stack:
   emitted every flush as ``perf_step_times`` records — stamped with
   ``sync_missing`` when the timer only ever measured async dispatch, so a
   meaningless number carries its own caveat;
-* **compile/retrace events** — ``perf_compile`` for the first observed
-  compile, ``perf_retrace`` whenever the step function's jit cache grows
-  afterwards (each retrace silently doubles compile memory and stalls the
-  device for seconds; a per-step retrace is the weak-type closure-leak bug
-  class);
+* **compile/retrace events** — ``perf_compile`` for the step function's
+  first lowering, ``perf_retrace`` for each later one, read from the
+  compile ledger (:mod:`grace_tpu.telemetry.compiles`, JAX's own events)
+  and so carrying ``trace_s`` / ``lower_s`` / ``compile_s`` and
+  ``cache_hit``, for the jit call path and ``fn.lower().compile()`` alike
+  (each retrace silently doubles compile memory and stalls the device for
+  seconds; a per-step retrace is the weak-type closure-leak bug class);
 * **device-memory watermarks** (``perf_memory``: ``bytes_in_use`` /
   ``peak_bytes_in_use`` from the runtime's allocator stats, max across
   local devices; silently absent on backends without stats, e.g. CPU);
@@ -40,34 +42,12 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+from grace_tpu.telemetry import compiles
 from grace_tpu.utils.profiling import StepTimer
 
-__all__ = ["ProfileRecorder", "compile_count", "device_memory_watermarks",
+__all__ = ["ProfileRecorder", "device_memory_watermarks",
            "grace_state_footprint", "expected_state_footprint",
            "check_state_footprint"]
-
-
-def compile_count(step_fn) -> Optional[int]:
-    """Total compiled variants behind a step function, or None when the
-    callable exposes no jit cache. Understands both a raw ``jax.jit``
-    wrapper (``_cache_size``) and the lazy-spec wrapper
-    ``grace_tpu.train`` returns (``jit_cache`` dict of jitted fns)."""
-    cache = getattr(step_fn, "jit_cache", None)
-    if cache is not None:
-        total = 0
-        for fn in cache.values():
-            sub = compile_count(fn)
-            if sub is None:
-                return None
-            total += sub
-        return total
-    size = getattr(step_fn, "_cache_size", None)
-    if callable(size):
-        try:
-            return int(size())
-        except Exception:
-            return None
-    return None
 
 
 def device_memory_watermarks(devices=None) -> Optional[Dict[str, int]]:
@@ -200,13 +180,23 @@ class ProfileRecorder:
             rec.update(i)
         rec.flush(len(batches) - 1)
 
-    ``step_fn`` (optional) enables retrace detection via
-    :func:`compile_count`; without it only timing/memory records are
-    emitted. The recorder never touches the device between flushes — step
-    timing is host wall-clock around the timer's sync fetch, memory stats
-    are an allocator query, and the retrace probe reads a host-side cache
-    size — so it is safe on the hot path (contrast the host callbacks
-    graft-lint's pass 4 rejects).
+    ``step_fn`` (optional) enables retrace detection: the compile ledger is
+    asked for the function's ``fun_name``. Without it only timing/memory
+    records are emitted. ``grace_tpu.train`` gives each step it builds a
+    ``fun_name`` no other program of the process has, so a process that
+    builds several steps (a tuner's candidates, a rebuilt step after a
+    reshard) records each on its own, and the first lowering of another
+    step is no retrace of this one. A raw ``jax.jit`` wrapper is asked for
+    by its ``__name__``, which JAX's events cannot tell from another
+    function of that name: give a recorded function a name of its own. The
+    ledger is per process, so the first record counts every lowering of
+    the name so far, an ahead-of-time compile made before the recorder was
+    built included. The
+    recorder never touches the device between flushes — step timing is
+    host wall-clock around the timer's sync fetch, memory stats are an
+    allocator query, and the retrace probe reads a host-side dict — so it
+    is safe on the hot path (contrast the host callbacks graft-lint's
+    pass 4 rejects).
     """
 
     def __init__(self, sink=None, every: int = 20, warmup: int = 2,
@@ -217,10 +207,12 @@ class ProfileRecorder:
         self.every = every
         self.percentiles = tuple(percentiles)
         self.timer = StepTimer(warmup=warmup)
-        self.retraces = 0        # cache growth events after the first compile
+        self.retraces = 0        # lowerings after the first
         self.flushes = 0
-        self._step_fn = step_fn
-        self._compiles: Optional[int] = None
+        self._fun_name: Optional[str] = (
+            getattr(step_fn, "fun_name", None)
+            or getattr(step_fn, "__name__", None))
+        self._seen: Optional[dict] = None     # the ledger's sums last reported
 
     # -- timing (delegates to the promoted StepTimer) -----------------------
     def step(self):
@@ -231,8 +223,8 @@ class ProfileRecorder:
 
     # -- per-iteration hook -------------------------------------------------
     def update(self, step: int) -> List[dict]:
-        """Call once per loop iteration (after the step). Checks the jit
-        cache every iteration — a retrace must be attributed to the step
+        """Call once per loop iteration (after the step). Asks the compile
+        ledger every iteration — a retrace must be attributed to the step
         that caused it, not to a flush boundary — and emits the windowed
         records on every ``every``-th call."""
         records = self._check_retrace(step)
@@ -241,25 +233,25 @@ class ProfileRecorder:
         return records
 
     def _check_retrace(self, step: int) -> List[dict]:
-        if self._step_fn is None:
+        if self._fun_name is None:
             return []
-        count = compile_count(self._step_fn)
-        if count is None:
+        now = compiles.summary(self._fun_name)
+        seen = self._seen or dict.fromkeys(now, 0)
+        new = now["lowerings"] - seen["lowerings"]
+        if new <= 0:
             return []
-        records: List[dict] = []
-        if self._compiles is None:
-            self._compiles = count
-            if count > 0:
-                records.append({"event": "perf_compile", "step": step,
-                                "cache_size": count})
-        elif count > self._compiles:
-            self.retraces += count - self._compiles
-            self._compiles = count
-            records.append({"event": "perf_retrace", "step": step,
-                            "cache_size": count,
-                            "retraces": self.retraces})
-        self._emit(records)
-        return records
+        self._seen = now
+        rec = {"event": "perf_retrace" if seen["lowerings"] else "perf_compile",
+               "step": step, "cache_size": now["lowerings"],
+               "trace_s": now["trace_s"] - seen["trace_s"],
+               "lower_s": now["lower_s"] - seen["lower_s"],
+               "compile_s": now["compile_s"] - seen["compile_s"],
+               "cache_hit": now["cache_hits"] - seen["cache_hits"] == new}
+        if seen["lowerings"]:
+            self.retraces += new
+            rec["retraces"] = self.retraces
+        self._emit([rec])
+        return [rec]
 
     def flush(self, step: int) -> List[dict]:
         """Emit the windowed records: step-time percentiles and (when the

@@ -315,7 +315,7 @@ def rejoin_barrier(state, consensus, mesh,
                          "config — the fingerprint audit IS the gate.")
     has_model = hasattr(state, "model_state")
 
-    def device_step(st):
+    def rejoin_audit(st):
         opt = strip_world_axis(st.opt_state)
         if has_model:
             params, mstate, opt = force_audit(
@@ -325,7 +325,7 @@ def rejoin_barrier(state, consensus, mesh,
         return type(st)(params, add_world_axis(opt))
 
     specs = partition_specs(state, axis_name)
-    fn = jax.jit(shard_map(device_step, mesh=mesh, in_specs=(specs,),
+    fn = jax.jit(shard_map(rejoin_audit, mesh=mesh, in_specs=(specs,),
                            out_specs=specs, check_vma=False))
     pre_repairs = audit_report(state).get("repairs", 0)
     new_state = fn(state)

@@ -29,7 +29,11 @@ Six layers (see each module's docstring for the design rationale):
 
 Plus :func:`trace_stage` (:mod:`~grace_tpu.telemetry.scopes`), which names
 the compress / exchange / decompress / memory-update stages in XLA op
-metadata so ``utils.profiling.trace`` captures attributable Perfetto spans.
+metadata so ``utils.profiling.trace`` captures attributable Perfetto spans,
+and the compile ledger (:mod:`~grace_tpu.telemetry.compiles`): every trace,
+lowering, compile and cache read of the process, by function, from JAX's
+own events. It is imported here so that it is listening before any entry
+point builds a step.
 
 IMPORT CONSTRAINT: modules in this package must not import
 ``grace_tpu.core`` / ``transform`` / ``resilience`` at module level —
@@ -37,6 +41,7 @@ IMPORT CONSTRAINT: modules in this package must not import
 reader's ``GuardState`` lookup is deliberately lazy.
 """
 
+from grace_tpu.telemetry import compiles
 from grace_tpu.telemetry.aggregate import (WATCH_FIELDS, WatchConfig,
                                            WatchState, watch_init,
                                            watch_record)
@@ -60,4 +65,5 @@ __all__ = [
     "TelemetryReader",
     "Sink", "JSONLSink", "TensorBoardSink", "MultiSink",
     "trace_stage",
+    "compiles",
 ]

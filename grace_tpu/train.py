@@ -29,6 +29,7 @@ GraceState leaves), and the loop reads health via
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -76,6 +77,9 @@ def _apply_param_specs(specs, state, param_specs):
     return specs._replace(params=param_specs)
 
 
+_steps_built = itertools.count(1)
+
+
 def _lazy_sharded_step(device_step, mesh: Mesh, axis_name, donate: bool,
                        param_specs=None):
     """jit(shard_map(device_step)) with state specs derived from the first
@@ -86,6 +90,13 @@ def _lazy_sharded_step(device_step, mesh: Mesh, axis_name, donate: bool,
     track) overrides the params portion of the state specs."""
     mesh_spec = MeshSpec.normalize(axis_name)
     cache = {}
+    # JAX's compile events tell programs apart by name alone, so each step
+    # built in a process gets its own: the first keeps ``device_step`` (and
+    # with it the HLO module's name and the compile cache's key), the n-th
+    # is ``device_step_<n>``.
+    n = next(_steps_built)
+    if n > 1:
+        device_step.__name__ = f"{device_step.__name__}_{n}"
 
     def step(state, batch):
         key = jax.tree_util.tree_structure(state)
@@ -105,6 +116,9 @@ def _lazy_sharded_step(device_step, mesh: Mesh, axis_name, donate: bool,
     # Callers (bench.py MFU accounting) can reach the underlying jitted fns
     # for AOT introspection (lower().cost_analysis()) without re-wrapping.
     step.jit_cache = cache
+    # What grace_tpu.telemetry.compiles.summary is asked for, for this step
+    # and no other of the process.
+    step.fun_name = device_step.__name__
     return step
 
 

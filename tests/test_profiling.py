@@ -14,6 +14,8 @@ Covers the read side of the observability stack:
 * ProfileRecorder: a seeded weak-type closure leak detected as a runtime
   retrace, percentile/sync-missing records, GraceState footprint checked
   against live arrays (per-device and world-sharded layouts);
+* the compile ledger (grace_tpu.telemetry.compiles) the recorder reads:
+  JAX's own trace/lower/compile spans and cache events, by function;
 * tools/perf_report.py CLI: clean exit on the fixture, exit 1 on a seeded
   baseline regression, PROF_LAST.json evidence, evidence_summary pickup.
 
@@ -434,20 +436,315 @@ def test_recorder_flush_records_percentiles_and_sync_flag():
     assert last["sync_missing"] is True       # the caveat travels with it
 
 
-def test_recorder_compile_count_understands_lazy_wrapper():
-    from grace_tpu.profiling import compile_count
+# ---------------------------------------------------------------------------
+# the compile ledger (grace_tpu.telemetry.compiles) and the recorder on it
+# ---------------------------------------------------------------------------
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture
+def ledger():
+    """The process's ledger, emptied: this test process has compiled many
+    functions before, some of them under the names used here."""
+    from grace_tpu.telemetry import compiles
+    compiles.reset()
+    return compiles
+
+
+def everything(ledger):
+    """All the ledger holds. A span of any length leaves a mark: it adds
+    an interval, or widens one, or adds to a name's sums."""
+    led = ledger.LEDGER
+    return ({k: dict(v) for k, v in led._by_name.items()},
+            list(led._intervals), led.counts())
+
+
+def _tiny_step(mesh, scale=1.0):
+    import optax
+    from grace_tpu.train import init_train_state, make_train_step
+
+    def loss_fn(p, b):
+        return jnp.mean((b @ p["w"]) ** 2) * scale
+
+    tx = optax.sgd(1e-2)
+    step = make_train_step(loss_fn, tx, mesh, donate=False)
+    return step, init_train_state({"w": jnp.ones((4, 2))}, tx, mesh)
+
+
+def test_lazy_wrapper_carries_the_fun_name_the_ledger_hears(mesh, ledger):
+    """``grace_tpu.train``'s step says under which name JAX reports its
+    program, so that nobody guesses: the ledger, asked for that name,
+    holds the one trace, lowering and compile of the step."""
+    step, state = _tiny_step(mesh)
+    assert step.fun_name.startswith("device_step")
+    assert ledger.summary(step.fun_name)["lowerings"] == 0
+    state, loss = step(state, jnp.ones((8, 4)))
+    jax.block_until_ready(loss)
+    got = ledger.summary(step.fun_name)
+    assert got["lowerings"] == 1
+    assert got["trace_s"] > 0 and got["lower_s"] > 0 and got["compile_s"] > 0
+    assert len(step.jit_cache) == 1
+
+
+def test_first_step_of_a_process_keeps_the_plain_name(mesh, monkeypatch):
+    """The first step a process builds is ``device_step``, as it always
+    was: its HLO module's name, and the compile cache's key, stand. Later
+    ones are numbered."""
+    import itertools
+    from grace_tpu import train
+
+    monkeypatch.setattr(train, "_steps_built", itertools.count(1))
+    names = [_tiny_step(mesh)[0].fun_name for _ in range(3)]
+    assert names == ["device_step", "device_step_2", "device_step_3"]
+
+
+def test_another_steps_first_lowering_is_no_retrace_of_this_one(mesh, ledger):
+    """A process that builds several steps (a tuner's candidates, chaos
+    smoke's step_a / step_b) records each on its own."""
+    step_a, state_a = _tiny_step(mesh)
+    step_b, state_b = _tiny_step(mesh, scale=2.0)
+    assert step_a.fun_name != step_b.fun_name
+    sink_a, sink_b = ListSink(), ListSink()
+    rec_a = ProfileRecorder(sink_a, every=100, warmup=0, step_fn=step_a)
+    rec_b = ProfileRecorder(sink_b, every=100, warmup=0, step_fn=step_b)
+    batch = jnp.ones((8, 4))
+    state_a, _ = step_a(state_a, batch)
+    rec_a.update(0)
+    rec_b.update(0)
+    state_b, loss = step_b(state_b, batch)          # b's first lowering
+    jax.block_until_ready(loss)
+    rec_a.update(1)
+    rec_b.update(1)
+    assert [(r["event"], r["step"], r["cache_size"])
+            for r in sink_a.records] == [("perf_compile", 0, 1)]
+    assert [(r["event"], r["step"], r["cache_size"])
+            for r in sink_b.records] == [("perf_compile", 1, 1)]
+    assert rec_a.retraces == rec_b.retraces == 0
+    assert ledger.summary(step_a.fun_name)["lowerings"] == 1
+    assert ledger.summary(step_b.fun_name)["lowerings"] == 1
+
+
+def test_ledger_imports_only_jax_monitoring_and_the_standard_library():
+    import ast
+    import grace_tpu  # noqa: F401  (importing the package starts the ledger)
+    from grace_tpu.telemetry import compiles
+
+    assert sys.modules["grace_tpu.telemetry.compiles"] is compiles
+    with open(compiles.__file__) as f:
+        tree = ast.parse(f.read())
+    imports = [n for n in ast.walk(tree)
+               if isinstance(n, (ast.Import, ast.ImportFrom))]
+    names = sorted(a.name for n in imports if isinstance(n, ast.Import)
+                   for a in n.names)
+    froms = sorted((n.module, a.name) for n in imports
+                   if isinstance(n, ast.ImportFrom) for a in n.names)
+    assert names == []
+    assert froms == [("__future__", "annotations"), ("jax", "monitoring")]
+
+
+def test_ledger_keys_trace_lower_compile_of_one_function_together(ledger):
+    @jax.jit
+    def ledger_probe_a(x):
+        return jnp.tanh(x) * 2
+
+    jax.block_until_ready(ledger_probe_a(jnp.ones((3,))))
+    got = ledger.summary("ledger_probe_a")
+    assert got["lowerings"] == 1 and got["cache_hits"] == 0
+    assert got["trace_s"] > 0 and got["lower_s"] > 0 and got["compile_s"] > 0
+    assert ledger.summary("jit(ledger_probe_a)") == got      # JAX's other name
+    assert ledger.summary("never_compiled")["lowerings"] == 0
+
+
+def test_ledger_sees_the_ahead_of_time_path(ledger):
+    """``fn.lower(x).compile()`` never grows the jitted function's own
+    cache, which is what the old probe polled."""
 
     @jax.jit
-    def f(x):
-        return x + 1
+    def ledger_probe_aot(x):
+        return x * 3 + 1
 
-    class Wrapper:                            # grace_tpu.train shape
-        jit_cache = {"k": f}
+    compiled = ledger_probe_aot.lower(jnp.ones((5,))).compile()
+    got = ledger.summary("ledger_probe_aot")
+    assert got["lowerings"] == 1 and got["compile_s"] > 0
+    for _ in range(50):
+        out = compiled(jnp.ones((5,)))
+    jax.block_until_ready(out)
+    assert ledger.summary("ledger_probe_aot") == got
 
-    assert compile_count(Wrapper()) == 0
-    f(jnp.ones(()))
-    assert compile_count(Wrapper()) == 1
-    assert compile_count(object()) is None
+
+def test_fifty_steady_calls_of_a_compiled_step_add_no_event(ledger):
+    @jax.jit
+    def ledger_probe_steady(c):
+        return c + jnp.float32(1)
+
+    c = ledger_probe_steady(jnp.zeros((), jnp.float32))
+    before, wall = everything(ledger), ledger.wall_s()
+    assert before[0]["ledger_probe_steady"]["lowerings"] == 1
+    for _ in range(50):
+        c = ledger_probe_steady(c)
+    jax.block_until_ready(c)
+    assert everything(ledger) == before and ledger.wall_s() == wall
+
+
+def test_a_cached_trace_adds_no_lowering():
+    """JAX's tracing cache answers in a trace span of its own, of no or
+    next to no length: it is no retrace."""
+    from grace_tpu.telemetry.compiles import CompileLedger
+
+    led = CompileLedger()
+    led.on_span(TRACE, 10.0, 12.0, fun_name="f")
+    led.on_span(LOWER, 12.0, 13.0, fun_name="jit(f)")
+    led.on_span(COMPILE, 13.0, 17.0, fun_name="jit(f)")
+    led.on_span(TRACE, 20.0, 20.0, fun_name="f")           # the cache
+    got = led.summary("f")
+    assert got == {"trace_s": 2.0, "lower_s": 1.0, "compile_s": 4.0,
+                   "lowerings": 1, "cache_hits": 0}
+    led.on_span("/jax/some/other/span", 0.0, 99.0, fun_name="f")
+    assert led.summary("f") == got and led.wall_s() == 7.0
+
+
+def test_five_thousand_nested_spans_do_not_evict_a_functions_sums():
+    """A step's trace holds thousands of nested traces of ``jnp``
+    functions, and the ledger holds what a set-up of a dozen spans would:
+    one entry a name and one interval a stretch of work."""
+    from grace_tpu.telemetry.compiles import CompileLedger
+
+    led = CompileLedger()
+    for i in range(5000):                    # inside the step's trace
+        led.on_span(TRACE, 1.0 + i * 1e-4, 1.0 + i * 1e-4 + 5e-5,
+                    fun_name=f"jnp_fn_{i % 40}")
+    led.on_span(TRACE, 0.5, 2.0, fun_name="device_step")
+    for i in range(5000):                    # other programs, before the lowering
+        led.on_span(TRACE, 3.0 + i * 1e-4, 3.0 + i * 1e-4 + 5e-5,
+                    fun_name=f"jnp_fn_{i % 40}")
+    led.on_span(LOWER, 4.0, 4.75, fun_name="jit(device_step)")
+    assert led.summary("device_step") == {
+        "trace_s": 1.5, "lower_s": 0.75, "compile_s": 0.0,
+        "lowerings": 1, "cache_hits": 0}
+    # the step's trace swallowed its 5000 children; the later 5000 are
+    # disjoint slivers of 50 µs and the lowering is apart from them
+    assert led.wall_s() == pytest.approx(1.5 + 5000 * 5e-5 + 0.75)
+    assert len(led._by_name) == 41 and len(led._intervals) == 5002
+    assert led._intervals[0] == (0.5, 2.0)
+
+
+def test_wall_of_nested_intervals_is_the_outer_one():
+    from grace_tpu.telemetry.compiles import CompileLedger
+
+    led = CompileLedger()
+    led.on_span(TRACE, 1.0, 2.0, fun_name="inner_a")
+    led.on_span(TRACE, 2.5, 3.0, fun_name="inner_b")
+    led.on_span(TRACE, 0.0, 4.0, fun_name="outer")
+    assert led.wall_s() == 4.0                           # not 5.5
+    led.on_span(COMPILE, 6.0, 7.0, fun_name="jit(outer)")
+    led.on_span(LOWER, 6.5, 8.0, fun_name="jit(other)")  # overlaps: one more second
+    assert led.wall_s() == 6.0
+    assert led._intervals == [(0.0, 4.0), (6.0, 8.0)]
+
+
+def test_cache_events_are_counted_and_hits_belong_to_their_compile():
+    from grace_tpu.telemetry.compiles import CompileLedger
+
+    led = CompileLedger()
+    assert led.counts() == {"cache_hits": 0, "cache_misses": 0}
+    led.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    led.on_event("/jax/compilation_cache/cache_misses")
+    led.on_span(COMPILE, 0.0, 9.0, fun_name="jit(cold)")
+    led.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    led.on_event("/jax/compilation_cache/cache_hits")
+    led.on_span(COMPILE, 10.0, 10.5, fun_name="jit(warm)")
+    led.on_event("/jax/compilation_cache/tasks_using_cache")     # not kept
+    assert led.counts() == {"cache_hits": 1, "cache_misses": 1}
+    assert led.summary("cold")["cache_hits"] == 0
+    assert led.summary("warm")["cache_hits"] == 1
+    led.reset()
+    assert led.counts()["cache_hits"] == 0 and led.wall_s() == 0.0
+    assert led.summary("warm")["compile_s"] == 0.0 and led._by_name == {}
+
+
+def test_persistent_cache_counts_a_miss_then_a_hit(tmp_path, ledger):
+    """With a compilation cache directory the first compile of a fresh
+    program is written (a miss) and the next compile of the same program,
+    from another function object, is read back (a hit)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def fresh():
+        def ledger_probe_cached(x):
+            return jnp.cos(x) * 7 + jnp.float32(0.125)
+        return jax.jit(ledger_probe_cached)
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    x = jnp.ones((11,))          # made before the cache is on
+    try:
+        for k, v in zip(keys, (str(tmp_path), 0, -1)):
+            jax.config.update(k, v)
+        cc.reset_cache()
+        fresh().lower(x).compile()
+        first = ledger.counts()
+        assert first["cache_misses"] == 1 and first["cache_hits"] == 0
+        assert ledger.summary("ledger_probe_cached")["cache_hits"] == 0
+        fresh().lower(x).compile()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    after = ledger.counts()
+    assert after == {"cache_hits": 1, "cache_misses": 1}
+    got = ledger.summary("ledger_probe_cached")
+    assert got["lowerings"] == 2 and got["cache_hits"] == 1
+
+
+def test_recorder_retrace_record_carries_durations(ledger):
+    @jax.jit
+    def ledger_probe_leaky(c):
+        return c + 1.5
+
+    sink = ListSink()
+    rec = ProfileRecorder(sink, every=100, warmup=0,
+                          step_fn=ledger_probe_leaky)
+    c = jnp.zeros((), jnp.int32)
+    for i in range(4):
+        c = ledger_probe_leaky(c)
+        rec.update(i)
+    assert ledger.summary("ledger_probe_leaky")["lowerings"] == 2
+    first, again = sink.records
+    assert (first["event"], first["step"], first["cache_size"]) == (
+        "perf_compile", 0, 1)
+    assert (again["event"], again["step"], again["cache_size"],
+            again["retraces"]) == ("perf_retrace", 1, 2, 1)
+    for r in (first, again):
+        assert r["trace_s"] > 0 and r["lower_s"] > 0 and r["compile_s"] > 0
+        assert r["cache_hit"] is False
+    total = ledger.summary("ledger_probe_leaky")
+    assert first["lower_s"] + again["lower_s"] == pytest.approx(total["lower_s"])
+
+
+def test_recorder_sees_an_ahead_of_time_compile(ledger):
+    """The path the benchmark, ``chip_smoke.py`` and ``bench.py`` take."""
+
+    @jax.jit
+    def ledger_probe_aot_step(c):
+        return c * jnp.float32(2)
+
+    compiled = ledger_probe_aot_step.lower(jnp.ones(())).compile()
+    sink = ListSink()
+    rec = ProfileRecorder(sink, every=100, warmup=0,
+                          step_fn=ledger_probe_aot_step)
+    c = jnp.ones(())
+    for i in range(3):
+        c = compiled(c)
+        rec.update(i)
+    assert [(r["event"], r["step"], r["cache_size"]) for r in sink.records] == [
+        ("perf_compile", 0, 1)]
+    assert sink.records[0]["compile_s"] > 0 and rec.retraces == 0
+    # a callable with no name to ask for gives no compile records
+    assert ProfileRecorder(sink, step_fn=object()).update(0) == []
 
 
 # ---------------------------------------------------------------------------
