@@ -295,10 +295,20 @@ class Allgather(Communicator):
     Mirrors grace_dl/dist/communicator/allgather.py:7-45. The reference's
     variable-size path (gather sizes → pad → split, lines 16-38) is
     unnecessary: payloads are statically shaped under XLA, with invalid lanes
-    zero-valued (see compressors with static-capacity payloads). Per-rank
-    decompression is vmapped over the gathered world axis and runs as one
-    fused XLA computation instead of the reference's Python loop
-    (SURVEY.md §3.1 hot spot).
+    zero-valued (see compressors with static-capacity payloads).
+
+    Which decode runs: a compressor that can aggregate its gathered
+    payloads without W dense copies says so through
+    ``fused_aggregate_decompress(gathered, ctx, world)`` — TopK chunk mode
+    sums the ranks in the (rows, k) view and flattens once (staged, W > 1),
+    or runs its Pallas kernel when that is enabled. Everything else (and
+    any payload the hook declines with None: W = 1, sub-k slices, other
+    algorithms) is decoded per rank under ``vmap`` over the gathered world
+    axis, then ``aggregate``d and averaged — the reference's Python loop
+    (SURVEY.md §3.1 hot spot) as one traced computation. That stacks W
+    dense tensors; XLA fuses the stack away for small leaves, but for a
+    chunk payload of a million-element leaf it relayouts all W copies in a
+    loop (PERF.md §6, PR 27), which is why the hook exists.
     """
 
     def exchange(self, payload: Payload, ctx: Ctx, compressor: Compressor

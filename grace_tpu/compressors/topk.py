@@ -32,7 +32,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from grace_tpu.core import Compressor, Ctx, Payload, State
-from grace_tpu.ops.sparse import chunkwise_dense, scatter_dense
+from grace_tpu.ops.sparse import (chunkwise_dense, chunkwise_dense_sum,
+                                  scatter_dense)
+from grace_tpu.telemetry.scopes import STAGE_DECOMPRESS, trace_stage
 
 
 def static_k(numel: int, ratio: float) -> int:
@@ -244,15 +246,20 @@ class TopKCompressor(Compressor):
 
     def fused_aggregate_decompress(self, gathered: Payload, ctx: Ctx,
                                    world: int):
-        """Allgather fused exchange path: (world, k) payload stacks ->
-        aggregated (and world-averaged, per ``self.average``) dense tensor
-        in one n-sized HBM pass (ops/pallas_topk.py chunk_aggregate_dense),
-        replacing world vmapped one-hot builds + a sum. None = staged path.
+        """Allgather exchange path: (world, k) payload stacks -> aggregated
+        (and world-averaged, per ``self.average``) dense tensor, without
+        the (world, rows, k) stack of ``vmap(decompress)``.
+
+        With the Pallas kernel enabled: one n-sized HBM pass
+        (ops/pallas_topk.py chunk_aggregate_dense). Otherwise, for a
+        chunk-structured payload of world > 1 ranks: the staged
+        :meth:`_aggregate_rows`. None = the communicator's vmapped decode
+        (non-chunk algorithms, sub-k payloads, world == 1).
         """
         numel, shape, dtype = ctx
         gate = self._fused_chunk_gate(numel, dtype, lambda: world)
         if gate is None:
-            return None
+            return self._aggregate_rows(gathered, ctx, world)
         k, interpret = gate
         values, indices = gathered
         if values.shape != (world, k):
@@ -272,6 +279,27 @@ class TopKCompressor(Compressor):
                                     numel, average=self.average,
                                     interpret=interpret)
         return out.reshape(shape).astype(dtype)
+
+    def _aggregate_rows(self, gathered: Payload, ctx: Ctx, world: int):
+        """Staged aggregate-then-reshape decode of a gathered chunk payload
+        (ops.sparse.chunkwise_dense_sum): the ranks' sum in the (rows, k)
+        view, ONE flatten, then the average — the same sum over the same
+        ``world`` addends as ``vmap(decompress)`` + ``aggregate``. Static
+        conditions only: the same chunk structure :meth:`decompress` checks
+        per rank, and more than one rank (at world == 1 the single decode
+        fuses into its consumer as it is; None leaves that program
+        unchanged)."""
+        values, indices = gathered
+        numel, shape, dtype = ctx
+        k = static_k(numel, self.compress_ratio)
+        if (self.algorithm != "chunk" or world == 1 or numel < 2 * k
+                or values.shape != (world, k)):
+            return None
+        with trace_stage(f"{STAGE_DECOMPRESS}/aggregate_rows"):
+            out = chunkwise_dense_sum(values.astype(dtype),
+                                      (indices // k).astype(jnp.int32),
+                                      -(-numel // k), numel, shape)
+            return out / world if self.average else out
 
     def decompress(self, payload: Payload, ctx: Ctx) -> jax.Array:
         values, indices = payload

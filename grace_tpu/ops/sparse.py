@@ -38,7 +38,37 @@ def chunkwise_dense(values: jax.Array, win_row: jax.Array, rows: int,
     ``values``/``win_row`` have length k; element c lands at flat index
     ``win_row[c] * k + c``. Padding columns introduced at compress time
     carry value 0, so rows*k > numel overhang truncates harmlessly.
+
+    This is ONE rank's decode: ``TopKCompressor.decompress`` (the memory
+    update, the ring/two-shot hops, the W = 1 exchange). The final
+    ``reshape(-1)`` flattens a tiled (rows, k) layout whose k is rarely a
+    multiple of 128, a physical relayout; alone it fuses into its consumer,
+    but vmapped over W gathered payloads it becomes a (W, rows, k) stack
+    that XLA:TPU relayouts in a loop over row windows per large leaf. The
+    all-gather exchange of W > 1 payloads therefore decodes through
+    :func:`chunkwise_dense_sum`, which sums first and relayouts once.
     """
     mask = jnp.arange(rows, dtype=win_row.dtype)[:, None] == win_row[None, :]
     dense = jnp.where(mask, values[None, :], jnp.zeros((), values.dtype))
+    return dense.reshape(-1)[:numel].reshape(shape)
+
+
+def chunkwise_dense_sum(values: jax.Array, win_row: jax.Array, rows: int,
+                        numel: int, shape: tuple) -> jax.Array:
+    """Sum of W ranks' chunk-structured payloads, built dense ONCE.
+
+    ``values``/``win_row`` are the gathered ``(W, k)`` stacks. The ranks are
+    added in the (rows, k) view — W compares and adds per element in one
+    fused elementwise pass, rank 0 first like ``jnp.sum(stacked, axis=0)``
+    — and only the sum is flattened, truncated and reshaped: the same
+    float sum over the same W addends as ``vmap(chunkwise_dense)`` + sum,
+    without the (W, rows, k) tensor and its W relayouts (see
+    :func:`chunkwise_dense`).
+    """
+    row = jnp.arange(rows, dtype=win_row.dtype)[:, None]
+    zero = jnp.zeros((), values.dtype)
+    dense = jnp.where(row == win_row[0][None, :], values[0][None, :], zero)
+    for w in range(1, values.shape[0]):
+        dense = dense + jnp.where(row == win_row[w][None, :],
+                                  values[w][None, :], zero)
     return dense.reshape(-1)[:numel].reshape(shape)

@@ -7,7 +7,8 @@ all_gather/psum collectives, single process.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
 
 from grace_tpu.parallel import shard_map
 from grace_tpu import comm
@@ -366,3 +367,164 @@ def test_allreduce_chunked_psum_matches_whole(mesh, rng, monkeypatch):
     out2 = run_exchange(mesh, comm.Allreduce(),
                         C.NoneCompressor(average=False), jnp.asarray(y))
     np.testing.assert_allclose(out2, y.sum(0), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Allgather of chunk-structured top-k payloads: aggregate, then reshape
+# ---------------------------------------------------------------------------
+
+# (shape, ratio, wire dtype): k a multiple of 128 with rows*k == numel; k no
+# multiple of 128 with rows*k > numel (padding); a leaf with k = 1; bfloat16
+# wire values.
+_ROWS_CASES = {
+    "k128": ((128, 100), 0.01, "float32"),        # k=128, rows=100
+    "k34-padded": ((3, 3, 16, 24), 0.01, "float32"),   # k=34, 102*34 > 3456
+    "k1": ((64,), 0.01, "float32"),               # k=1, rows=64
+    "bf16-wire": ((40, 50), 0.05, "bfloat16"),    # k=100, rows=20
+}
+
+
+class _SumTopK(C.TopKCompressor):
+    average = False         # a class flag of Compressor, not a field
+
+
+def _chunk_codec(case, average=True):
+    shape, ratio, wire = _ROWS_CASES[case]
+    cls = C.TopKCompressor if average else _SumTopK
+    return shape, cls(compress_ratio=ratio, algorithm="chunk",
+                      wire_dtype=wire)
+
+
+def _rank_inputs(rng, world, shape, k, disjoint):
+    """Per-rank gradients. ``disjoint``: rank r is zero outside row r of the
+    (rows, k) view, so the ranks keep disjoint positions and every element
+    of the sum has one non-zero addend."""
+    n = int(np.prod(shape))
+    x = rng.standard_normal((world, n)).astype(np.float32)
+    if disjoint:
+        keep = np.zeros((world, n), bool)
+        for r in range(world):
+            keep[r, r * k:(r + 1) * k] = True
+        x = np.where(keep, x, 0.0).astype(np.float32)
+    return x.reshape((world,) + shape)
+
+
+def _exchange_both_ways(world, comp, per_rank):
+    """Rank 0's output of ``Allgather.exchange`` and of the per-rank decode
+    it replaces (vmap(decompress) + aggregate + average) on the same
+    gathered payloads, plus whether the compressor's hook answered."""
+    mesh = Mesh(np.asarray(jax.devices()[:world]), ("data",))
+    answered = []
+
+    def body(x):
+        x = x[0]
+        payload, ctx, _ = comp.compress(x, None, jax.random.key(0))
+        out = comm.Allgather().exchange(payload, ctx, comp)
+        gathered = tuple(jax.lax.all_gather(t, "data") for t in payload)
+        answered.append(comp.fused_aggregate_decompress(
+            gathered, ctx, world) is not None)
+        ref = comp.aggregate(jax.vmap(
+            lambda p: comp.decompress(p, ctx))(gathered))
+        if comp.average:
+            ref = ref / world
+        return out[None], ref[None]
+
+    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
+                           out_specs=(P("data"), P("data")), check_vma=False))
+    out, ref = fn(jnp.asarray(per_rank))
+    return np.asarray(out[0]), np.asarray(ref[0]), answered[0]
+
+
+@pytest.mark.parametrize("average", [True, False], ids=["mean", "sum"])
+@pytest.mark.parametrize("disjoint", [False, True],
+                         ids=["overlapping", "disjoint"])
+@pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+@pytest.mark.parametrize("world", [1, 2, 4])
+def test_allgather_chunk_topk_aggregates_rows_like_per_rank_decode(
+        rng, world, case, disjoint, average):
+    """The staged aggregate-then-reshape decode (ops.sparse.
+    chunkwise_dense_sum) against vmap(decompress) + aggregate (+ average)
+    on a real ``world``-device mesh: equal to 1e-6 of the leaf's norm, and
+    bitwise where the ranks' kept positions are disjoint. At world == 1 the
+    hook declines and the exchange IS the per-rank decode."""
+    shape, comp = _chunk_codec(case, average=average)
+    k = max(1, int(np.prod(shape) * comp.compress_ratio))
+    x = _rank_inputs(rng, world, shape, k, disjoint)
+    out, ref, answered = _exchange_both_ways(world, comp, x)
+    assert answered == (world > 1)
+    assert out.shape == shape and out.dtype == ref.dtype
+    assert np.linalg.norm(ref) > 0
+    if disjoint or world == 1:
+        np.testing.assert_array_equal(out, ref)
+    else:
+        assert np.linalg.norm(out - ref) <= 1e-6 * np.linalg.norm(ref)
+
+
+@pytest.mark.parametrize("world", [1, 2, 4])
+def test_chunkwise_dense_sum_is_the_sum_of_chunkwise_dense(rng, world):
+    """The function alone, off the mesh: W payloads with padding (rows*k >
+    numel) against W single decodes added in rank order — bitwise, both
+    being the same left-to-right float32 sum."""
+    from grace_tpu.ops.sparse import chunkwise_dense, chunkwise_dense_sum
+    shape, k = (7, 11, 13), 17                     # 1001 elements, rows=59
+    numel = int(np.prod(shape))
+    rows = -(-numel // k)
+    values = jnp.asarray(rng.standard_normal((world, k)), jnp.float32)
+    win_row = jnp.asarray(rng.integers(0, rows - 1, (world, k)), jnp.int32)
+    got = chunkwise_dense_sum(values, win_row, rows, numel, shape)
+    want = chunkwise_dense(values[0], win_row[0], rows, numel, shape)
+    for w in range(1, world):
+        want = want + chunkwise_dense(values[w], win_row[w], rows, numel,
+                                      shape)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("why", ["sub-k", "exact", "rows<2"])
+def test_aggregate_rows_declines_what_decompress_scatters(rng, why):
+    """A payload without the full-column chunk structure keeps the
+    communicator's vmapped decode, whose ``decompress`` takes the scatter
+    path: a sub-k payload (a two-shot slice), a non-chunk algorithm, a
+    leaf with fewer than two rows per chunk."""
+    world, n = 4, 1000
+    comp = C.TopKCompressor(compress_ratio=0.9 if why == "rows<2" else 0.05,
+                            algorithm="exact" if why == "exact" else "chunk")
+    k = int(n * comp.compress_ratio)
+    width = k // 2 if why == "sub-k" else k
+    values = jnp.asarray(rng.standard_normal((world, width)), jnp.float32)
+    indices = jnp.asarray(np.stack([rng.permutation(n)[:width]
+                                    for _ in range(world)]), jnp.int32)
+    ctx = (n, (n,), jnp.float32)
+    assert comp.fused_aggregate_decompress((values, indices), ctx,
+                                           world) is None
+    dense = jax.vmap(lambda p: comp.decompress(p, ctx))((values, indices))
+    expect = np.zeros((world, n), np.float32)
+    for r in range(world):
+        expect[r, np.asarray(indices[r])] = np.asarray(values[r])
+    np.testing.assert_array_equal(np.asarray(dense), expect)
+
+
+@pytest.mark.parametrize("world", [1, 4])
+def test_demanded_kernel_still_takes_the_kernel(rng, monkeypatch, world):
+    """``use_pallas=True`` on a TPU answers with the Pallas aggregate at
+    any world, never with the staged rows path."""
+    import grace_tpu.ops.pallas_topk as pallas_topk
+    n, k = 4000, 40
+    calls = []
+
+    def kernel(values, win, k_, numel, *, average, interpret):
+        calls.append((values.shape, average, interpret))
+        return jnp.zeros((numel,), jnp.float32)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_topk, "chunk_aggregate_dense", kernel)
+    comp = C.TopKCompressor(compress_ratio=0.01, algorithm="chunk",
+                            use_pallas=True)
+    monkeypatch.setattr(
+        C.TopKCompressor, "_aggregate_rows",
+        lambda *a, **kw: pytest.fail("staged path taken under use_pallas"))
+    values = jnp.asarray(rng.standard_normal((world, k)), jnp.float32)
+    indices = jnp.tile(jnp.arange(k, dtype=jnp.int32), (world, 1))
+    out = comp.fused_aggregate_decompress((values, indices),
+                                          (n, (n,), jnp.float32), world)
+    assert out.shape == (n,)
+    assert calls == [((world, k), True, False)]

@@ -20,6 +20,7 @@ passes here is not a chip run and is never reported as one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -207,3 +208,96 @@ def test_qsgd_auto_exchange_lowers_for_four_chips(topo, kernels_on,
     assert "tpu_custom_call" in text
     assert any(op in text for op in ("collective-permute", "all-to-all",
                                      "all-gather", "all-reduce"))
+
+
+# ---------------------------------------------------------------------------
+# the relayout loops PR 27 took out of the four-chip all-gather decode
+# ---------------------------------------------------------------------------
+
+def test_topk_chunk_allgather_decode_has_no_relayout_loop(topo):
+    """The all-gather exchange of ONE (3, 3, 512, 512) leaf at top-k 1 %
+    chunk over the four described chips. Decoded per rank under vmap, the
+    (4, 101, 23592) stack's flatten (k is no multiple of 128: a physical
+    relayout of a tiled layout) compiled to ``while`` loops over row
+    windows, float32 and int32 twin alike: 36 a step in ResNet-50, 11 % of
+    the four-chip step. Summed in the (rows, k) view first, no loop is
+    left, and the wire is what it was: one collective per payload tensor
+    (XLA:TPU runs an all-gather this small as an all-reduce of a buffer
+    each chip wrote its own piece of; in the whole step it combines them
+    into real all-gathers)."""
+    grace = grace_from_params({"compressor": "topk", "compress_ratio": 0.01,
+                               "topk_algorithm": "chunk", "memory": "none",
+                               "communicator": "allgather"})
+    comm, codec = grace.communicator, grace.compressor
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+
+    def body(x):
+        payload, ctx, _ = codec.compress(x[0], None, jax.random.key(0))
+        return comm.exchange(payload, ctx, codec)[None]
+
+    step = shard_map(body, mesh=mesh, in_specs=P("data"),
+                     out_specs=P("data"))
+    x = jax.ShapeDtypeStruct((4, 3, 3, 512, 512), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    text = compile_text(step, x)
+    assert " while(" not in text
+    assert "%wide.body" not in text
+    collectives = sum(text.count(f" {op}{start}(")
+                      for op in ("all-gather", "all-reduce")
+                      for start in ("", "-start"))
+    assert collectives == 2                 # values, indices
+
+
+def scope_engagements(lowered_text: str, scope_op: str) -> int:
+    """Operations of a lowered module (``as_text(debug_info=True)``) whose
+    name stack ends in ``scope_op``."""
+    names = re.findall(r'^(#loc\d+) = loc\("[^"]*%s"' % re.escape(scope_op),
+                       lowered_text, re.M)
+    return sum(lowered_text.count(f"loc({n})") for n in names)
+
+
+@pytest.mark.parametrize("params, world, engaged", [
+    ({"compressor": "topk", "compress_ratio": 0.01,
+      "topk_algorithm": "chunk", "memory": "residual",
+      "communicator": "allgather"}, 4, 4),
+    ({"compressor": "topk", "compress_ratio": 0.01,
+      "topk_algorithm": "chunk", "memory": "residual",
+      "communicator": "allgather"}, 1, 0),
+    ({"compressor": "topk", "compress_ratio": 0.01,
+      "memory": "residual", "communicator": "allgather"}, 4, 0),
+    ({"compressor": "powersgd", "compress_rank": 2, "memory": "powersgd",
+      "communicator": "allgather"}, 4, 0),
+    ({"compressor": "none", "memory": "none",
+      "communicator": "allreduce"}, 4, 0),
+], ids=["topk-chunk-w4", "topk-chunk-w1", "topk-exact-w4", "powersgd-w4",
+        "dense-w4"])
+def test_aggregate_rows_engagement_count_in_lowered_step(params, world,
+                                                        engaged):
+    """How often the staged aggregate-then-reshape decode engages is a
+    static count: the leaves whose exchange carries the sub-scope
+    ``grace/decompress/aggregate_rows`` in the lowered train step (one
+    ``iota``, the row index, per engagement). Every leaf with numel >= 2k
+    of a chunk top-k all-gather over more than one device; none at one
+    device, for another algorithm, for PowerSGD (empty payload) or dense.
+    A small step on the CPU mesh: nothing here needs the described chip."""
+    import optax
+    from grace_tpu.train import init_train_state, make_train_step
+
+    mesh = Mesh(np.asarray(jax.devices()[:world]), ("data",))
+    model = {"conv": jnp.ones((3, 3, 8, 16)), "dense": jnp.ones((64, 32)),
+             "bias": jnp.ones((32,)), "pair": jnp.ones((2,)),
+             "scalar": jnp.ones((1,))}      # numel 1 < 2k: scatter path
+
+    def loss_fn(p, batch):
+        return sum(jnp.sum(leaf) for leaf in p.values()) * jnp.mean(batch)
+
+    tx = optax.chain(grace_from_params(params).transform(seed=0),
+                     optax.sgd(0.1))
+    state = init_train_state(model, tx, mesh)
+    batch = jnp.ones((world * 2, 3))
+    step = make_train_step(loss_fn, tx, mesh, donate=False)
+    jax.eval_shape(step, state, batch)
+    fn = next(iter(step.jit_cache.values()))
+    text = fn.lower(state, batch).as_text(debug_info=True)
+    assert scope_engagements(
+        text, "grace/decompress/aggregate_rows/iota") == engaged
