@@ -10,11 +10,17 @@ checkpoints with orbax alongside them.
 * ``resnet``        — ResNet-50/101/152 v1.5 (torchvision stand-in used by
                       examples/torch/pytorch_synthetic_benchmark.py:49)
 * ``transformer``   — BERT-style encoder (BASELINE.json BERT/PowerSGD config)
+* ``lfm2``          — LFM2-MoE causal decoder: gated short convolutions,
+                      grouped-query attention, sparse experts of which a chip
+                      holds a share, next-token loss; ``(params, model_state,
+                      ids) -> (loss, model_state)`` (the benchmark's
+                      ``lfm2-24b-a2b-ep8`` configuration)
 * ``vgg``           — VGG-11/13/16/19 (the communication-bound classic of the
                       reference's synthetic-benchmark model list)
 """
 
-from grace_tpu.models import (layers, lenet, resnet, resnet_cifar,
+from grace_tpu.models import (layers, lenet, lfm2, resnet, resnet_cifar,
                               transformer, vgg)
 
-__all__ = ["layers", "lenet", "resnet", "resnet_cifar", "transformer", "vgg"]
+__all__ = ["layers", "lenet", "lfm2", "resnet", "resnet_cifar", "transformer",
+           "vgg"]

@@ -135,6 +135,31 @@ def ln_apply(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return y.astype(x.dtype)
 
 
+def rms_init(d: int) -> Params:
+    return {"scale": jnp.ones((d,))}
+
+
+def rms_apply(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over the last axis with a learned weight, computed in
+    float32 and returned in the activation dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * p["scale"]).astype(x.dtype)
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the whole head, rotate-half: ``x`` is
+    ``(..., T, heads, head_dim)``, position ``t`` along the third axis from
+    the end. Angles and the rotation are float32."""
+    t, d = x.shape[-3], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # pooling / misc
 # ---------------------------------------------------------------------------

@@ -1,0 +1,524 @@
+"""LFM2-MoE causal decoder (LiquidAI ``lfm2_moe``): gated short convolutions
+with a full-attention layer among every few, a dense gated feed-forward in
+the leading layers and sparse experts after them, next-token loss.
+
+Equations (``u = RMSNorm(x)`` with a learned weight; no bias anywhere):
+
+* layer: ``h = x + Op(RMSNorm_op(x))``, ``y = h + FFN(RMSNorm_ffn(h))``;
+  after the last layer RMSNorm, then the output head.
+* ``conv`` operator: ``(B, C, X) = split3(W_in u)``, ``z = B * X``,
+  ``c_t = sum_j w_j * z_{t-(L-1)+j}`` (depth-wise, causal, kernel ``L =
+  conv_L_cache``, zeros before the start of each sequence),
+  ``Op = W_out (C * c)``.
+* ``full_attention`` operator: grouped-query attention, RMSNorm over each
+  query and key head, rotary positions over the whole head (rotate-half),
+  causal softmax of ``q k^T / sqrt(head_dim)``.
+* dense feed-forward: ``W_2 (silu(W_1 h) * W_3 h)``.
+* expert feed-forward: ``s = sigmoid(W_r h)``; the ``num_experts_per_tok``
+  experts are the top of ``s + b`` (``b`` the expert bias, model state, not
+  trained by the gradient); their weights are ``s_i`` (without ``b``) over
+  ``sum s_i + 1e-6`` times ``routed_scaling_factor``; ``y = sum_i g_i
+  W_2,i (silu(W_1,i h) * W_3,i h)``.
+
+**The expert layer is told which experts it holds** (``first_expert``,
+``experts_held``): it routes over all ``num_experts``, and computes the
+part of the result its own experts give, as grouped matrix products
+(``lax.ragged_dot``) over the assignments sorted by expert. No assignment
+is dropped: the sorted rows are bounded by the worst case (every one of a
+token's ``k`` assignments held here) and walked in blocks, each gathering
+its rows, running the grouped products and adding the results to their
+tokens; a block past the rows in use is skipped, so the work follows the
+load and not the bound, and a visited block is computed whole, so the
+time does not follow the load inside it. A block is ``moe_row_block``
+rows, or twice a balanced router's load. With all experts held the layer is the
+whole one; with a share it is what that chip computes before an exchange
+this file does not have.
+
+Memory: every part of a layer (operator, feed-forward, head with loss) is
+recomputed in the backward pass from its input, the dense parts
+``seq_block`` sequences at a time, so the step holds two activations a
+layer and one block's intermediates. Parameters are cast to the
+activation dtype inside a block, so a weight's gradient is summed over
+the blocks in float32.
+
+Model state carries, per expert layer, the expert bias and three counters
+of the last step (float32, so that the step's mean over replicas keeps
+their type): ``drawn`` (assignments each of the ``num_experts`` experts
+drew), ``held`` (assignments to held experts that were computed) and
+``dropped`` (assignments to held experts that were not: always 0).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from grace_tpu.models import layers as L
+from grace_tpu.telemetry.scopes import (STAGE_ATTENTION, STAGE_DENSE_FFN,
+                                        STAGE_LM_HEAD, STAGE_MOE_COMBINE,
+                                        STAGE_MOE_DISPATCH,
+                                        STAGE_MOE_EXPERTS, STAGE_MOE_ROUTER,
+                                        STAGE_SHORT_CONV)
+
+_PERIOD = ("full_attention", "conv", "conv", "conv")
+
+
+@dataclasses.dataclass(frozen=True)
+class Config:
+    """LFM2-24B-A2B as published, all of it held here, unless said
+    otherwise. ``vocab_size`` is the number of rows held."""
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = ("conv", "conv") + _PERIOD * 9 + (
+        "full_attention", "conv")
+    num_dense_layers: int = 2
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    first_expert: int = 0
+    experts_held: int = 64
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 64
+    conv_L_cache: int = 3
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    routed_scaling_factor: float = 1.0
+    # how the work is walked, not what is computed
+    seq_block: int = 1            # sequences recomputed together
+    attn_q_block: int = 1024      # queries scored together
+    moe_row_block: int = 0        # rows of one grouped product; 0: by load
+
+    def __post_init__(self):
+        if not 0 <= self.first_expert <= (self.num_experts
+                                          - self.experts_held):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.experts_held} are not "
+                f"among the router's {self.num_experts}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("query heads must divide over key/value heads")
+        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)}")
+
+    def is_moe(self, layer: int) -> bool:
+        return layer >= self.num_dense_layers
+
+
+def tiny(**kw) -> Config:
+    """Test-scale config: one dense layer and a period, 8 experts."""
+    d = dict(vocab_size=128, hidden_size=32, layer_types=("conv",) + _PERIOD,
+             num_dense_layers=1, intermediate_size=64,
+             moe_intermediate_size=16, num_experts=8, num_experts_per_tok=2,
+             experts_held=8, num_attention_heads=4, num_key_value_heads=2,
+             head_dim=8, attn_q_block=8, moe_row_block=16)
+    d.update(kw)
+    return Config(**d)
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
+
+def init(key: jax.Array, cfg: Config) -> Tuple[L.Params, L.ModelState]:
+    """Truncated normal (std 0.02) matrices, unit norm weights, a
+    convolution kernel uniform in +-1/sqrt(L), untied embedding and head,
+    expert bias zero."""
+    d, hd = cfg.hidden_size, cfg.head_dim
+    keys = iter(L.split_keys(key, 2 + 8 * len(cfg.layer_types)))
+
+    def mat(*shape):
+        return L.trunc_normal(next(keys), shape)
+
+    def layer(i, kind):
+        if kind == "conv":
+            bound = 1.0 / math.sqrt(cfg.conv_L_cache)
+            op = {"in_proj": mat(d, 3 * d),
+                  "kernel": jax.random.uniform(
+                      next(keys), (cfg.conv_L_cache, d), jnp.float32,
+                      -bound, bound),
+                  "out_proj": mat(d, d)}
+        else:
+            op = {"q_proj": mat(d, cfg.num_attention_heads * hd),
+                  "k_proj": mat(d, cfg.num_key_value_heads * hd),
+                  "v_proj": mat(d, cfg.num_key_value_heads * hd),
+                  "o_proj": mat(cfg.num_attention_heads * hd, d),
+                  "q_norm": L.rms_init(hd), "k_norm": L.rms_init(hd)}
+        if cfg.is_moe(i):
+            e, f = cfg.experts_held, cfg.moe_intermediate_size
+            ffn = {"router": mat(d, cfg.num_experts), "w1": mat(e, d, f),
+                   "w3": mat(e, d, f), "w2": mat(e, f, d)}
+        else:
+            f = cfg.intermediate_size
+            ffn = {"w1": mat(d, f), "w3": mat(d, f), "w2": mat(f, d)}
+        return {"op_norm": L.rms_init(d), "op": op,
+                "ffn_norm": L.rms_init(d), "ffn": ffn}
+
+    params = {"embed": L.embedding_init(next(keys), cfg.vocab_size, d),
+              "layers": [layer(i, kind)
+                         for i, kind in enumerate(cfg.layer_types)],
+              "final_norm": L.rms_init(d),
+              "head": mat(d, cfg.vocab_size)}
+    return params, init_state(cfg)
+
+
+def init_state(cfg: Config) -> L.ModelState:
+    def moe():
+        return {"expert_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+                "drawn": jnp.zeros((cfg.num_experts,), jnp.float32),
+                "held": jnp.zeros((), jnp.float32),
+                "dropped": jnp.zeros((), jnp.float32)}
+
+    return {"layers": [moe() if cfg.is_moe(i) else {}
+                       for i in range(len(cfg.layer_types))]}
+
+
+# ---------------------------------------------------------------------------
+# walking the batch in blocks
+# ---------------------------------------------------------------------------
+
+def _over_sequences(fn, p, x, block: int):
+    """``fn(p, x_block)`` over blocks of ``block`` sequences, one after
+    another, each recomputed from its input in the backward pass. ``x`` and
+    what ``fn`` returns are trees whose leaves lead with the sequences."""
+    fn = jax.checkpoint(fn)
+    n = jax.tree_util.tree_leaves(x)[0].shape[0]
+    if block >= n:
+        return fn(p, x)
+    if n % block:
+        raise ValueError(f"{n} sequences do not divide into blocks of {block}")
+    out = lax.map(lambda xb: fn(p, xb), jax.tree_util.tree_map(
+        lambda a: a.reshape(n // block, block, *a.shape[1:]), x))
+    return jax.tree_util.tree_map(
+        lambda y: y.reshape(n, *y.shape[2:]), out)
+
+
+def _dot(x, w):
+    return x @ w.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# operators
+# ---------------------------------------------------------------------------
+
+def short_conv(p, u, cfg: Config):
+    """The gated short convolution of normalised ``u``: ``(n, T, d)``."""
+    b, c, x = jnp.split(_dot(u, p["in_proj"]), 3, axis=-1)
+    z = b * x
+    taps = cfg.conv_L_cache
+    t = z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    kernel = p["kernel"].astype(z.dtype)
+    conv = sum(kernel[j] * zp[:, j:j + t] for j in range(taps))
+    return _dot(c * conv, p["out_proj"])
+
+
+def _scores_block(q, k, v, start):
+    """Causal attention of one block of queries, at positions ``start`` on,
+    over the keys up to the block's end. ``q``: ``(n, Q, Hkv, G, D)``;
+    ``k``, ``v``: ``(n, K, Hkv, D)``."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    s = jnp.einsum("nqhgd,nkhd->nhgqk", q, k).astype(jnp.float32) * scale
+    qpos = start + jnp.arange(q.shape[1])
+    mask = qpos[:, None] >= jnp.arange(k.shape[1])[None, :]
+    s = jnp.where(mask, s, -jnp.inf)
+    a = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("nhgqk,nkhd->nqhgd", a, v)
+
+
+def attention(p, u, cfg: Config):
+    """Grouped-query causal self-attention of normalised ``u``."""
+    n, t, _ = u.shape
+    hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    q = _dot(u, p["q_proj"]).reshape(n, t, hq, hd)
+    k = _dot(u, p["k_proj"]).reshape(n, t, hkv, hd)
+    v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
+    q = L.rotary(L.rms_apply(p["q_norm"], q, cfg.norm_eps), cfg.rope_theta)
+    k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta)
+    q = q.reshape(n, t, hkv, hq // hkv, hd)
+    block = jax.checkpoint(_scores_block, static_argnums=(3,))
+    out = [block(q[:, s:s + cfg.attn_q_block],
+                 k[:, :s + cfg.attn_q_block], v[:, :s + cfg.attn_q_block], s)
+           for s in range(0, t, cfg.attn_q_block)]
+    return _dot(jnp.concatenate(out, axis=1).reshape(n, t, hq * hd),
+                p["o_proj"])
+
+
+def dense_ffn(p, u):
+    return _dot(jax.nn.silu(_dot(u, p["w1"])) * _dot(u, p["w3"]), p["w2"])
+
+
+# ---------------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------------
+
+def route(p, bias, x, cfg: Config):
+    """``(experts, gates)`` of every token of ``x`` ``(N, d)``: ``(N, k)``
+    expert ids among all ``num_experts`` and their float32 weights."""
+    s = jax.nn.sigmoid(_dot(x, p["router"]).astype(jnp.float32))
+    _, experts = lax.top_k(s + bias, cfg.num_experts_per_tok)
+    # the chosen scores by a compare and a sum (a gather of N*k single
+    # numbers runs one at a time on the chip)
+    chosen = experts[..., None] == jnp.arange(cfg.num_experts)
+    g = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6)
+    return experts, g * cfg.routed_scaling_factor
+
+
+def _count(ids, bins: int):
+    """How often each of ``bins`` values occurs in ``ids`` (a compare and a
+    sum: a scatter of ones runs one add at a time on the chip)."""
+    return jnp.sum(ids[:, None] == jnp.arange(bins)[None, :], axis=0,
+                   dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def _sorted(v, order, inverse):
+    """``v[order]`` for a permutation ``order``; backwards ``g[inverse]``, a
+    gather where differentiating the gather would scatter."""
+    return jnp.take(v, order, axis=0)
+
+
+_sorted.defvjp(
+    lambda v, order, inverse: (jnp.take(v, order, axis=0), inverse),
+    lambda inverse, g: (jnp.take(g, inverse, axis=0), None, None))
+
+
+def _row_block(cfg: Config, tokens: int) -> int:
+    """Rows of one grouped product: ``moe_row_block``, or twice the load of
+    a balanced router, in whole tiles of 512. Twice, because a router
+    trained on a share learns to prefer the experts that answer: the load
+    here grew by a quarter in 45 steps (PERF.md, PR 28), and a step's time
+    should not jump when it crosses a block's end. Small blocks are no way
+    out as the layer stands: a block of 2,048 rows costs 1.7 times as much
+    a row on the chip (each block's backward pass adds the whole float32
+    weight-gradient stacks once more), so the step came out 1.3 % slower
+    than with this default, and less steady."""
+    if cfg.moe_row_block:
+        return cfg.moe_row_block
+    mean = tokens * cfg.num_experts_per_tok * cfg.experts_held / cfg.num_experts
+    return max(512, -(-int(2 * mean) // 512) * 512)
+
+
+def _expert_rows(w, rows, gates, sizes, n_valid):
+    """The held experts' gated feed-forward of one block of sorted rows,
+    weighted. ``sizes``: rows of each held expert in this block, all of
+    the block's rows among them; the rows from ``n_valid`` on are no
+    token's, go in as zeros and are held at zero."""
+    valid = (jnp.arange(rows.shape[0]) < n_valid)[:, None]
+
+    def grouped(x, w):
+        return jnp.where(valid, lax.ragged_dot(x, w, sizes), 0)
+
+    rows = jnp.where(valid, rows, 0)
+    h = jax.nn.silu(grouped(rows, w["w1"])) * grouped(rows, w["w3"])
+    return grouped(h, w["w2"]) * gates[:, None].astype(rows.dtype)
+
+
+def _blocks(tokens, total, visit, carry):
+    """``visit(c, n_valid, carry)`` for every block of sorted rows that holds
+    a row; the blocks past ``total`` rows are skipped, so the work follows
+    the rows there are and not the bound on them."""
+    n_blocks, block = tokens.shape
+
+    def body(c, carry):
+        n_valid = jnp.clip(total - c * block, 0, block)
+        return lax.cond(n_valid > 0, lambda carry: visit(c, n_valid, carry),
+                        lambda carry: carry, carry)
+
+    return lax.fori_loop(0, n_blocks, body, carry)
+
+
+def _cast(w, dtype):
+    return jax.tree_util.tree_map(lambda a: a.astype(dtype), w)
+
+
+@jax.custom_vjp
+def held_experts(w, x, tokens, gates, sizes, total):
+    """Sum over the sorted rows of the held experts' weighted results, by
+    token: ``(N, d)``. ``tokens``, ``gates``: ``(blocks, rows)``, the token
+    and the weight of each sorted row; ``sizes``: ``(blocks, experts held)``;
+    ``total``: the rows in use. Forward gathers a block's rows, runs the
+    grouped products and adds the results to their tokens; backward does
+    the same the other way round, recomputing the block."""
+    wa = _cast(w, x.dtype)
+
+    def visit(c, n_valid, y):
+        with jax.named_scope(STAGE_MOE_DISPATCH):
+            rows = jnp.take(x, tokens[c], axis=0)
+        with jax.named_scope(STAGE_MOE_EXPERTS):
+            out = _expert_rows(wa, rows, gates[c], sizes[c], n_valid)
+        with jax.named_scope(STAGE_MOE_COMBINE):
+            return y.at[tokens[c]].add(out.astype(jnp.float32))
+
+    y = _blocks(tokens, total, visit, jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _held_experts_fwd(w, x, tokens, gates, sizes, total):
+    return (held_experts(w, x, tokens, gates, sizes, total),
+            (w, x, tokens, gates, sizes, total))
+
+
+def _held_experts_bwd(res, dy):
+    w, x, tokens, gates, sizes, total = res
+    wa = _cast(w, x.dtype)
+
+    def visit(c, n_valid, carry):
+        dw, dx, dgates = carry
+        with jax.named_scope(STAGE_MOE_DISPATCH):
+            rows = jnp.take(x, tokens[c], axis=0)
+        with jax.named_scope(STAGE_MOE_COMBINE):
+            dout = jnp.take(dy, tokens[c], axis=0)
+        with jax.named_scope(STAGE_MOE_EXPERTS):
+            _, pull = jax.vjp(
+                lambda wa, rows, g: _expert_rows(wa, rows, g, sizes[c],
+                                                 n_valid),
+                wa, rows, gates[c])
+            dwa, drows, dg = pull(dout)
+            dw = jax.tree_util.tree_map(
+                lambda a, b: a + b.astype(jnp.float32), dw, dwa)
+        with jax.named_scope(STAGE_MOE_DISPATCH):
+            dx = dx.at[tokens[c]].add(drows.astype(jnp.float32))
+        return dw, dx, dgates.at[c].set(dg)
+
+    dw, dx, dgates = _blocks(
+        tokens, total, visit,
+        (jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32), w),
+         jnp.zeros(x.shape, jnp.float32), jnp.zeros(gates.shape, gates.dtype)))
+    return (jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), dw, w),
+            dx.astype(x.dtype), None, dgates, None, None)
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def _route_and_sort(p, state, u, cfg: Config):
+    """Route the tokens ``u`` ``(N, d)`` and sort their assignments by held
+    expert: what :func:`held_experts` takes, and the layer's counters."""
+    k, held = cfg.num_experts_per_tok, cfg.experts_held
+    n_slots = u.shape[0] * k
+    block = _row_block(cfg, u.shape[0])
+    n_rows = -(-n_slots // block) * block         # the bound, in whole blocks
+    with jax.named_scope(STAGE_MOE_ROUTER):
+        experts, gates = route(p, state["expert_bias"], u, cfg)
+        drawn = _count(experts.reshape(-1), cfg.num_experts)
+    with jax.named_scope(STAGE_MOE_DISPATCH):
+        local = experts.reshape(-1) - cfg.first_expert
+        here = (local >= 0) & (local < held)
+        group = jnp.where(here, local, held)          # the rest sort last
+        slot_of_row = jnp.argsort(group, stable=True)
+        row_of_slot = jnp.argsort(slot_of_row)
+        ends = jnp.cumsum(_count(group, held + 1))[:held]
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+        # rows of each held expert inside each block of the grouped product
+        lo = jnp.arange(0, n_rows, block)[:, None]
+        sizes = (jnp.clip(ends, lo, lo + block)
+                 - jnp.clip(starts, lo, lo + block))
+        computed = jnp.sum(sizes).astype(jnp.float32)
+        # A visited block's rows past the load go to the last expert as
+        # zeros, so that a block costs the same however full it is and the
+        # step's time does not follow the router's drift.
+        sizes = sizes.at[:, -1].add(block - jnp.sum(sizes, axis=1))
+        pad = (0, n_rows - n_slots)
+        tokens = jnp.pad(slot_of_row // k, pad).reshape(-1, block)
+        row_gates = jnp.pad(_sorted(gates.reshape(-1), slot_of_row,
+                                    row_of_slot), pad).reshape(-1, block)
+    assigned = jnp.sum(here).astype(jnp.float32)
+    counters = {"expert_bias": state["expert_bias"],
+                "drawn": drawn.astype(jnp.float32), "held": computed,
+                "dropped": assigned - computed}
+    return (tokens, row_gates, sizes, ends[-1]), counters
+
+
+def moe_ffn(p, state, u, cfg: Config):
+    """The held experts' part of the expert layer's result for normalised
+    ``u`` ``(n, T, d)``, and the layer's new state (the counters)."""
+    x = u.reshape(-1, u.shape[-1])
+    sorted_rows, counters = _route_and_sort(p, state, x, cfg)
+    y = held_experts({k: p[k] for k in ("w1", "w3", "w2")}, x, *sorted_rows)
+    return y.reshape(u.shape), counters
+
+
+# ---------------------------------------------------------------------------
+# the model
+# ---------------------------------------------------------------------------
+
+def _operator_part(kind, cfg):
+    stage = STAGE_SHORT_CONV if kind == "conv" else STAGE_ATTENTION
+    op = short_conv if kind == "conv" else attention
+
+    def part(p, x):
+        with jax.named_scope(stage):
+            return x + op(p["op"], L.rms_apply(p["op_norm"], x, cfg.norm_eps),
+                          cfg)
+    return part
+
+
+def _dense_part(cfg):
+    def part(p, x):
+        with jax.named_scope(STAGE_DENSE_FFN):
+            return x + dense_ffn(
+                p["ffn"], L.rms_apply(p["ffn_norm"], x, cfg.norm_eps))
+    return part
+
+
+def _moe_part(cfg):
+    def part(p, state, x):
+        u = L.rms_apply(p["ffn_norm"], x, cfg.norm_eps)
+        y, state = moe_ffn(p["ffn"], state, u, cfg)
+        return x + y, state
+    return part
+
+
+def hidden_states(params, model_state, ids, cfg: Config,
+                  dtype=jnp.float32):
+    """ids ``(n, T)`` -> the last layer's output ``(n, T, d)`` (before the
+    final norm) and the new model state."""
+    x = L.embedding_apply(params["embed"], ids, dtype=dtype)
+    new_state = []
+    for i, (kind, p, s) in enumerate(zip(cfg.layer_types, params["layers"],
+                                         model_state["layers"])):
+        x = _over_sequences(_operator_part(kind, cfg), p, x, cfg.seq_block)
+        if cfg.is_moe(i):
+            # recomputed from x; the experts' own forward is not needed
+            # again (their backward recomputes block by block) and falls away
+            x, s = jax.checkpoint(_moe_part(cfg))(p, s, x)
+        else:
+            x = _over_sequences(_dense_part(cfg), p, x, cfg.seq_block)
+        new_state.append(s)
+    return x, {"layers": new_state}
+
+
+def _head_part(cfg):
+    def part(p, xt):
+        x, targets = xt
+        with jax.named_scope(STAGE_LM_HEAD):
+            u = L.rms_apply(p["final_norm"], x, cfg.norm_eps)
+            logits = _dot(u, p["head"]).astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.sum(nll[..., 0], axis=-1)
+    return part
+
+
+def next_token_loss(params, model_state, ids, cfg: Config,
+                    dtype=jnp.float32):
+    """Mean over all tokens of the cross-entropy of position ``t``'s logits
+    against token ``t + 1`` (a sequence's last position has no target):
+    ``(loss, new_model_state)``."""
+    x, new_state = hidden_states(params, model_state, ids, cfg, dtype)
+    n, t = ids.shape
+    sums = _over_sequences(
+        _head_part(cfg),
+        {"final_norm": params["final_norm"], "head": params["head"]},
+        (x[:, :-1], ids[:, 1:]), cfg.seq_block)
+    return jnp.sum(sums) / (n * (t - 1)), new_state

@@ -8,6 +8,10 @@ PowerSGD on its 2-D weight matrices is the intended pairing.
 
 Masked-LM head included so examples can train on real objectives; the bench
 path uses sequence classification over pooled [CLS].
+
+An encoder with learned positions, LayerNorm and GELU; the causal decoder
+(RMSNorm, rotary positions, gated feed-forward, experts, next-token loss) is
+``models/lfm2.py``.
 """
 
 from __future__ import annotations

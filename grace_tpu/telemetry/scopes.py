@@ -30,7 +30,10 @@ __all__ = ["trace_stage", "match_stage", "ALL_STAGES",
            "STAGE_FWD_BWD", "STAGE_OPTIMIZER", "STAGE_APPLY",
            "STAGE_TELEMETRY", "STAGE_DENSE_ESCAPE", "STAGE_CONSENSUS",
            "STAGE_RING_HOP", "STAGE_WATCH", "STAGE_BUCKET", "STAGE_ADAPT",
-           "STAGE_PIPELINE"]
+           "STAGE_PIPELINE", "STAGE_ATTENTION", "STAGE_SHORT_CONV",
+           "STAGE_DENSE_FFN", "STAGE_MOE_ROUTER", "STAGE_MOE_DISPATCH",
+           "STAGE_MOE_EXPERTS", "STAGE_MOE_COMBINE", "STAGE_LM_HEAD",
+           "MODEL_STAGES"]
 
 # Canonical stage names — one vocabulary for the profiler, the report tool,
 # and the docs. Keep in sync with README "Observability".
@@ -77,6 +80,27 @@ STAGE_ADAPT = "grace/adapt"
 # nest inside it; match_stage's rightmost rule still attributes their ops
 # to ring_hop/exchange as before.
 STAGE_PIPELINE = "grace/pipeline"
+# The parts of a model's forward and backward pass (models/lfm2.py): they
+# nest inside STAGE_FWD_BWD, and the rightmost rule attributes a part's
+# forward, recomputed and backward operations to it, so what is left
+# under "grace/forward_backward" is what no part names (embedding,
+# residual adds). Lower case and underscores only: the benchmark's
+# reducer reads ``grace/[a-z_]+``. One exception, measured on the chip
+# (PERF.md, PR 28): XLA runs ``lax.ragged_dot`` as a kernel of its own
+# naming (``ragged-dot-none``) that keeps no scope, so in a device trace
+# the grouped products stand under no stage, and "grace/moe_experts" holds
+# only what is traced around them (row masks, activation, gate weights).
+STAGE_ATTENTION = "grace/attention"
+STAGE_SHORT_CONV = "grace/short_conv"
+STAGE_DENSE_FFN = "grace/dense_ffn"
+STAGE_MOE_ROUTER = "grace/moe_router"
+STAGE_MOE_DISPATCH = "grace/moe_dispatch"      # sort, gather
+STAGE_MOE_EXPERTS = "grace/moe_experts"        # masks, activation, gates
+STAGE_MOE_COMBINE = "grace/moe_combine"
+STAGE_LM_HEAD = "grace/lm_head"                # final norm, head, loss
+MODEL_STAGES = (STAGE_ATTENTION, STAGE_SHORT_CONV, STAGE_DENSE_FFN,
+                STAGE_MOE_ROUTER, STAGE_MOE_DISPATCH, STAGE_MOE_EXPERTS,
+                STAGE_MOE_COMBINE, STAGE_LM_HEAD)
 
 # The canonical stage vocabulary, longest-prefix-matchable: the profiler,
 # tools/telemetry_report.py, and the static auditor's finding attribution
@@ -88,7 +112,7 @@ ALL_STAGES = tuple(sorted(
     (STAGE_COMPENSATE, STAGE_COMPRESS, STAGE_EXCHANGE, STAGE_DECOMPRESS,
      STAGE_MEMORY_UPDATE, STAGE_FWD_BWD, STAGE_OPTIMIZER, STAGE_APPLY,
      STAGE_TELEMETRY, STAGE_DENSE_ESCAPE, STAGE_CONSENSUS, STAGE_RING_HOP,
-     STAGE_WATCH, STAGE_BUCKET, STAGE_ADAPT, STAGE_PIPELINE),
+     STAGE_WATCH, STAGE_BUCKET, STAGE_ADAPT, STAGE_PIPELINE, *MODEL_STAGES),
     key=len, reverse=True))
 
 

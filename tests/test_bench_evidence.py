@@ -379,9 +379,15 @@ def test_worker_platform_rejects_other_names():
         bench.worker_platform(["bench.py", "--_worker", "gpu"])
 
 
-def test_setup_platform_tpu_fails_without_a_tpu():
+def test_setup_platform_tpu_fails_without_a_tpu(monkeypatch):
     # Under the test harness the first device is a CPU: the chip path must
-    # exit, not substitute the CPU mesh.
+    # exit, not substitute the CPU mesh. It places the persistent compile
+    # cache before it looks at the devices; kept out of here, or every later
+    # compile of this worker process is written to ``.jax_cache`` and counted
+    # as a cache miss by the compile ledger, whichever test it belongs to.
+    from grace_tpu.utils import compile_cache
+    monkeypatch.setattr(compile_cache, "place_compile_cache",
+                        lambda platform: None)
     with pytest.raises(SystemExit, match="not a TPU"):
         bench.setup_platform("tpu")
 

@@ -1,0 +1,518 @@
+"""``grace_tpu.models.lfm2`` against the plain reference
+(``benchmarks/reference/lfm2_moe.py``) at a small size on the CPU, and the
+properties the model promises: the shares of an expert layer add up to the
+whole layer, no assignment is dropped however skewed the router, the short
+convolution reads neither across a sequence's start nor from the future,
+and walking the work in blocks changes nothing.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmarks.models import lfm2_moe as builder  # noqa: E402
+from benchmarks.reference import lfm2_moe as plain  # noqa: E402
+from benchmarks.trace_reduce import STAGE, stage_of  # noqa: E402
+from grace_tpu.models import layers as L  # noqa: E402
+from grace_tpu.models import lfm2  # noqa: E402
+from grace_tpu.telemetry import scopes  # noqa: E402
+
+# A share of a small model in the configuration file's own keys: 2 experts
+# held (experts 2 and 3) of the 8 the router scores, 2 a token.
+SIZES = {
+    "conv_L_cache": 3, "hidden_size": 32, "intermediate_size": 64,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "moe_intermediate_size": 16, "norm_eps": 1e-5, "num_attention_heads": 4,
+    "num_dense_layers": 1, "num_experts": 2, "num_experts_per_tok": 2,
+    "num_hidden_layers": 5, "num_key_value_heads": 2,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "routed_scaling_factor": 1, "vocab_size": 128,
+    "published": {"num_experts": 8}, "share": 1,
+    "layers_held": [0, 2, 3, 4, 5], "seq_length": 16, "per_chip_batch": 4,
+    "activation_dtype": "float32", "param_dtype": "float32"}
+# How the program walks the work here (``lfm2.Config``'s block sizes; the
+# benchmark's builder leaves them at their defaults): several blocks of each
+# kind at this size.
+WALK = {"attn_q_block": 8, "moe_row_block": 32, "seq_block": 2}
+GROUPS = ["embed", "final_norm", "head"] + [f"layers/{i}" for i in range(5)]
+
+
+def _program_loss(sizes, **walk):
+    """The builder's ``program_loss`` with other block sizes."""
+    cfg = dataclasses.replace(builder.model_config(sizes), **{**WALK, **walk})
+    dtype = jnp.dtype(sizes["activation_dtype"])
+    return lambda params, mstate, batch: lfm2.next_token_loss(
+        params, mstate, batch, cfg, dtype=dtype)
+
+
+def _both(sizes, key=1):
+    """Loss and gradients of the program and of the reference on the same
+    seeded weights and batch, at float32 'highest'."""
+    with jax.default_matmul_precision("highest"):
+        params, state = builder.init(jax.random.key(key), sizes)
+        ids = builder.make_batch(jax.random.key(key + 1),
+                                 sizes["per_chip_batch"], sizes)
+        out = []
+        for loss_fn in (_program_loss(sizes),
+                        builder.reference_loss(sizes)):
+            (loss, new_state), grads = jax.jit(jax.value_and_grad(
+                loss_fn, has_aux=True))(params, state, ids)
+            out.append((float(loss), grads, new_state))
+    return out
+
+
+@pytest.fixture(scope="module")
+def float32_pair():
+    return _both(SIZES)
+
+
+def _rel(a, b):
+    """Largest difference of a leaf over the reference leaf's largest
+    entry."""
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+def _group(tree, name):
+    for part in name.split("/"):
+        tree = tree[int(part)] if part.isdigit() else tree[part]
+    return tree
+
+
+# Tolerances. In float32 at 'highest' the two differ only in the order of
+# sums (grouped product against one product per expert, a sum over four
+# chosen rows against a sum over eight masked ones, blocks of queries
+# against whole rows of scores): a few units of 2**-24 per sum, through
+# five layers. 2e-5 relative is ~300 such units; the bfloat16 run below
+# is a thousand times over it.
+LOSS_TOL = 2e-6
+GRAD_TOL = 2e-5
+
+
+def test_loss_agrees_with_the_plain_reference(float32_pair):
+    (got, _, _), (want, _, _) = float32_pair
+    assert abs(got - want) <= LOSS_TOL * abs(want)
+    assert 4.0 < want < 6.0                      # ln 128 = 4.85 at the start
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_every_leafs_gradient_agrees_with_the_plain_reference(
+        float32_pair, group):
+    (_, got, _), (_, want, _) = float32_pair
+    gaps = jax.tree_util.tree_map(_rel, _group(got, group),
+                                  _group(want, group))
+    flat = jax.tree_util.tree_flatten_with_path(gaps)[0]
+    assert flat and all(g <= GRAD_TOL for _, g in flat), flat
+    assert all(float(jnp.max(jnp.abs(w))) > 0 for w in
+               jax.tree_util.tree_leaves(_group(want, group)))
+
+
+def test_a_bfloat16_run_is_outside_the_tolerances(float32_pair):
+    """The tolerances are tight enough that the program computed in a
+    lower precision than the test states fails one of them."""
+    (_, _, _), (want_loss, want, _) = float32_pair
+    with jax.default_matmul_precision("highest"):
+        params, state = builder.init(jax.random.key(1), SIZES)
+        ids = builder.make_batch(jax.random.key(2), 4, SIZES)
+        low = dict(SIZES, activation_dtype="bfloat16")
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            _program_loss(low), has_aux=True))(params, state, ids)
+    gaps = jax.tree_util.tree_leaves(jax.tree_util.tree_map(_rel, grads, want))
+    assert (abs(float(loss) - want_loss) > LOSS_TOL * want_loss
+            or max(gaps) > GRAD_TOL)
+    assert max(gaps) > 50 * GRAD_TOL
+
+
+def test_the_program_reads_the_tree_the_benchmark_makes():
+    cfg = builder.model_config(SIZES)
+    own, own_state = jax.eval_shape(lambda k: lfm2.init(k, cfg),
+                                    jax.random.key(0))
+    made, made_state = jax.eval_shape(lambda k: builder.init(k, SIZES),
+                                      jax.random.key(0))
+    assert (jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
+            == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made))
+    assert (jax.tree_util.tree_structure(own_state)
+            == jax.tree_util.tree_structure(made_state))
+    assert len(jax.tree_util.tree_leaves(own)) == 50
+
+
+def test_the_published_configuration_counts_its_parameters():
+    """The share the benchmark's configuration states: 486,062,208
+    parameters in 50 leaves, 62 % of them in expert stacks."""
+    import json
+    import math
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "lfm2-24b-a2b-ep8.json")) as f:
+        sizes = json.load(f)
+    cfg = builder.model_config(sizes)
+    assert (cfg.hidden_size, cfg.intermediate_size,
+            cfg.moe_intermediate_size) == (2048, 11776, 1536)
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim) == (32, 8, 64)
+    assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.experts_held,
+            cfg.first_expert, cfg.conv_L_cache) == (64, 4, 8, 0, 3)
+    assert cfg.layer_types == ("conv", "full_attention", "conv", "conv",
+                               "conv")
+    shapes = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda k: lfm2.init(k, cfg)[0], jax.random.key(0)))
+    counts = [math.prod(s.shape) for s in shapes]
+    assert len(counts) == 50 and sum(counts) == sizes["parameters_held"]
+    assert sum(counts) == 486_062_208
+    assert max(counts) == 8 * 2048 * 1536
+    assert sum(c for c, s in zip(counts, shapes) if len(s.shape) == 3) \
+        == 301_989_888
+    # the whole published model through the same Config: 40 layers
+    whole = lfm2.Config()
+    assert len(whole.layer_types) == 40
+    assert whole.layer_types.count("full_attention") == 10
+    assert whole.layer_types[:6] == ("conv", "conv", "full_attention",
+                                     "conv", "conv", "conv")
+
+
+# ---------------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------------
+
+def _expert_layer(key):
+    """One expert layer's weights for all 8 experts, a normalised input,
+    and the sizes of the uncut layer."""
+    sizes = dict(SIZES, num_experts=8, share=0)
+    d, f = 32, 16
+    ks = jax.random.split(jax.random.key(key), 6)
+    whole = {"router": jax.random.normal(ks[0], (d, 8)) * 0.3,
+             "w1": jax.random.normal(ks[1], (8, d, f)) * 0.2,
+             "w3": jax.random.normal(ks[2], (8, d, f)) * 0.2,
+             "w2": jax.random.normal(ks[3], (8, f, d)) * 0.2}
+    u = jax.random.normal(ks[4], (3, 16, d))
+    bias = jax.random.normal(ks[5], (8,)) * 0.1
+    return sizes, whole, u, bias
+
+
+def _share_of(whole, first, held):
+    return {"router": whole["router"],
+            **{k: whole[k][first:first + held] for k in ("w1", "w3", "w2")}}
+
+
+def _state(bias):
+    return {"expert_bias": bias, "drawn": jnp.zeros((8,)),
+            "held": jnp.zeros(()), "dropped": jnp.zeros(())}
+
+
+@pytest.mark.parametrize("shares", [1, 2, 4, 8])
+def test_the_shares_partial_results_add_up_to_the_whole_layer(shares):
+    """Every chip of ``shares`` computes its own experts' part; the parts
+    add up to what the uncut plain reference gives for the whole layer
+    (nothing is computed alike by all, so nothing is counted twice)."""
+    sizes, whole, u, bias = _expert_layer(3)
+    held = 8 // shares
+    with jax.default_matmul_precision("highest"):
+        want = jax.vmap(lambda x: plain._experts(
+            whole, bias, x, sizes, first=0))(u)
+        total, computed = jnp.zeros_like(u), 0.0
+        for share in range(shares):
+            cfg = lfm2.tiny(first_expert=share * held, experts_held=held,
+                            moe_row_block=24)
+            y, counters = lfm2.moe_ffn(
+                _share_of(whole, share * held, held), _state(bias), u, cfg)
+            total = total + y
+            computed += float(counters["held"])
+            assert float(counters["dropped"]) == 0.0
+            np.testing.assert_array_equal(counters["expert_bias"], bias)
+    np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-6)
+    assert computed == u.shape[0] * u.shape[1] * 2     # every assignment once
+    assert float(jnp.max(jnp.abs(want))) > 1e-3
+
+
+def test_a_share_leaves_out_what_the_absent_experts_would_add():
+    sizes, whole, u, bias = _expert_layer(4)
+    cfg = lfm2.tiny(first_expert=2, experts_held=2)
+    part = dict(sizes, num_experts=2, share=1)
+    with jax.default_matmul_precision("highest"):
+        got, counters = lfm2.moe_ffn(_share_of(whole, 2, 2), _state(bias), u,
+                                     cfg)
+        want = jax.vmap(lambda x: plain._experts(
+            _share_of(whole, 2, 2), bias, x, part,
+            first=plain.layout(part)["first"]))(u)
+        full = jax.vmap(lambda x: plain._experts(
+            whole, bias, x, dict(sizes), first=0))(u)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert float(jnp.max(jnp.abs(got - full))) > 1e-3
+    # the counters: what the router drew over all 8, what was held here
+    experts, _ = lfm2.route(_share_of(whole, 2, 2), bias,
+                            u.reshape(-1, 32), cfg)
+    np.testing.assert_array_equal(
+        counters["drawn"], np.bincount(np.asarray(experts).ravel(),
+                                       minlength=8))
+    assert float(counters["drawn"].sum()) == 3 * 16 * 2
+    assert float(counters["held"]) == float(
+        ((experts >= 2) & (experts < 4)).sum())
+
+
+@pytest.mark.parametrize("target,row_block", [(0, 96), (1, 32), (1, 8)])
+def test_no_assignment_is_dropped_under_a_skewed_router(target, row_block):
+    """A bias that sends every token to the held expert ``target`` first:
+    all 48 tokens' rows land in one group, past any balanced capacity, and
+    every one of them is computed."""
+    sizes, whole, u, _ = _expert_layer(5)
+    bias = jnp.zeros((8,)).at[2 + target].set(10.0)
+    cfg = lfm2.tiny(first_expert=2, experts_held=2, moe_row_block=row_block)
+    part = dict(sizes, num_experts=2, share=1)
+    with jax.default_matmul_precision("highest"):
+        got, counters = jax.jit(lambda p, s, x: lfm2.moe_ffn(p, s, x, cfg))(
+            _share_of(whole, 2, 2), _state(bias), u)
+        want = jax.vmap(lambda x: plain._experts(
+            _share_of(whole, 2, 2), bias, x, part, first=2))(u)
+    assert float(counters["drawn"][2 + target]) == 48.0
+    assert float(counters["held"]) >= 48.0
+    assert float(counters["dropped"]) == 0.0
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_every_token_to_held_experts_fills_the_worst_case_bound():
+    """Both of a token's experts held here: the grouped product's rows are
+    all in use, which is the bound, and none is dropped."""
+    sizes, whole, u, _ = _expert_layer(6)
+    bias = jnp.zeros((8,)).at[jnp.array([4, 5])].set(10.0)
+    cfg = lfm2.tiny(first_expert=4, experts_held=2, moe_row_block=32)
+    _, counters = lfm2.moe_ffn(_share_of(whole, 4, 2), _state(bias), u, cfg)
+    assert float(counters["held"]) == 48 * 2 and float(
+        counters["dropped"]) == 0.0
+
+
+def test_expert_gradients_agree_and_the_bias_takes_none():
+    sizes, whole, u, bias = _expert_layer(7)
+    cfg = lfm2.tiny(first_expert=2, experts_held=2, moe_row_block=24)
+    part = dict(sizes, num_experts=2, share=1)
+    share = _share_of(whole, 2, 2)
+
+    def program(p, b, x):
+        return jnp.sum(jnp.sin(lfm2.moe_ffn(p, _state(b), x, cfg)[0]))
+
+    def reference(p, b, x):
+        return jnp.sum(jnp.sin(jax.vmap(lambda row: plain._experts(
+            p, b, row, part, first=2))(x)))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(program, argnums=(0, 1, 2))(share, bias, u)
+        want = jax.grad(reference, argnums=(0, 1, 2))(share, bias, u)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-6)
+    assert float(jnp.max(jnp.abs(got[1]))) == 0.0     # not trained by it
+    assert float(jnp.max(jnp.abs(got[0]["router"]))) > 0
+
+
+def test_a_share_outside_the_routers_experts_is_refused():
+    with pytest.raises(ValueError, match="not among"):
+        lfm2.tiny(first_expert=7, experts_held=2)
+    with pytest.raises(ValueError, match="unknown layer types"):
+        lfm2.tiny(layer_types=("conv", "mamba"))
+
+
+# ---------------------------------------------------------------------------
+# the short convolution, attention, the walk
+# ---------------------------------------------------------------------------
+
+def _conv_weights(key, d=8):
+    ks = jax.random.split(jax.random.key(key), 4)
+    return {"in_proj": jax.random.normal(ks[0], (d, 3 * d)),
+            "kernel": jax.random.normal(ks[1], (3, d)),
+            "out_proj": jax.random.normal(ks[2], (d, d))}, ks[3]
+
+
+def test_short_convolution_reads_neither_the_future_nor_another_sequence():
+    cfg = lfm2.tiny(hidden_size=8)
+    p, key = _conv_weights(11)
+    u = jax.random.normal(key, (2, 10, 8))
+    base = lfm2.short_conv(p, u, cfg)
+    later = lfm2.short_conv(p, u.at[:, 6:].add(1.0), cfg)
+    np.testing.assert_array_equal(base[:, :6], later[:, :6])
+    assert float(jnp.max(jnp.abs(base[:, 6:] - later[:, 6:]))) > 0
+    # the other sequence of the batch changes nothing here
+    other = lfm2.short_conv(p, u.at[1].add(1.0), cfg)
+    np.testing.assert_array_equal(base[0], other[0])
+    # position t reads t-2..t and no further back
+    back = lfm2.short_conv(p, u.at[:, 2].add(1.0), cfg)
+    np.testing.assert_array_equal(base[:, 5:], back[:, 5:])
+    assert float(jnp.max(jnp.abs(base[:, 4] - back[:, 4]))) > 0
+
+
+def test_short_convolution_starts_each_sequence_on_zeros():
+    """Two sequences laid end to end as one differ from the two apart at
+    the second one's first two positions, and only there: nothing of a
+    sequence reaches the next one of the batch."""
+    cfg = lfm2.tiny(hidden_size=8)
+    p, key = _conv_weights(12)
+    u = jax.random.normal(key, (2, 6, 8))
+    apart = lfm2.short_conv(p, u, cfg)
+    joined = lfm2.short_conv(p, u.reshape(1, 12, 8), cfg)[0]
+    np.testing.assert_allclose(apart[0], joined[:6], rtol=1e-6)
+    np.testing.assert_allclose(apart[1, 2:], joined[8:], rtol=1e-6)
+    assert float(jnp.max(jnp.abs(apart[1, :2] - joined[6:8]))) > 1e-3
+    want = jax.vmap(lambda x: plain._conv(p, x, {"conv_L_cache": 3}))(u)
+    np.testing.assert_allclose(apart, want, rtol=1e-5, atol=1e-6)
+
+
+def test_the_model_is_causal():
+    cfg = lfm2.tiny()
+    params, state = lfm2.init(jax.random.key(0), cfg)
+    ids = jax.random.randint(jax.random.key(1), (2, 16), 0, cfg.vocab_size)
+    base, _ = lfm2.hidden_states(params, state, ids, cfg)
+    later, _ = lfm2.hidden_states(
+        params, state, ids.at[:, 9:].set((ids[:, 9:] + 1) % cfg.vocab_size),
+        cfg)
+    np.testing.assert_array_equal(base[:, :9], later[:, :9])
+    assert float(jnp.max(jnp.abs(base[:, 9:] - later[:, 9:]))) > 0
+
+
+def test_attention_agrees_with_the_plain_reference():
+    cfg = lfm2.tiny(attn_q_block=4)
+    p = lfm2.init(jax.random.key(2), cfg)[0]["layers"][1]["op"]
+    p = jax.tree_util.tree_map(
+        lambda x: x * 8 if x.ndim == 2 else x + 0.1 * jnp.arange(x.size), p)
+    u = jax.random.normal(jax.random.key(3), (2, 16, 32))
+    sizes = dict(SIZES)
+    with jax.default_matmul_precision("highest"):
+        got = lfm2.attention(p, u, cfg)
+        want = jax.vmap(lambda x: plain._attention(p, x, sizes, 8))(u)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_rotary_positions_rotate_half_the_whole_head():
+    x = jax.random.normal(jax.random.key(4), (5, 2, 8))
+    got = L.rotary(x, 1e6)
+    np.testing.assert_allclose(got, plain._rotate(x, 1e6), rtol=1e-6)
+    np.testing.assert_allclose(got[0], x[0], rtol=1e-6)      # position 0
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    # the dot of a rotated query and key depends on their distance alone
+    q = jnp.broadcast_to(x[:1], x.shape)
+    r = L.rotary(q, 1e6)
+    np.testing.assert_allclose(jnp.sum(r[1] * r[3]), jnp.sum(r[2] * r[4]),
+                               rtol=1e-5)
+
+
+def test_rms_norm():
+    x = jax.random.normal(jax.random.key(5), (3, 16)) * 4
+    p = {"scale": jnp.linspace(0.5, 2.0, 16)}
+    got = L.rms_apply(p, x, 1e-5)
+    want = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * p["scale"]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert L.rms_apply(p, x.astype(jnp.bfloat16)).dtype == jnp.bfloat16
+    assert set(L.rms_init(16)) == {"scale"}
+
+
+@pytest.mark.parametrize("walk", [
+    {"seq_block": 1}, {"seq_block": 4}, {"attn_q_block": 16},
+    {"attn_q_block": 4}, {"moe_row_block": 8}, {"moe_row_block": 128},
+    {"moe_row_block": 0}])
+def test_walking_the_work_in_other_blocks_changes_nothing(walk, float32_pair):
+    (want_loss, want, _), _ = float32_pair
+    with jax.default_matmul_precision("highest"):
+        params, state = builder.init(jax.random.key(1), SIZES)
+        ids = builder.make_batch(jax.random.key(2), 4, SIZES)
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            _program_loss(SIZES, **walk), has_aux=True))(params, state, ids)
+    assert abs(float(loss) - want_loss) <= LOSS_TOL * want_loss
+    gaps = jax.tree_util.tree_leaves(jax.tree_util.tree_map(_rel, grads, want))
+    assert max(gaps) <= GRAD_TOL
+
+
+def test_blocks_that_do_not_divide_the_batch_are_refused():
+    cfg = lfm2.tiny(seq_block=3)
+    params, state = lfm2.init(jax.random.key(0), cfg)
+    ids = jnp.zeros((4, 8), jnp.int32)
+    with pytest.raises(ValueError, match="do not divide"):
+        lfm2.next_token_loss(params, state, ids, cfg)
+
+
+# ---------------------------------------------------------------------------
+# on the normal path: the compressed training step, its counters, its scopes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def trained():
+    """Four steps of ``make_stateful_train_step`` under the top-k
+    transform and AdamW, on the CPU's devices."""
+    import optax
+    from grace_tpu import data_parallel_mesh, grace_from_params
+    from grace_tpu.train import (init_stateful_train_state,
+                                 make_stateful_train_step)
+
+    sizes = dict(SIZES, activation_dtype="bfloat16")
+    mesh = data_parallel_mesh()
+    world = mesh.devices.size
+    grace = grace_from_params({
+        "compressor": "topk", "compress_ratio": 0.05,
+        "topk_algorithm": "chunk", "memory": "residual",
+        "communicator": "allgather", "fusion": "none"})
+    tx = optax.chain(grace.transform(seed=0), optax.adamw(1e-2))
+    params, mstate = builder.init(jax.random.key(3), sizes)
+    ids = builder.make_batch(jax.random.key(4), 2 * world, sizes)
+    state = init_stateful_train_state(params, mstate, tx, mesh)
+    step = make_stateful_train_step(builder.program_loss(sizes), tx, mesh,
+                                    donate=False)
+    losses = []
+    for _ in range(4):
+        state, loss = step(state, ids)
+        losses.append(float(loss))
+    text = next(iter(step.jit_cache.values())).lower(state, ids).as_text(
+        debug_info=True)
+    return {"losses": losses, "state": state, "world": world, "text": text,
+            "grace": grace, "tokens": 2 * sizes["seq_length"]}
+
+
+def test_the_compressed_step_trains_the_model(trained):
+    losses = trained["losses"]
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.01
+
+
+def test_the_model_state_carries_bias_and_counters_through_the_step(trained):
+    layers = trained["state"].model_state["layers"]
+    assert layers[0] == {} and len(layers) == 5
+    for layer in layers[1:]:
+        assert set(layer) == {"expert_bias", "drawn", "held", "dropped"}
+        # the step's mean over replicas: each replica drew 2 for each of
+        # its tokens over the router's 8 experts
+        assert float(layer["drawn"].sum()) == pytest.approx(
+            trained["tokens"] * 2)
+        assert 0 <= float(layer["held"]) <= trained["tokens"] * 2
+        assert float(layer["dropped"]) == 0.0
+        assert float(jnp.max(jnp.abs(layer["expert_bias"]))) == 0.0
+
+
+def test_wire_report_reads_the_expert_stacks(trained):
+    from grace_tpu.utils.metrics import wire_report
+    report = wire_report(trained["grace"].compressor, trained["state"].params)
+    stacks = [leaf for leaf in report.leaves if leaf.path.endswith("['w1']")
+              and "layers'][1]" in leaf.path]
+    assert len(report.leaves) == 50 and len(stacks) == 1
+    assert stacks[0].dense_bytes == 2 * 32 * 16 * 4
+    k = max(1, int(2 * 32 * 16 * 0.05))
+    assert stacks[0].wire_bytes == k * 8                 # a value, an index
+    assert 0 < report.wire_bytes < report.dense_bytes
+
+
+def test_every_part_of_the_step_is_under_its_stage(trained):
+    text = trained["text"]
+    for stage in scopes.MODEL_STAGES:
+        assert stage in text, stage
+        assert STAGE.fullmatch(stage), stage             # the reducer reads it
+        assert stage in scopes.ALL_STAGES
+    # the rightmost scope names the part: forward, recomputation, backward
+    name = ("jit(device_step)/grace/forward_backward/transpose(jvp("
+            "grace/moe_experts))/checkpoint/ragged_dot")
+    assert stage_of(name) == "grace/moe_experts"
+    assert scopes.match_stage(name) == scopes.STAGE_MOE_EXPERTS
+    assert scopes.match_stage(
+        "grace/forward_backward/jvp(grace/short_conv)/dot") \
+        == scopes.STAGE_SHORT_CONV
+    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 24
