@@ -32,8 +32,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from grace_tpu.core import Compressor, Ctx, Payload, State
-from grace_tpu.ops.sparse import (chunkwise_dense, chunkwise_dense_sum,
-                                  scatter_dense)
+from grace_tpu.ops.sparse import (chunk_first_max, chunkwise_dense,
+                                  chunkwise_dense_sum, scatter_dense,
+                                  takes_row_slices)
 from grace_tpu.telemetry.scopes import STAGE_DECOMPRESS, trace_stage
 
 
@@ -216,14 +217,28 @@ class TopKCompressor(Compressor):
         is one more elementwise pass (~0.3 ms). Exactly one mask row is hot
         per column, so the sum reproduces the gathered value bit-exactly —
         argmax and the mask agree on ties (both take the first max).
+
+        The view itself is a physical relayout on the TPU (k is rarely a
+        multiple of the 128 lanes), and past ``ops.sparse.
+        RELAYOUT_LOOP_ELEMENTS`` XLA:TPU runs the pad + ``reshape(rows, k)``
+        as a ``while`` loop over 4-row windows. Such a leaf never asks for
+        the view: ``ops.sparse.chunk_first_max`` reads the same columns
+        from row-block slices of the flat buffer, each small enough for
+        one reshape, and returns the same ``values`` and winning rows, bit
+        for bit. The route follows from the leaf's static ``(rows, k)``
+        alone.
         """
         n = flat.size
         rows = -(-n // k)                      # ceil(n / k) >= 2
-        body = jnp.zeros((rows * k,), flat.dtype).at[:n].set(flat)
-        body = body.reshape(rows, k)
-        win_row = jnp.argmax(jnp.abs(body), axis=0).astype(jnp.int32)
-        mask = jnp.arange(rows, dtype=jnp.int32)[:, None] == win_row[None, :]
-        values = jnp.sum(jnp.where(mask, body, 0), axis=0)
+        if takes_row_slices(rows, k):
+            values, win_row = chunk_first_max(flat, k)
+        else:
+            body = jnp.zeros((rows * k,), flat.dtype).at[:n].set(flat)
+            body = body.reshape(rows, k)
+            win_row = jnp.argmax(jnp.abs(body), axis=0).astype(jnp.int32)
+            mask = (jnp.arange(rows, dtype=jnp.int32)[:, None]
+                    == win_row[None, :])
+            values = jnp.sum(jnp.where(mask, body, 0), axis=0)
         indices = win_row * k + jnp.arange(k, dtype=jnp.int32)
         return values, indices
 
@@ -286,15 +301,24 @@ class TopKCompressor(Compressor):
         view, ONE flatten, then the average — the same sum over the same
         ``world`` addends as ``vmap(decompress)`` + ``aggregate``. Static
         conditions only: the same chunk structure :meth:`decompress` checks
-        per rank, and more than one rank (at world == 1 the single decode
-        fuses into its consumer as it is; None leaves that program
-        unchanged)."""
+        per rank, and more than one rank. At world == 1 the single decode
+        fuses into its consumer as it is, and None leaves that program
+        unchanged; only a leaf on the row-slices route
+        (``ops.sparse.takes_row_slices``) is answered, with its plain
+        :meth:`decompress`."""
         values, indices = gathered
         numel, shape, dtype = ctx
         k = static_k(numel, self.compress_ratio)
-        if (self.algorithm != "chunk" or world == 1 or numel < 2 * k
+        if (self.algorithm != "chunk" or numel < 2 * k
                 or values.shape != (world, k)):
             return None
+        if world == 1:
+            # One payload: nothing to sum. A leaf on the row-slices route
+            # is decoded as the memory update decodes it, unbatched, so
+            # that XLA keeps one of the two decodes.
+            if not takes_row_slices(-(-numel // k), k):
+                return None
+            return self.decompress((values[0], indices[0]), ctx)
         with trace_stage(f"{STAGE_DECOMPRESS}/aggregate_rows"):
             out = chunkwise_dense_sum(values.astype(dtype),
                                       (indices // k).astype(jnp.int32),

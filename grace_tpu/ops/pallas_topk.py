@@ -14,9 +14,15 @@ new residual tile plus the k-sized wire values/rows.
 Layout: the flat buffer is viewed as (rows, k) row-major — strided chunk c
 is column c, exactly the TopKCompressor 'chunk' wire format. To avoid
 materializing a zero-padded copy of the whole buffer (which would re-add
-two full HBM passes), the buffer is split into a FREE row-major reshape of
+two full HBM passes), the buffer is split into a row-major reshape of
 the ``n // k`` full rows plus one k-sized zero-padded tail row; the kernel
-reduces over both. beta/gamma feedback coefficients are static jit args
+reduces over both. That reshape copies nothing in row-major terms, but on
+the TPU it is free only where k is a multiple of the 128 lanes: otherwise
+``flat -> (rows, k)`` is a physical relayout of a tiled layout, which
+XLA:TPU runs as one operation up to 2**22 elements and as a ``while`` loop
+over 4-row windows past that (``ops/sparse.py`` RELAYOUT_LOOP_ELEMENTS; the
+staged route avoids it by walking row-block slices of the flat buffer,
+this one does not). beta/gamma feedback coefficients are static jit args
 folded into the kernel, so the only HBM traffic is: read grad + residual,
 write residual + the two k-sized wire planes, plus one n-sized reassembly
 write of the residual halves.

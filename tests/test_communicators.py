@@ -460,6 +460,30 @@ def test_allgather_chunk_topk_aggregates_rows_like_per_rank_decode(
         assert np.linalg.norm(out - ref) <= 1e-6 * np.linalg.norm(ref)
 
 
+@pytest.mark.parametrize("average", [True, False], ids=["mean", "sum"])
+@pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+def test_allgather_w1_decodes_a_row_slices_leaf_directly(
+        rng, monkeypatch, case, average):
+    """At one device a leaf on the row-slices route (ops.sparse: its view
+    passes the constant, set small here, and k is no multiple of 128) is
+    decoded by the hook as the memory update decodes it — one decode for
+    XLA to keep, where the vmapped one would chain a row block at a time —
+    and equals the per-rank decode bitwise. ``k128`` stays on the view, and
+    there the hook declines as before."""
+    from grace_tpu.ops import sparse
+    monkeypatch.setattr(sparse, "RELAYOUT_LOOP_ELEMENTS", 40)
+    shape, comp = _chunk_codec(case, average=average)
+    n = int(np.prod(shape))
+    k = max(1, int(n * comp.compress_ratio))
+    x = _rank_inputs(rng, 1, shape, k, disjoint=False)
+    out, ref, answered = _exchange_both_ways(1, comp, x)
+    assert answered == sparse.takes_row_slices(-(-n // k), k)
+    assert answered == (case != "k128")
+    assert out.shape == shape and out.dtype == ref.dtype
+    assert np.linalg.norm(ref) > 0
+    np.testing.assert_array_equal(out, ref)
+
+
 @pytest.mark.parametrize("world", [1, 2, 4])
 def test_chunkwise_dense_sum_is_the_sum_of_chunkwise_dense(rng, world):
     """The function alone, off the mesh: W payloads with padding (rows*k >

@@ -11,6 +11,9 @@ import numpy as np
 import pytest
 
 from grace_tpu import compressors as C
+from grace_tpu.compressors.topk import static_k
+from grace_tpu.memories import ResidualMemory
+from grace_tpu.ops import sparse
 
 KEY = jax.random.key(42)
 
@@ -385,3 +388,110 @@ def test_degenerate_inputs_stay_finite(name, case, rng):
     d = c.decompress(p, ctx)
     assert d.shape == x.shape and d.dtype == x.dtype
     assert bool(jnp.all(jnp.isfinite(d)))
+
+
+# ---------------------------------------------------------------------------
+# chunk top-k on large leaves: static row-block slices against the view
+# ---------------------------------------------------------------------------
+
+# rows, k, lanes of padding in the last row, the limit the test sets in
+# ``ops.sparse.RELAYOUT_LOOP_ELEMENTS`` to send this small leaf down the
+# row-slices route, and the ``(per, count)`` blocks its whole rows are then
+# walked in (the last block starts early, to end with the last whole row).
+_SLICE_LAYOUTS = {
+    "divisible": (20, 30, 0, 100, (3, 7)),        # 20 whole rows, 21 walked
+    "pad1": (9, 37, 1, 37, (1, 8)),               # a row a block + the padded
+    "pad-k-minus-1": (21, 11, 10, 110, (8, 3)),   # tiles of 8: 20 rows as 24
+    "rows2": (2, 37, 4, 40, (1, 1)),              # one whole row, one padded
+    "k1": (17, 1, 0, 9, (8, 3)),                  # k = 1: 17 rows as 24
+}
+
+
+def _slice_layout_input(layout, data, rng):
+    rows, k, pad, _, _ = _SLICE_LAYOUTS[layout]
+    n = rows * k - pad
+    if data == "random":
+        x = rng.standard_normal(n)
+    elif data == "ties":                  # equal |x| across rows, both signs
+        x = rng.integers(-2, 3, n).astype(np.float64)
+    elif data == "zeros":                 # all tie at 0; -0.0 among them
+        x = np.where(rng.random(n) < 0.5, 0.0, -0.0)
+    elif data == "nan":                   # a NaN past row 0, and two in one
+        x = rng.standard_normal(n)        # column: the first one wins
+        x[(rows - 1) * k:(rows - 1) * k + 1] = np.nan
+        x[(k + k // 2) % n] = np.nan
+        x[((rows - 1) * k + k // 2) % n] = np.nan
+    elif data == "inf":                   # +inf and -inf tie in |x|
+        x = rng.standard_normal(n)
+        x[rng.integers(0, n, max(2, n // 7))] = np.inf
+        x[rng.integers(0, n, max(2, n // 7))] = -np.inf
+    return jnp.asarray(x.astype(np.float32)), (k + 0.5) / n
+
+
+def _codec_and_residual(x, ratio, wire):
+    """values, indices, the decode and the residual it leaves, as bytes."""
+    comp = C.TopKCompressor(compress_ratio=ratio, algorithm="chunk",
+                            wire_dtype=wire)
+    (values, indices), ctx, _ = comp.compress(x, None, KEY)
+    dense = comp.decompress((values, indices), ctx)
+    resid = ResidualMemory().update(x, (values, indices), ctx, comp,
+                                    jnp.zeros_like(x))
+    assert values.dtype == jnp.dtype(wire) and indices.dtype == jnp.int32
+    return [np.asarray(a).tobytes() for a in (values, indices, dense, resid)]
+
+
+@pytest.mark.parametrize("wire", ["float32", "bfloat16"])
+@pytest.mark.parametrize("data", ["random", "ties", "zeros", "nan", "inf"])
+@pytest.mark.parametrize("layout", sorted(_SLICE_LAYOUTS))
+def test_topk_chunk_row_slices_equal_the_view_bitwise(monkeypatch, rng,
+                                                      layout, data, wire):
+    """The two routes of chunk top-k (the (rows, k) view by reshape; static
+    row-block slices of the flat buffer, ops.sparse) on the same leaf:
+    ``values``, ``indices``, the decode and the new residual byte for byte.
+    The route follows from (rows, k) against one constant, set here so that
+    a small leaf takes the slices."""
+    rows, k, pad, limit, blocks = _SLICE_LAYOUTS[layout]
+    x, ratio = _slice_layout_input(layout, data, rng)
+    assert not sparse.takes_row_slices(rows, k)
+    view = _codec_and_residual(x, ratio, wire)
+    monkeypatch.setattr(sparse, "RELAYOUT_LOOP_ELEMENTS", limit)
+    assert sparse.takes_row_slices(rows, k)
+    assert sparse.row_blocks(rows - bool(pad), k) == blocks
+    sliced = _codec_and_residual(x, ratio, wire)
+    for name, a, b in zip(("values", "indices", "decode", "residual"),
+                          view, sliced):
+        assert a == b, name
+    indices = np.frombuffer(sliced[1], np.int32)
+    assert indices.max() < x.size           # a padding lane never wins
+
+
+def _lowered_scopes(n, ratio=0.01, dtype=jnp.float32):
+    comp = C.TopKCompressor(compress_ratio=ratio, algorithm="chunk")
+
+    def f(x, r):
+        payload, ctx, _ = comp.compress(x, None, KEY)
+        return ResidualMemory().update(x, payload, ctx, comp, r)
+
+    x = jax.ShapeDtypeStruct((n,), dtype)
+    text = jax.jit(f).lower(x, x).as_text(debug_info=True)
+    return ("grace/compress/row_slices" in text,
+            "grace/decompress/row_slices" in text)
+
+
+@pytest.mark.parametrize("n, k, sliced", [
+    (100 * 41_527 + 50, 41_527, False),   # view of 4,194,227 elements
+    (100 * 41_529 + 50, 41_529, True),    # 4,194,429: just past 2**22
+    (100 * 41_600 + 50, 41_600, False),   # k % 128 == 0: the reshape is free
+    (2_359_296, 23_592, False),           # ResNet-50's largest leaf
+    (25_165_824, 251_658, True),          # an LFM2 expert stack
+], ids=["under", "over", "k-lane-multiple", "resnet-largest", "lfm2-expert"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_topk_chunk_route_follows_the_view_size(n, k, sliced, dtype):
+    """A leaf whose (rows, k) view holds at most RELAYOUT_LOOP_ELEMENTS
+    takes the view, the next one up the slices, in both directions and
+    whatever the element width; what the lowered program carries says so
+    (the sub-scopes ``grace/compress/row_slices`` and
+    ``grace/decompress/row_slices``)."""
+    assert static_k(n, 0.01) == k
+    assert sparse.takes_row_slices(-(-n // k), k) == sliced
+    assert _lowered_scopes(n, dtype=jnp.dtype(dtype)) == (sliced, sliced)

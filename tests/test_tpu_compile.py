@@ -19,8 +19,10 @@ only the worker that runs this file may load the library. A compile that
 passes here is not a chip run and is never reported as one.
 """
 
+import json
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,10 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 import grace_tpu.ops
 from grace_tpu import grace_from_params
+from grace_tpu.compressors.topk import static_k
+from grace_tpu.memories import ResidualMemory
+from grace_tpu.models import lfm2, resnet
+from grace_tpu.ops import sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
                                         quantize_stochastic, sign_pack)
 from grace_tpu.ops.pallas_topk import (chunk_aggregate_dense,
@@ -38,6 +44,12 @@ from grace_tpu.ops.pallas_topk import (chunk_aggregate_dense,
 from grace_tpu.ops.pallas_wire import (decode_accumulate,
                                        packed_int_accumulate)
 from grace_tpu.parallel import shard_map
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
+    sys.path.insert(0, REPO)
+
+from benchmarks.models import lfm2_moe  # noqa: E402
 
 N = 25_557_032            # ResNet-50's flat gradient
 K = N // 100              # top-k 1 %
@@ -301,3 +313,137 @@ def test_aggregate_rows_engagement_count_in_lowered_step(params, world,
     text = fn.lower(state, batch).as_text(debug_info=True)
     assert scope_engagements(
         text, "grace/decompress/aggregate_rows/iota") == engaged
+
+
+# ---------------------------------------------------------------------------
+# the relayout loops PR 29 took out of the one-chip step's large leaves
+# ---------------------------------------------------------------------------
+
+def _leaf_pipeline(g, r):
+    """One leaf's compensate -> compress -> update -> decompress, top-k 1 %
+    chunk with a float32 residual: what the transform runs per leaf."""
+    codec = grace_from_params({
+        "compressor": "topk", "compress_ratio": 0.01,
+        "topk_algorithm": "chunk", "memory": "none",
+        "communicator": "allgather"}).compressor
+    memory = ResidualMemory()
+    c, r = memory.compensate(g, r)
+    payload, ctx, _ = codec.compress(c, None, jax.random.key(0))
+    r = memory.update(c, payload, ctx, codec, r)
+    return codec.decompress(payload, ctx), r
+
+
+@pytest.mark.parametrize("shape, view_forced, sliced", [
+    ((8, 2048, 1536), False, True),         # an LFM2 expert stack
+    ((8, 2048, 1536), True, False),         # ... as the parent ran it
+    ((3, 3, 512, 512), False, False),       # ResNet-50's largest leaf
+    ((100 * 41_527 + 50,), False, False),   # view of 4,194,227 elements
+    ((100 * 41_529 + 50,), True, False),    # 4,194,429, by the view
+    ((100 * 41_529 + 50,), False, True),    # ... and as it runs now
+    ((4_500_000,), False, True),            # rows * k == n: no padding lane
+], ids=["lfm2-expert", "lfm2-expert-by-view", "resnet-largest",
+        "under-the-constant", "over-by-view", "over", "no-padding"])
+def test_topk_chunk_leaf_has_no_relayout_loop(one_chip, monkeypatch, shape,
+                                              view_forced, sliced):
+    """Past ``ops.sparse.RELAYOUT_LOOP_ELEMENTS`` XLA:TPU runs the flat <->
+    (rows, k) reshape of a chunk top-k leaf as two ``while`` loops of its
+    own making (``wide.body``: 4-row windows by dynamic-slice and
+    dynamic-update-slice into the tiled view, 25 steps a direction for 101
+    rows; 54 such loops a step in the LFM2 cell, 52 of its 727 ms, under no
+    ``op_name``). On the row-slices route the same leaf compiles without
+    them: what loops there is the route's own walk over equal row blocks
+    (two ``while``, one a direction, a few steps each, under the route's
+    scope), and the reshape inside a step is one operation. The ``by-view``
+    cases hold the constant to the compiler that is installed: if they stop
+    looping, the constant can go up."""
+    if view_forced:
+        monkeypatch.setattr(sparse, "RELAYOUT_LOOP_ELEMENTS", 1 << 62)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    # a function of its own each time: jit would answer a second trace of
+    # the same function and shapes from its cache, whatever the constant
+    text = compile_text(lambda g, r: _leaf_pipeline(g, r), x, x)
+    loops = [line for line in text.splitlines() if " while(" in line]
+    if view_forced:
+        assert len(loops) == 2 and "%wide.body" in text
+        assert not any("row_slices" in line for line in loops)
+    else:
+        assert "%wide.body" not in text
+        assert len(loops) == (2 if sliced else 0)
+        assert all("/row_slices/" in line for line in loops)
+    assert ("grace/compress/row_slices" in text) == sliced
+    assert ("grace/decompress/row_slices" in text) == sliced
+
+
+def _lfm2_shapes():
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "lfm2-24b-a2b-ep8.json")) as f:
+        sizes = json.load(f)
+    cfg = lfm2_moe.model_config(sizes)
+    return jax.eval_shape(lambda k: lfm2.init(k, cfg)[0], jax.random.key(0))
+
+
+def _resnet50_shapes():
+    return jax.eval_shape(lambda k: resnet.init(k, depth=50)[0],
+                          jax.random.key(0))
+
+
+_TOPK_CHUNK = {"compressor": "topk", "compress_ratio": 0.01,
+               "topk_algorithm": "chunk", "memory": "residual",
+               "communicator": "allgather"}
+
+
+@pytest.mark.parametrize("model, params, leaves, engaged", [
+    ("lfm2", _TOPK_CHUNK, 50, 27),
+    ("resnet50", _TOPK_CHUNK, 161, 0),
+    ("lfm2", dict(_TOPK_CHUNK, topk_algorithm="exact"), 50, 0),
+    ("lfm2", dict(_TOPK_CHUNK, topk_algorithm="approx"), 50, 0),
+    ("lfm2", {"compressor": "powersgd", "compress_rank": 4,
+              "memory": "powersgd", "communicator": "allgather"}, 50, 0),
+    ("lfm2", {"compressor": "none", "memory": "none",
+              "communicator": "allreduce"}, 50, 0),
+], ids=["lfm2-chunk", "resnet50-chunk", "lfm2-exact", "lfm2-approx",
+        "lfm2-powersgd", "lfm2-dense"])
+def test_row_slices_engagement_count_in_lowered_step(model, params, leaves,
+                                                     engaged):
+    """How many leaves take the row-slices route is a static count: the
+    calls under ``grace/compress/row_slices`` in the lowered one-device
+    train step, one a leaf, and two a leaf under
+    ``grace/decompress/row_slices`` (the memory update's decode and the
+    exchange's, which XLA merges). The leaves of the benchmark's LFM2
+    configuration whose view passes the constant (27 of 50: all of
+    4,194,304 elements or more), none of ResNet-50's 161, none for another
+    algorithm or codec. Lowered from shapes with a loss that touches every
+    leaf: nothing here runs or needs the described chip."""
+    import optax
+    from grace_tpu.train import init_train_state, make_train_step
+
+    shapes = {"lfm2": _lfm2_shapes, "resnet50": _resnet50_shapes}[model]()
+    counts = [int(np.prod(s.shape)) for s in
+              jax.tree_util.tree_leaves(shapes)]
+    assert len(counts) == leaves
+    if params is _TOPK_CHUNK:
+        by_size = sum(sparse.takes_row_slices(-(-n // static_k(n, 0.01)),
+                                              static_k(n, 0.01))
+                      for n in counts)
+        assert by_size == engaged
+        assert by_size == sum(n >= 4_194_304 for n in counts)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+
+    def loss_fn(p, batch):
+        return sum(jnp.sum(leaf) for leaf in
+                   jax.tree_util.tree_leaves(p)) * jnp.mean(batch)
+
+    tx = optax.chain(grace_from_params(params).transform(seed=0),
+                     optax.sgd(0.1))
+    state = jax.eval_shape(lambda p: init_train_state(p, tx, mesh), shapes)
+    batch = jax.ShapeDtypeStruct((2, 3), jnp.float32)
+    step = make_train_step(loss_fn, tx, mesh, donate=False)
+    jax.eval_shape(step, state, batch)
+    fn = next(iter(step.jit_cache.values()))
+    text = fn.lower(state, batch).as_text(debug_info=True)
+    assert scope_engagements(
+        text, "grace/compress/row_slices/jit(row_blocks_first_max)"
+    ) == engaged
+    assert scope_engagements(
+        text, "grace/decompress/row_slices/jit(row_blocks_dense)"
+    ) == 2 * engaged
