@@ -60,10 +60,10 @@ class QSGDCompressor(Compressor):
     # Fused Pallas TPU kernel for the quantize step (in-core PRNG, one HBM
     # pass — see grace_tpu/ops/pallas_quant.py). 'auto' (the default, also
     # what grace_from_params passes): kernel on real TPU, staged XLA path
-    # elsewhere — the round-5 on-chip A/B measured the kernel 42% faster
-    # end-to-end (0.824 vs 0.580 of dense; BENCH_ALL_TPU_LAST.json
-    # 2026-08-01). Note the OPPOSITE resolution from Top-K, whose A/B
-    # measured staged faster. True forces the kernel even off-TPU
+    # elsewhere — the on-chip A/B of 2026-08-01, before the driver's
+    # ledger, measured the kernel 42% faster end-to-end; not re-measured
+    # on v5e in a cell (PERF.md §7, first open cell). Note the OPPOSITE
+    # resolution from Top-K. True forces the kernel even off-TPU
     # (interpret mode: slow, test-only); False forces staged.
     use_pallas: bool | str = "auto"
 
@@ -79,11 +79,11 @@ class QSGDCompressor(Compressor):
     def _pallas_mode(self):
         # The ONE shared selection rule (grace_tpu.ops.pallas_mode): under
         # 'auto' the kernel runs on real TPU and the staged path elsewhere
-        # — the round-5 on-chip A/B (BENCH_ALL_TPU_LAST.json 2026-08-01)
-        # measured the fused quant kernel at 2111 img/s vs 1483 staged
-        # (0.824 vs 0.580 of dense): unlike Top-K, where the staged path
-        # wins, QSGD's per-element stochastic rounding gains 42% from the
-        # single-pass kernel with in-core PRNG.
+        # — the on-chip A/B of 2026-08-01, before the driver's ledger,
+        # measured the fused quant kernel at 2111 img/s vs 1483 staged;
+        # not re-measured on v5e in a cell (PERF.md §7, first open cell).
+        # Unlike Top-K, QSGD's per-element stochastic rounding gained 42%
+        # from the single-pass kernel with in-core PRNG.
         from grace_tpu.ops import pallas_mode
         return pallas_mode(self.use_pallas, kernel="quant")
 

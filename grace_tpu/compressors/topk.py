@@ -63,10 +63,10 @@ class TopKCompressor(Compressor):
     # + select + value extract + residual update in one HBM pass — see
     # grace_tpu/ops/pallas_topk.py), used via the Communicator.step fast
     # path with linear-error-feedback memories. 'auto' resolves to the
-    # staged XLA path everywhere: the on-chip A/B (BENCH_ALL_TPU_LAST.json
-    # 2026-07-31, same session) measured staged at 1602 vs fused-kernel
-    # 1441 imgs/sec on the ResNet-50 headline — XLA's own fusion beats the
-    # hand-written kernel end-to-end, so the kernel is an explicit opt-in
+    # staged XLA path everywhere: the on-chip A/B of 2026-07-31, before
+    # the driver's ledger, measured staged at 1602 vs fused-kernel 1441
+    # imgs/sec on ResNet-50; not re-measured on v5e in a cell (PERF.md §7,
+    # first open cell). So the kernel is an explicit opt-in
     # (True; forces interpret mode off-TPU for tests) until a measurement
     # says otherwise.
     use_pallas: bool | str = "auto"
@@ -213,7 +213,7 @@ class TopKCompressor(Compressor):
         Values come from a one-hot masked sum over the (rows, k) view, NOT
         ``flat[indices]``: a k-element gather from the fused buffer
         serializes on TPU (measured ~5-6 ms of the ~10 ms compressed-step
-        overhead at n=25.5M, tools/tpu_micro.py) while the masked reduction
+        overhead at n=25.5M, on-chip 2026-08-01) while the masked reduction
         is one more elementwise pass (~0.3 ms). Exactly one mask row is hot
         per column, so the sum reproduces the gathered value bit-exactly —
         argmax and the mask agree on ties (both take the first max).

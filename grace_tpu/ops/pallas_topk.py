@@ -6,8 +6,8 @@ grace_dl/dist/__init__.py:47-52) is pure elementwise/reduction work over the
 fused gradient buffer, but expressed in jnp it streams the n-element buffer
 through HBM ~6 times (compensated, padded body, |body| argmax, masked value
 sum, one-hot dense, residual subtract — XLA fuses some neighbors but the
-measured compressed-step overhead on a 25.5M buffer was still ~10 ms vs a
-~3-pass roofline, BENCH_TPU_LAST.json 2026-07-31). This kernel does the
+compressed-step overhead on a 25.5M buffer was still ~10 ms vs a ~3-pass
+roofline on chip, 2026-07-31, before the driver's ledger). This kernel does the
 whole thing in ONE pass: read grad + residual tiles into VMEM, write the
 new residual tile plus the k-sized wire values/rows.
 

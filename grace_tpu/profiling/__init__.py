@@ -22,8 +22,7 @@ reads the evidence back:
   sinks.
 
 CLI: ``tools/perf_report.py`` (stage table + overlap % + percentiles +
-baseline gating, writes ``PROF_LAST.json``); ``tools/tpu_profile.py``
-captures on the chip and reports through the same analyzer offline.
+baseline gating, writes ``PROF_LAST.json``).
 """
 
 from grace_tpu.profiling.recorder import (ProfileRecorder,

@@ -154,8 +154,7 @@ def check_state_footprint(state, grace_or_tx, params,
     """Live GraceState bytes vs the expected model. ``matches`` compares
     the three per-codec components exactly — the model is the abstract
     init shape, so a mismatch means the live state was built under a
-    different codec/fusion/telemetry config than the one being reported
-    (the bug class the bench resume gate exists for)."""
+    different codec/fusion/telemetry config than the one being reported."""
     live = grace_state_footprint(state)
     model = expected_state_footprint(grace_or_tx, params, world=world)
     matches = all(live[k] == model[k]

@@ -28,8 +28,7 @@ perf arc is blocked on —
 Two input formats, one span model:
 
 * ``*.trace.json.gz`` / ``*.json`` — the Chrome-trace-format export every
-  ``jax.profiler.trace`` capture writes (the format the old ad-hoc
-  ``tpu_profile --report`` summarized). Fully supported.
+  ``jax.profiler.trace`` capture writes. Fully supported.
 * ``*.xplane.pb`` — the raw XSpace protobuf. Decoded with a small
   schema-pinned reader (:data:`_XPLANE_SCHEMA`; pure stdlib, mirroring the
   hand-encoded protos of :class:`~grace_tpu.telemetry.sinks.TensorBoardSink`)
@@ -503,7 +502,7 @@ class TraceAnalysis:
             out.append(
                 f"  overlap: {self.overlap_us / 1e3:.3f} ms of collective "
                 f"time hidden under compute — overlap fraction "
-                f"{self.overlap_fraction:.1%} (device timelines; the bench "
+                f"{self.overlap_fraction:.1%} (device timelines; the tuner's "
                 "projection model assumes 0%)")
         sp = self.step_percentiles_ms()
         if sp:

@@ -72,8 +72,7 @@ wedge the run.
 Event vocabulary (timeline kind ``retune``): ``retune_drift``,
 ``retune_measure``, ``retune_prepare``, ``retune_abort``,
 ``retune_promote``, ``retune_probation_clear``, ``retune_demote``,
-``retune_timeout``. ``retune_promote`` / ``retune_demote`` are incident
-triggers (:mod:`grace_tpu.evidence.incident`).
+``retune_timeout``.
 """
 
 from __future__ import annotations

@@ -57,8 +57,7 @@ def _retry_io(fn, what: str):
     """``checkpoint._retry_io`` (bounded-backoff retry of transient
     ``OSError``s — the same policy ``Checkpointer.save`` uses, so a
     preempted node's NFS blip can't drop the last window of records) when
-    available; single attempt on a box without orbax's dependency tree
-    (the bench.py fallback idiom)."""
+    available; single attempt on a box without orbax's dependency tree."""
     try:
         from grace_tpu.checkpoint import _retry_io as retry
     except Exception:

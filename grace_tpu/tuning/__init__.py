@@ -13,14 +13,13 @@ given a model's param tree and a target mesh topology, it
    :class:`~grace_tpu.core.Topology` through the documented
    wire-dominated cost model (:mod:`.cost`), flow pass 5/6/7 over the
    ranked survivors — every rejection recorded with its reason;
-3. **measures the shortlist** (:mod:`.measure`): real timed steps with
-   bench.py's timing discipline, dense brackets interleaved same-session,
+3. **measures the shortlist** (:mod:`.measure`): real timed steps on the
+   host clock, dense brackets interleaved same-session,
    each candidate's own measured compute step substituted back into the
    cost model for the target-topology ranking;
 4. **stamps the winner**: a ``grace_from_params``-loadable config with git
    revision, topology, the prune funnel, and the measured≤static overlap
-   sandwich as its honesty gate, written to ``TUNE_LAST.json``
-   (rendered by ``tools/evidence_summary.py``).
+   sandwich as its honesty gate, written to ``TUNE_LAST.json``.
 
 CLI: ``tools/graft_tune.py``.
 """
@@ -177,9 +176,7 @@ def run_tune(topologies: Sequence[Union[str, TuneTopology]], *,
 
 def write_tune_evidence(doc: Dict[str, Any],
                         path: str = TUNE_EVIDENCE_PATH) -> None:
-    """Atomic tmp+fsync+replace, the repo's evidence-write idiom, plus a
-    ledger record (repo-root artifacts only — a test writing to tmp_path
-    must not touch EVIDENCE/ledger.jsonl)."""
+    """Atomic tmp+fsync+replace, the repo's evidence-write idiom."""
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
         json.dump(doc, f, indent=1)
@@ -187,24 +184,3 @@ def write_tune_evidence(doc: Dict[str, Any],
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, path)
-    if (os.path.dirname(os.path.abspath(path)) !=
-            os.path.dirname(os.path.abspath(TUNE_EVIDENCE_PATH))):
-        return
-    try:
-        from grace_tpu.evidence.ledger import record_artifact
-        prov = doc.get("provenance") or {}
-        winner = doc.get("winner") or {}
-        n_dev = prov.get("n_devices")
-        record_artifact(
-            path, id="tune-winner", metric="tune_winner_config",
-            value=winner.get("candidate"), claim_class="measured",
-            tool="graft_tune", platform=prov.get("platform"),
-            chip=prov.get("device"), n_devices=n_dev,
-            topology={"world": n_dev, "tiers": ["ici"], "slice": None,
-                      "region": None},
-            config=winner.get("grace_params"),
-            lint_clean=bool(doc.get("ok")))
-    except Exception as e:                               # noqa: BLE001
-        import sys
-        print(f"[graft_tune] ledger emission failed: {e}",
-              file=sys.stderr, flush=True)

@@ -8,12 +8,11 @@ One pricing rule, stated once and stamped into every ``TUNE_LAST.json``:
 where ``(ici_bytes, dcn_bytes, wan_bytes)`` is
 :meth:`Communicator.recv_link_bytes` under the *target*
 :class:`~grace_tpu.core.Topology` — the same shared per-link wire model
-the bench projections, the telemetry ring and the static auditor's
-wire-reconciliation pass already agree on — and the bandwidth constants
-are ``bench.PROJECTION_MODEL``'s public per-chip numbers (ICI ~90 GB/s,
-DCN ~25 GB/s, WAN ~0.25 GB/s — the documented cross-region model
-assumption), imported, not duplicated, so the tuner and the bench can
-never price the same bytes differently.
+the telemetry ring and the static auditor's wire-reconciliation pass
+already agree on — and the bandwidth constants
+are this module's own (ICI ~90 GB/s, DCN ~25 GB/s, WAN ~0.25 GB/s — the
+documented cross-region model assumption): a PROJECTION MODEL, not a
+measurement. What the chip measures is `PERF_LEDGER.jsonl`'s.
 
 Why the legs are priced separately: a flat communicator's critical-path
 rank receives every pipelined chunk over the worst boundary link the
@@ -31,7 +30,7 @@ Model limits (recorded in the evidence, enforced by the measured stage):
 * **wire-dominated**: the static stage prices every candidate at the SAME
   base compute step — codec compute cost (topk selection, qsgd quantize,
   pallas fusion) is deliberately NOT modeled, because the repo's own
-  bench history shows it is unpredictable from first principles (the
+  chip history shows it is unpredictable from first principles (the
   staged qsgd path measured 42% slower than the kernel; chunk vs exact
   top-k is a 2× swing). That is what the measured shortlist is for.
 * **no overlap** — with ONE declared exception: a double-buffered
@@ -52,37 +51,31 @@ Model limits (recorded in the evidence, enforced by the measured stage):
 from __future__ import annotations
 
 import dataclasses
-import os
-import sys
 from typing import Any, Dict, Optional
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+# Projection model, not a measurement: per-chip bandwidths a projected
+# step time is priced at.
+ICI_RING_BYTES_PER_S = 9.0e10
+DCN_BYTES_PER_S = 2.5e10
+WAN_BYTES_PER_S = 2.5e8
 
-
-def _bench_module():
-    """The repo-root ``bench`` module (stdlib-only at import time). The
-    tuner lives inside the package, so add the checkout root when running
-    from an installed layout."""
-    try:
-        import bench
-    except ImportError:
-        sys.path.insert(0, ROOT)
-        import bench
-    if not hasattr(bench, "PROJECTION_MODEL"):
-        raise ImportError(
-            "a different top-level module shadows the repo's bench.py — "
-            "the tuner needs bench.PROJECTION_MODEL's bandwidth constants")
-    return bench
+PROJECTION_MODEL = {
+    "constants_source": (
+        "TPU v5e: 4 ICI links/chip in a 2D torus, ~45 GB/s per direction "
+        "per link (cloud.google.com/tpu/docs/system-architecture-tpu-vm; "
+        "jax-ml.github.io/scaling-book/ 'TPU networking'); a 1-D ring "
+        "collective rides 2 links -> ~90 GB/s per chip. DCN ~25 GB/s/host "
+        "(scaling-book cross-slice figure). WAN ~0.25 GB/s/host of "
+        "sustained cross-region collective bandwidth — a MODEL ASSUMPTION "
+        "(~100x below DCN), not a measurement."),
+}
 
 
 def projection_constants():
     """(ici_bytes_per_s, dcn_bytes_per_s, wan_bytes_per_s,
-    projection_model_doc) — the ONE set of bandwidth assumptions, owned
-    by bench.py."""
-    bench = _bench_module()
-    return (bench.ICI_RING_BYTES_PER_S, bench.DCN_BYTES_PER_S,
-            bench.WAN_BYTES_PER_S, bench.PROJECTION_MODEL)
+    projection_model_doc) — the ONE set of bandwidth assumptions."""
+    return (ICI_RING_BYTES_PER_S, DCN_BYTES_PER_S, WAN_BYTES_PER_S,
+            PROJECTION_MODEL)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,8 +189,7 @@ def price_candidate(grace, model_structs, spec: TuneTopology, *,
     candidate (0.0 = pure wire ranking; the measured stage replaces it
     with each candidate's own timed step); ``dense_step_s`` defaults to
     the same value so the speedup ratio stays like-for-like. Dense rides
-    a ring allreduce priced through the identical shared model
-    (``bench.project_multichip``'s convention).
+    a ring allreduce priced through the identical shared model.
     """
     from grace_tpu.comm import Allreduce
     from grace_tpu.utils import wire_report

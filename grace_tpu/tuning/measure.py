@@ -3,12 +3,10 @@
 The static stage deliberately prices every candidate at the same compute
 step (cost.py's wire-dominated model); this stage supplies what it cannot:
 each shortlisted candidate's OWN compute cost, from real timed steps of a
-real train step on the live mesh. The timing discipline is bench.py's
-(``bench.throughput``: fetch-bounded windows, RTT-subtracted — the same
-function the headline capture uses), and the rows follow bench's
-same-session contract: every candidate sample is bracketed by a dense
-baseline sample measured moments before it, never by a number from
-another session.
+real train step on the live mesh. A sample is a window of dependent
+steps between two ``jax.block_until_ready`` calls on the host clock, and
+every candidate sample is bracketed by a dense baseline sample measured
+moments before it, never by a number from another session.
 
 The honesty gate is the measured≤static **overlap sandwich** from
 ``perf_report --overlap-config``: the winner's step is profiled, the
@@ -20,15 +18,15 @@ measurements is exactly the vibes-selection this subsystem exists to kill.
 
 Models: ``"toy"`` is the audit registry's own default param tree (512
 params — the model every static number in the funnel was priced on), with
-the same linear-softmax loss ``trace_train_step`` audits; ``"resnet50"``
-is bench.py's headline protocol for on-chip runs. Both run the identical
-selection/ranking/sandwich path — the toy model is how tier-1 drives the
-whole loop on a CPU mesh in seconds.
+the same linear-softmax loss ``trace_train_step`` audits, which is how
+tier-1 drives the whole loop on a CPU mesh in seconds; ``"resnet50"`` is
+priced statically only (its step is the benchmark's to measure).
 """
 
 from __future__ import annotations
 
 import statistics
+import time
 from typing import Any, Dict, List, Optional
 
 from grace_tpu.tuning.candidates import Candidate
@@ -155,13 +153,12 @@ def build_model_step(grace, mesh, model: str = "toy", *, seed: int = 0,
                                      size=(n_dev * per_device_bs,)))
         batch = (x, y)
     elif model == "resnet50":
-        # The headline protocol belongs to bench.py's stateful path (batch
-        # norm state, shape overrides, evidence persistence); on-chip
-        # shortlists should run `bench_all --tuned` for resnet rows. The
-        # tuner's in-process measurement keeps the stateless toy step.
+        # ResNet-50 needs the stateful step (batch-norm state): the
+        # benchmark's cells measure it (benchmarks/run.py). The tuner's
+        # in-process measurement keeps the stateless toy step.
         raise NotImplementedError(
-            "resnet50 measurement runs through bench_all --tuned (the "
-            "evidence-persisting path); the in-process shortlist uses "
+            "resnet50 is measured by the benchmark's cells "
+            "(benchmarks/run.py); the in-process shortlist uses "
             "model='toy'")
     else:
         raise ValueError(f"unknown model {model!r}")
@@ -171,18 +168,22 @@ def build_model_step(grace, mesh, model: str = "toy", *, seed: int = 0,
     return step, state, batch
 
 
-def _bench():
-    from grace_tpu.tuning.cost import _bench_module
-    return _bench_module()
-
-
 def _timed_step_s(step, state, batch, *, timed_steps: int,
                   warmup: int) -> tuple:
-    """One sample: median-free single window via bench.throughput —
+    """One sample on the host clock: ``timed_steps`` dependent steps
+    between two ``jax.block_until_ready`` calls, so the window opens on a
+    drained device and closes when the last step's outputs exist —
     returns (step_seconds, new_state)."""
-    items_per_sec, state = _bench().throughput(
-        step, state, batch, timed_steps, warmup=warmup)
-    return batch[1].shape[0] / items_per_sec, state
+    import jax
+
+    for _ in range(warmup):
+        state, loss = step(state, batch)
+    jax.block_until_ready((state, loss))
+    t0 = time.perf_counter()
+    for _ in range(timed_steps):
+        state, loss = step(state, batch)
+    jax.block_until_ready((state, loss))
+    return (time.perf_counter() - t0) / timed_steps, state
 
 
 def measure_shortlist(shortlisted: List[Candidate], spec: TuneTopology,

@@ -689,27 +689,6 @@ def test_chaos_smoke_adapt_tighten_before_guard(tmp_path):
     assert first_adapt.step < first_guard.step
 
 
-def test_evidence_summary_renders_adapt(tmp_path, monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        "evidence_summary_adapt_under_test",
-        os.path.join(os.path.dirname(__file__), os.pardir, "tools",
-                     "evidence_summary.py"))
-    es = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(es)
-    doc = {"tool": "chaos_smoke", "captured_at": "2026-08-05T00:00:00",
-           "window": 6, "ladder": ["a", "b", "c"],
-           "tighten": {"count": 2, "first_step": 5,
-                       "within_one_window": True},
-           "loosen": {"count": 1, "first_step": 40},
-           "escalations": 1, "guard_skips": 4, "ordering_ok": True}
-    (tmp_path / "ADAPT_LAST.json").write_text(json.dumps(doc))
-    monkeypatch.setattr(es, "ROOT", str(tmp_path))
-    md = es.build()
-    assert "Adaptive compression (graft-adapt)" in md
-    assert "adapt_tighten precedes the first guard event" in md
-    assert "within one window" in md
-
-
 # ---------------------------------------------------------------------------
 # convergence floors: the routed transformer track + adaptive vs static
 # ---------------------------------------------------------------------------

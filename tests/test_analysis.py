@@ -385,34 +385,6 @@ def test_topology_descriptor():
     assert Topology.detect().slice_size is None
 
 
-def test_bench_projection_uses_shared_per_link_model():
-    """The xslice projection block prices the split the communicator
-    reports — dense and compressed both through recv_link_bytes."""
-    import bench
-
-    class FakeComp:
-        vote_aggregate = False
-
-    class FakeGrace:
-        compressor = FakeComp()
-        communicator = comm.Allgather()
-
-    rows = bench.project_multichip(0.1, 0.1, FakeGrace(),
-                                   wire_b=10 ** 6, dense_b=10 ** 8,
-                                   n_elems=25 * 10 ** 6)
-    for row in rows:
-        x = row["xslice"]
-        assert x["slice_size"] == bench.XSLICE_CHIPS
-        assert x["ici_bytes"] + x["dcn_bytes"] == row["recv_bytes_per_rank"]
-        if row["world"] > bench.XSLICE_CHIPS:
-            assert x["ici_bytes"] == 0        # flat gather beyond one slice
-            # flat DCN pricing matches the legacy all-DCN scenario
-            assert x["step_ms"] == row["step_ms_dcn"]
-        else:
-            assert x["dcn_bytes"] == 0
-            assert x["step_ms"] == row["step_ms_ici"]
-
-
 # ---------------------------------------------------------------------------
 # repo rule engine
 # ---------------------------------------------------------------------------

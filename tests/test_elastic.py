@@ -477,23 +477,3 @@ class TestElasticSmoke:
         assert resize["whole_slices"] and resize["slice_size"] == 4
         assert doc["rejoin"]["replica_variants"] == 1
         assert doc["footprint"] == {"4": True, "8": True}
-
-
-def test_evidence_summary_picks_up_elastic_last(tmp_path, monkeypatch):
-    evidence_summary = _load_tool("evidence_summary")
-    monkeypatch.setattr(evidence_summary, "ROOT", str(tmp_path))
-    doc = {"tool": "chaos_smoke", "captured_at": "2026-08-04T12:00:00",
-           "world_cycle": [8, 7, 8],
-           "resize_events": [{"event": "elastic_drain"},
-                             {"event": "elastic_resize"}],
-           "rejoin": {"rejoins": 1, "barrier_repairs": 1,
-                      "replica_variants": 1, "fingerprint_bytes": 512},
-           "floor": {"final_loss": 1.2, "floor": 2.25, "met": True},
-           "footprint": {"7": True, "8": True}}
-    (tmp_path / "ELASTIC_LAST.json").write_text(json.dumps(doc))
-    md = evidence_summary.build()
-    assert "chaos_smoke --elastic" in md
-    assert "world cycle 8 → 7 → 8" in md
-    assert "1 repair(s) for 1 rejoin(s)" in md
-    assert "bit-identical" in md
-    assert "floor met" in md

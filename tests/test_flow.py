@@ -573,23 +573,6 @@ def test_new_finding_kinds_render_in_telemetry_report(tmp_path):
     assert doc["guard_events"] == []
 
 
-def test_evidence_summary_renders_per_pass_counts(tmp_path, monkeypatch):
-    ev = _load_tool("evidence_summary")
-    monkeypatch.setattr(ev, "ROOT", str(tmp_path))
-    (tmp_path / "LINT_LAST.json").write_text(json.dumps({
-        "tool": "graft_lint", "errors": 0, "warnings": 0,
-        "configs_audited": 45, "rules_checked": 3,
-        "passes_run": ["a", "b"], "pass_counts": {"a": 0, "b": 0},
-        "captured_at": "2026-08-04T00:00:00+00:00"}))
-    md = ev.build()
-    assert "all 2 passes clean" in md
-    (tmp_path / "LINT_LAST.json").write_text(json.dumps({
-        "tool": "graft_lint", "errors": 2, "warnings": 0,
-        "configs_audited": 45, "rules_checked": 3,
-        "pass_counts": {"a": 0, "numeric_safety": 2}}))
-    assert "numeric_safety 2" in ev.build()
-
-
 def test_chaos_smoke_lint_gate_runs_flow_passes(tmp_path, monkeypatch):
     """chaos_smoke --lint audits its own config with the graft-flow AND
     graft-sound passes before any step runs (clean here — the artifact

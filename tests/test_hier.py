@@ -10,10 +10,7 @@ PR-6 split-sum identity, is monotone-in-slices on the DCN leg, and
 collapses to the flat ring formula when there is nothing to split; the
 telemetry ring's new ``wire_bytes_ici``/``wire_bytes_dcn`` fields carry the
 honest mixed split from a REAL sharded step; ``Topology.detect`` rejects
-the device lists it used to mis-size silently; and the bench xslice
-projection — priced through the shared ``recv_link_bytes`` model at the
-committed on-chip step times — shows topk1pct_hier beating dense at W=256
-over DCN where the flat allgather loses (the ISSUE 7 headline).
+the device lists it used to mis-size silently.
 """
 
 import dataclasses
@@ -453,49 +450,6 @@ def test_telemetry_link_split_flips_with_fallback_window(mesh):
                for r in compressed)
     assert all(r["wire_bytes_ici"] == 0 and r["wire_bytes_dcn"] > 0
                for r in dense)
-
-
-# ---------------------------------------------------------------------------
-# bench xslice projection: the ISSUE 7 headline
-# ---------------------------------------------------------------------------
-
-def test_xslice_projection_hier_beats_dense_where_flat_loses():
-    """ISSUE 7 acceptance: at the committed on-chip step times (bs=256
-    headline capture, BENCH_ALL_TPU_LAST 2026-08-01: dense 2285.27
-    imgs/sec, per-leaf Top-K at 0.9895× dense) and the measured topk 1%
-    wire bytes, the W=256 / slice_size=8 xslice projection puts the flat
-    allgather UNDER dense (the ROADMAP's 0.896× indictment) and the
-    hierarchical schedule ABOVE it — same step times, same codec, only
-    the schedule differs."""
-    import bench
-
-    dense_step = 256 / 2285.27           # s, bs=256 on the one v5e chip
-    topk_step = dense_step / 0.9895      # headline per-leaf ratio
-    wire_b, dense_b = 2_044_104, 102_228_128
-    n_elems = dense_b // 4
-
-    class _FakeComp:
-        vote_aggregate = False
-
-    def project(communicator):
-        grace = dataclasses.make_dataclass(
-            "G", ["compressor", "communicator"])(_FakeComp(), communicator)
-        rows = bench.project_multichip(topk_step, dense_step, grace,
-                                       wire_b, dense_b, n_elems)
-        return {r["world"]: r["xslice"] for r in rows}
-
-    flat = project(comm.Allgather())
-    hier = project(comm.HierarchicalAllreduce(slice_size=bench.XSLICE_CHIPS))
-    # the flat indictment, reproduced from the committed numbers
-    assert flat[256]["speedup_vs_dense"] == pytest.approx(0.896, abs=0.01)
-    assert flat[256]["ici_bytes"] == 0            # all-DCN beyond one slice
-    # the hier fix: same step time, >1× dense at cross-slice scale
-    assert hier[256]["speedup_vs_dense"] > 1.0
-    assert hier[256]["ici_bytes"] > 0 and hier[256]["dcn_bytes"] > 0
-    assert hier[256]["dcn_bytes"] < 0.05 * flat[256]["dcn_bytes"]
-    # and the win grows with scale: every cross-slice world beats flat
-    for w in (16, 64, 256):
-        assert hier[w]["speedup_vs_dense"] > flat[w]["speedup_vs_dense"]
 
 
 # ---------------------------------------------------------------------------

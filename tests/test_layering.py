@@ -1,0 +1,236 @@
+"""The architecture as a table the tests hold.
+
+(i) Which unit of ``grace_tpu/`` may import which, lower layers first. Every
+file's AST is walked, function-level imports included, so a lazy import
+counts like one at the top. An edge outside ``ALLOWED`` fails unless it is a
+named debt in ``KNOWN_UPWARD`` — and a debt that has been paid but is still
+listed fails too, so the table stays the drawing of the system as it is.
+No unit imports a script from the checkout root, under any name.
+
+(ii) What a cell's step loads: each of the benchmark's cells is built and
+stepped once in a process of its own, which must end without the static
+auditor, the tuner, the second trace reducer or the framework bridges in
+``sys.modules``: nothing a cell measures can depend on them.
+"""
+
+import ast
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(REPO, "grace_tpu")
+WORKLOADS = os.path.join(REPO, "benchmarks", "workloads")
+
+# Local modules that are not part of the package: the scripts and the
+# directories of the checkout root, whatever they are called. No unit may
+# import one.
+ROOT_MODULES = {
+    name[:-len(".py")] if name.endswith(".py") else name
+    for name in os.listdir(REPO)
+    if not name.startswith(".") and name != "grace_tpu"
+    and (name.endswith(".py") or os.path.isdir(os.path.join(REPO, name)))}
+
+# unit -> the units it may import. Lower layers first: a unit's row names
+# only units above it in this list (its own files are always allowed).
+ALLOWED = {
+    # named scopes, the compile ledger, the telemetry ring and its sinks
+    "telemetry": set(),
+    # Compressor / Memory / Communicator, Topology
+    "core": {"telemetry"},
+    "ops": {"telemetry"},
+    "parallel": {"core"},
+    "memories": {"core"},
+    "utils": {"core"},
+    "checkpoint": set(),
+    "compressors": {"core", "ops", "telemetry"},
+    "models": {"telemetry"},
+    "data": {"parallel"},
+    "comm": {"core", "memories", "telemetry", "utils"},
+    # the optax transform: compensate, compress, exchange, decompress
+    "transform": {"comm", "core", "telemetry", "utils"},
+    # params dict -> the configured triad
+    "helper": {"comm", "compressors", "core", "memories", "transform",
+               "utils"},
+    # the jitted shard_map step
+    "train": {"core", "parallel", "telemetry", "transform"},
+    # guard, consensus, adapt; and the host-side controllers that drive
+    # train steps (elastic, retune)
+    "resilience": {"comm", "core", "parallel", "telemetry", "transform",
+                   "train"},
+    "profiling": {"telemetry", "transform", "utils"},
+    # the static auditor: traces a configured step, reads everything below
+    "analysis": {"comm", "core", "helper", "ops", "parallel", "profiling",
+                 "resilience", "telemetry", "train", "transform"},
+    "tuning": {"analysis", "comm", "core", "helper", "models", "parallel",
+               "profiling", "train", "transform", "utils"},
+    "interop": {"helper", "parallel", "transform"},
+    # the package's own __init__: the public names
+    "grace_tpu": {"comm", "core", "helper", "parallel", "resilience",
+                  "telemetry", "train", "transform"},
+}
+
+# Edges that point up today, each with the debt that owns it (ROADMAP.md
+# queue 3). Remove a row in the PR that removes the import.
+KNOWN_UPWARD = {
+    ("transform", "resilience"): "debt (e): transform.py -> resilience.adapt",
+    ("train", "resilience"): "debt (e): train.py -> resilience.consensus",
+    ("utils", "resilience"): "debt (e): utils/metrics.py -> resilience.guard",
+    ("telemetry", "resilience"):
+        "debt (e): telemetry/reader.py -> resilience.guard",
+    ("telemetry", "checkpoint"):
+        "debt (e): telemetry/sinks.py -> checkpoint._retry_io",
+    ("helper", "resilience"):
+        "debt (e): helper.py -> resilience.adapt (the ladder's rungs)",
+    ("resilience", "analysis"):
+        "debt (e)/(f): elastic.py, retune.py -> analysis",
+    ("resilience", "profiling"): "debt (e)/(f): elastic.py -> profiling",
+    ("resilience", "tuning"): "debt (a): retune.py -> tuning.online",
+    ("analysis", "tuning"):
+        "D7: analysis/configs.py -> tuning's generated variants",
+}
+
+
+def unit_files(unit):
+    if unit == "grace_tpu":
+        return [os.path.join(PACKAGE, "__init__.py")]
+    single = os.path.join(PACKAGE, unit + ".py")
+    if os.path.isfile(single):
+        return [single]
+    return sorted(os.path.join(d, f)
+                  for d, _, fs in os.walk(os.path.join(PACKAGE, unit))
+                  for f in fs if f.endswith(".py"))
+
+
+def package_of(path):
+    """Dotted package a file's relative imports resolve against."""
+    rel = os.path.relpath(path, REPO)[:-len(".py")].split(os.sep)
+    return rel[:-1]
+
+
+def imported_modules(path):
+    """Every module a file imports, absolute, wherever the statement is."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    package = package_of(path)
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = (package[:len(package) - node.level + 1]
+                    if node.level else [])
+            module = ".".join(base + ([node.module] if node.module else []))
+            found.add(module)
+            # `from grace_tpu import comm`, `from . import passes`
+            found.update(f"{module}.{a.name}" for a in node.names)
+    return found
+
+
+def edges_of(unit):
+    """(units of the package, root modules) that ``unit`` imports."""
+    units, roots = set(), set()
+    for path in unit_files(unit):
+        for module in imported_modules(path):
+            parts = module.split(".")
+            if parts[0] == "grace_tpu" and len(parts) > 1:
+                if parts[1] in ALLOWED and parts[1] != unit:
+                    units.add(parts[1])
+            elif parts[0] in ROOT_MODULES:
+                roots.add(parts[0])
+    return units, roots
+
+
+def test_the_table_covers_the_package():
+    on_disk = {"grace_tpu"}
+    for name in os.listdir(PACKAGE):
+        if name.endswith(".py") and name != "__init__.py":
+            on_disk.add(name[:-len(".py")])
+        elif os.path.isfile(os.path.join(PACKAGE, name, "__init__.py")):
+            on_disk.add(name)
+    assert on_disk == set(ALLOWED)
+    # lower layers first: a row names only rows written before it
+    seen = set()
+    for unit, allowed in ALLOWED.items():
+        assert allowed <= seen, (unit, allowed - seen)
+        seen.add(unit)
+    for (unit, target), debt in KNOWN_UPWARD.items():
+        assert unit in ALLOWED and target in ALLOWED and debt
+        assert target not in ALLOWED[unit]
+
+
+@pytest.mark.parametrize("unit", list(ALLOWED))
+def test_unit_imports_stay_inside_its_layer(unit):
+    units, roots = edges_of(unit)
+    assert not roots, (
+        f"grace_tpu/{unit} imports from the checkout root: {sorted(roots)}")
+    known = {t for (u, t) in KNOWN_UPWARD if u == unit}
+    new = units - ALLOWED[unit] - known
+    assert not new, (
+        f"grace_tpu/{unit} now imports {sorted(new)}: move the code, or "
+        "draw the edge in ALLOWED if the layering is meant to change")
+    paid = known - units
+    assert not paid, (
+        f"grace_tpu/{unit} no longer imports {sorted(paid)}: take the "
+        "row out of KNOWN_UPWARD")
+
+
+# ---------------------------------------------------------------------------
+# (ii) a cell's step loads only the hot path
+# ---------------------------------------------------------------------------
+
+CELL_STEP = """
+import json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+cell = json.load(open(sys.argv[1]))
+import jax, jax.numpy as jnp, optax
+from grace_tpu.parallel import set_cpu_device_count
+set_cpu_device_count(cell["chips"])
+import grace_tpu
+from grace_tpu import grace_from_params
+from grace_tpu.train import (init_stateful_train_state,
+                             make_stateful_train_step)
+
+mesh = grace_tpu.data_parallel_mesh()
+assert mesh.devices.size == cell["chips"]
+tx = optax.chain(grace_from_params(dict(cell["grace"])).transform(seed=0),
+                 optax.sgd(0.1))
+params = {"w": jnp.ones((64, 32)), "b": jnp.zeros((32,)),
+          "scale": jnp.ones((64,))}
+
+def loss_fn(p, mstate, batch):
+    x, y = batch
+    out = (x * p["scale"]) @ p["w"] + p["b"]
+    return jnp.mean((out - y) ** 2), mstate
+
+state = init_stateful_train_state(params, {}, tx, mesh)
+step = make_stateful_train_step(loss_fn, tx, mesh)
+n = 8 * cell["chips"]
+state, loss = step(state, (jnp.ones((n, 64)), jnp.zeros((n, 32))))
+assert bool(jnp.isfinite(loss))
+print(json.dumps(sorted(sys.modules)))
+"""
+
+OFF_THE_HOT_PATH = ("grace_tpu.analysis", "grace_tpu.tuning",
+                    "grace_tpu.profiling", "grace_tpu.interop")
+
+
+@pytest.mark.parametrize("cell", sorted(
+    f[:-len(".json")] for f in os.listdir(WORKLOADS)))
+def test_a_cells_step_loads_only_the_hot_path(cell):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["PYTHONPATH"] = REPO
+    done = subprocess.run(
+        [sys.executable, "-c", CELL_STEP,
+         os.path.join(WORKLOADS, cell + ".json")],
+        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
+    assert done.returncode == 0, done.stderr[-2000:]
+    loaded = json.loads(done.stdout.strip().splitlines()[-1])
+    assert "grace_tpu.transform" in loaded and "grace_tpu.train" in loaded
+    extra = [m for m in loaded
+             if m.startswith(OFF_THE_HOT_PATH)
+             or m.split(".")[0] in ROOT_MODULES]
+    assert not extra, extra

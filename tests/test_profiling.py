@@ -17,7 +17,9 @@ Covers the read side of the observability stack:
 * the compile ledger (grace_tpu.telemetry.compiles) the recorder reads:
   JAX's own trace/lower/compile spans and cache events, by function;
 * tools/perf_report.py CLI: clean exit on the fixture, exit 1 on a seeded
-  baseline regression, PROF_LAST.json evidence, evidence_summary pickup.
+  baseline regression, PROF_LAST.json evidence;
+* the Chrome-trace export (profiling.trace_export): round trip, determinism,
+  host merge.
 
 Everything runs on CPU with no devices (the mesh fixture is the simulated
 8-device CPU mesh).
@@ -28,7 +30,6 @@ from __future__ import annotations
 import gzip
 import json
 import os
-import shutil
 import sys
 import warnings
 
@@ -877,16 +878,6 @@ def test_perf_report_overlap_regression_fires(tmp_path):
     assert perf_report.compare_to_baseline(baseline, current, 0.10) == []
 
 
-def test_tpu_profile_report_runs_offline(tmp_path, capsys):
-    """Satellite: --report works on CPU against a saved trace via the
-    shared analyzer (the ad-hoc xplane summary is gone)."""
-    tpu_profile = _tools_import("tpu_profile")
-    shutil.copy(FIXTURE, tmp_path / "host.trace.json.gz")
-    tpu_profile.report(str(tmp_path))
-    text = capsys.readouterr().out
-    assert "grace/compress" in text and "overlap" in text
-
-
 def test_telemetry_report_renders_perf_records(tmp_path, capsys):
     telemetry_report = _tools_import("telemetry_report")
     path = tmp_path / "run.jsonl"
@@ -919,17 +910,58 @@ def test_telemetry_report_renders_perf_records(tmp_path, capsys):
     assert "perf_step_times" not in text.split("== guard events")[1]
 
 
-def test_evidence_summary_picks_up_prof_last(tmp_path, monkeypatch):
-    evidence_summary = _tools_import("evidence_summary")
-    monkeypatch.setattr(evidence_summary, "ROOT", str(tmp_path))
-    prof = {"tool": "perf_report", "trace": "tests/data/perf_trace.json.gz",
-            "stages_ms": {"grace/compress": 1.2, "grace/exchange": 1.6},
-            "total_device_ms": 7.6, "overlap_fraction": 0.25,
-            "step_times": {"p50_ms": 0.9}, "regressions": [],
-            "note": "canned CPU fixture trace",
-            "captured_at": "2026-08-04T00:00:00+00:00"}
-    (tmp_path / "PROF_LAST.json").write_text(json.dumps(prof))
-    md = evidence_summary.build()
-    assert "Performance attribution" in md
-    assert "overlap fraction 25.0%" in md
-    assert "0 baseline regression(s)" in md
+# ---------------------------------------------------------------------------
+# Chrome-trace export round-trip
+
+
+def _spans():
+    from grace_tpu.profiling.trace_analysis import Span
+    return [
+        Span(name="allreduce-hop0", ts=0.0, dur=10.0,
+             device="/device:TPU:0", lane="XLA Ops", scope="ici"),
+        Span(name="allreduce-hop1", ts=10.0, dur=12.0,
+             device="/device:TPU:0", lane="XLA Ops", scope="dcn"),
+        Span(name="step", ts=0.0, dur=25.0,
+             device="/device:TPU:0", lane="Steps", scope=""),
+        Span(name="allreduce-hop0", ts=1.0, dur=9.0,
+             device="/device:TPU:1", lane="XLA Ops", scope="ici"),
+    ]
+
+
+@pytest.mark.parametrize("suffix", [".json", ".json.gz"])
+def test_chrome_trace_round_trip(tmp_path, suffix):
+    from grace_tpu.profiling.trace_analysis import load_trace_events
+    from grace_tpu.profiling.trace_export import write_chrome_trace
+    spans = _spans()
+    path = str(tmp_path / f"trace{suffix}")
+    write_chrome_trace(spans, path)
+    assert set(load_trace_events(path)) == set(spans)
+
+
+def test_chrome_trace_doc_is_deterministic():
+    from grace_tpu.profiling.trace_export import chrome_trace_doc
+    spans = _spans()
+    assert (json.dumps(chrome_trace_doc(spans))
+            == json.dumps(chrome_trace_doc(list(reversed(spans)))))
+
+
+def test_merge_host_traces_prefixes_and_aligns():
+    from grace_tpu.profiling.trace_analysis import parse_chrome_trace
+    from grace_tpu.profiling.trace_export import (chrome_trace_doc,
+                                                  merge_host_traces)
+    spans = _spans()
+    # host1's clock starts 1e6 µs later; align rebases both to t=0.
+    shifted = [type(s)(name=s.name, ts=s.ts + 1e6, dur=s.dur,
+                       device=s.device, lane=s.lane, scope=s.scope)
+               for s in spans]
+    merged = merge_host_traces({"host0": spans, "host1": shifted})
+    assert len(merged) == 2 * len(spans)
+    devices = {s.device for s in merged}
+    assert "host0//device:TPU:0" in devices
+    assert "host1//device:TPU:1" in devices
+    by_host = {h: [s for s in merged if s.device.startswith(h + "/")]
+               for h in ("host0", "host1")}
+    assert min(s.ts for s in by_host["host0"]) == 0.0
+    assert min(s.ts for s in by_host["host1"]) == 0.0
+    # the merged timeline still round-trips through the parser
+    assert set(parse_chrome_trace(chrome_trace_doc(merged))) == set(merged)

@@ -6,8 +6,8 @@ intermediate sum is exactly representable — integer-valued grads — so no
 tolerance can hide a wire-format bug); the per-hop requantization error is
 bounded and grows ~linearly in hop count (one requant hop vs world−1),
 never explodes; communicator-aware wire bytes are < 0.5× allgather's at
-W=8 and agree with the shared ``recv_wire_bytes`` model the bench
-projections use; the enforced compatibility gates (stateless +
+W=8 and agree with the shared ``recv_wire_bytes`` model the tuner's
+pricing uses; the enforced compatibility gates (stateless +
 summable-or-hop-requant) reject everything else with an actionable
 TypeError; and the ring composes with the resilience stack — guard
 rollback stays atomic and the consensus audit stays a bit-exact no-op on
@@ -225,7 +225,7 @@ def test_from_params_builds_ring():
 # ---------------------------------------------------------------------------
 
 def test_recv_wire_bytes_model():
-    """One model shared by bench projections and the telemetry ring:
+    """One model shared by the tuner's pricing and the telemetry ring:
     ring receives ~2·payload·(W−1)/W — flat in W — vs allgather's
     (W−1)·payload; under half allgather's bytes from W=8 up."""
     payload, n = 1000, 4096
@@ -240,10 +240,6 @@ def test_recv_wire_bytes_model():
             assert rb < gb
         if w >= 8:
             assert rb < 0.5 * gb
-    # bench's model is a delegation to the same method — keep them fused
-    import bench
-    assert bench.recv_bytes_model(ring, False, payload, n, 8) == \
-        ring.recv_wire_bytes(payload, n, 8)
 
 
 def _problem(seed=0):

@@ -565,50 +565,6 @@ def test_routed_update_applies_per_leaf_codecs(mesh, int_grads):
 # the transformer track wins on the model
 # ---------------------------------------------------------------------------
 
-def test_routed_bert_projection_beats_dense_at_scale():
-    """ISSUE 14 acceptance: the routed rscatter BERT config's per-link
-    xslice projection is >1.0× vs dense at W≥64, priced with the
-    committed on-chip dense step time (BENCH_BERT_TPU_LAST.json) on BOTH
-    sides — the tuner's wire-dominated convention — through the shared
-    per-link model; the committed flat bert_powersgd_r4 row stays the
-    0.80× before-picture."""
-    import sys
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    import tpu_bert_bench as B
-
-    from grace_tpu.models import transformer
-
-    with open(os.path.join(ROOT, "BENCH_BERT_TPU_LAST.json")) as f:
-        doc = json.load(f)
-    rows = {r["config"]: r for r in doc["rows"]}
-    # the before-picture: the committed flat BERT row LOSES
-    assert rows["bert_powersgd_r4"]["vs_baseline"] < 1.0
-    dense = rows["bert_dense"]
-    n = dense["per_device_bs"] * doc.get("n_devices", 1)
-    step_s = n / dense["seqs_per_sec"]
-
-    cfg = transformer.base(num_classes=2, max_len=dense["seq_len"])
-    params = jax.eval_shape(
-        lambda k: transformer.init(k, cfg)[0], jax.random.key(0))
-    n_elems = sum(int(np.prod(l.shape, dtype=np.int64))
-                  for l in jax.tree_util.tree_leaves(params))
-    assert n_elems == dense["n_params"]
-
-    grace = grace_from_params({
-        "compressor": "topk", "compress_ratio": 0.01,
-        "topk_algorithm": "chunk", "memory": "residual",
-        "communicator": "rscatter", "fusion": "none",
-        "route": B.BERT_ROUTE})
-    proj = B.project_routed(step_s, step_s, grace, params, n_elems)
-    by_world = {p["world"]: p for p in proj}
-    for w in (64, 256):
-        assert by_world[w]["xslice"]["speedup_vs_dense"] > 1.0, (
-            w, by_world[w]["xslice"])
-    # honest split: a flat schedule's xslice bytes ride DCN beyond one
-    # slice, and the routed wire is a small fraction of dense
-    assert by_world[64]["xslice"]["dcn_bytes"] > 0
-    assert by_world[64]["recv_bytes_per_rank"] < 0.05 * 4 * n_elems
-
 
 # ---------------------------------------------------------------------------
 # tuner 2-D spec + chaos smoke

@@ -9,10 +9,7 @@ the hier family on top at the W=256/slice8 projection topology, the tuner
 is deterministic (same registry + topology → byte-identical TUNE_LAST.json
 modulo timestamps), and a real end-to-end CPU run produces a
 provenance-stamped winner that beats the worst shortlisted candidate on
-measured step time and passes the measured≤static overlap sandwich —
-consumed by evidence_summary. Plus the stale-evidence honesty satellites:
-bench.evidence_staleness flags the committed pre-PR-7–10 captures, and
-bench_all's --tuned family is the one-command refresh.
+measured step time and passes the measured≤static overlap sandwich.
 """
 
 import importlib.util
@@ -21,8 +18,6 @@ import os
 
 import pytest
 
-import bench
-import bench_all
 from grace_tpu.helper import grace_from_params
 from grace_tpu.tuning import (Candidate, TuneTopology, candidate_legal,
                               enumerate_candidates, run_tune, static_prune,
@@ -231,10 +226,14 @@ def test_static_top_pick_at_xslice_is_sharded_or_hier_family(static_doc):
     assert flat["predicted"]["dcn_bytes"] > 0
 
 
-def test_cost_model_stamped_and_shared_with_bench(static_doc):
+def test_cost_model_stamped_from_cost_constants(static_doc):
+    from grace_tpu.tuning import cost
     cm = static_doc["cost_model"]
-    assert cm["ici_bytes_per_s"] == bench.ICI_RING_BYTES_PER_S
-    assert cm["dcn_bytes_per_s"] == bench.DCN_BYTES_PER_S
+    assert cm["ici_bytes_per_s"] == cost.ICI_RING_BYTES_PER_S
+    assert cm["dcn_bytes_per_s"] == cost.DCN_BYTES_PER_S
+    assert cm["wan_bytes_per_s"] == cost.WAN_BYTES_PER_S
+    assert cm["constants_source"] \
+        == cost.PROJECTION_MODEL["constants_source"]
     assert "recv_link_bytes" in cm["rule"]
 
 
@@ -262,7 +261,7 @@ def test_tune_determinism(tmp_path):
 # end-to-end: measured shortlist + sandwich + evidence
 # ---------------------------------------------------------------------------
 
-def test_tune_e2e_cpu_winner_and_sandwich(mesh, tmp_path, monkeypatch):
+def test_tune_e2e_cpu_winner_and_sandwich(mesh, tmp_path):
     """The whole loop on the 8-device CPU mesh: enumerate → prune →
     measure (real timed steps, dense brackets interleaved same-session) →
     winner stamped with provenance + topology + the measured≤static
@@ -291,14 +290,11 @@ def test_tune_e2e_cpu_winner_and_sandwich(mesh, tmp_path, monkeypatch):
         assert s["measured_overlap"] \
             <= s["static_overlap_bound"] + s["slack"]
 
-    # evidence round-trip: TUNE_LAST.json consumed by evidence_summary
+    # evidence round-trip: TUNE_LAST.json reads back what was stamped
     write_tune_evidence(doc, str(tmp_path / "TUNE_LAST.json"))
-    evidence_summary = _load_tool("evidence_summary")
-    monkeypatch.setattr(evidence_summary, "ROOT", str(tmp_path))
-    md = evidence_summary.build()
-    assert "Autotuning (graft-tune)" in md
-    assert w["candidate"] in md
-    assert "sandwich" in md and "holds" in md
+    back = json.loads((tmp_path / "TUNE_LAST.json").read_text())
+    assert back["winner"]["candidate"] == w["candidate"]
+    assert back["winner"]["overlap_sandwich"]["holds"]
 
 
 def test_graft_tune_cli_static(tmp_path):
@@ -314,7 +310,7 @@ def test_graft_tune_cli_static(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# satellites: lint registry coverage, bench_all --tuned, stale evidence
+# satellites: lint registry coverage
 # ---------------------------------------------------------------------------
 
 def test_variant_configs_registered_for_lint():
@@ -338,60 +334,6 @@ def test_variant_config_audits_clean():
                  if e["name"] == "tune-qsgd4-hier-packed")
     findings = audit_config(entry)
     assert [f for f in findings if f.severity == "error"] == []
-
-
-def test_bench_all_tuned_family(monkeypatch):
-    names = {c["name"] for c in bench_all.CONFIGS}
-    assert set(bench_all.TUNED_ROW_NAMES) <= names
-    row = next(c for c in bench_all.CONFIGS
-               if c["name"] == "qsgd4_packed_bucketed_pallas_bs256")
-    assert row["tpu_only"] and row["per_device_bs"] == 256
-    assert row["params"] == {"compressor": "qsgd", "quantum_num": 7,
-                             "use_pallas": True, "memory": "none",
-                             "communicator": "ring", "fusion": 1024}
-    hier = next(c for c in bench_all.CONFIGS
-                if c["name"] == "topk1pct_hier_bs256")
-    assert hier["params"]["slice_size"] == 8    # the projection topology
-    # --tuned selection: one command, dense anchor first, nothing else
-    monkeypatch.setenv("GRACE_BENCH_TUNED", "1")
-    active = bench_all.active_configs()
-    assert [c["name"] for c in active][0] == "none"
-    assert {c["name"] for c in active} == set(bench_all.TUNED_ROW_NAMES)
-    monkeypatch.delenv("GRACE_BENCH_TUNED")
-    assert len(bench_all.active_configs()) == len(bench_all.CONFIGS)
-
-
-def test_evidence_staleness_detector():
-    # The committed captures predate PRs 7-10: no provenance block, no
-    # fusion row stamps, no hier rows — all three detectors fire.
-    head = bench.load_tpu_evidence(
-        os.path.join(os.path.dirname(bench.__file__),
-                     "BENCH_TPU_LAST.json"))
-    assert head is not None
-    reasons = bench.evidence_staleness(head)
-    assert reasons and any("provenance" in r for r in reasons)
-    sweep = bench.load_tpu_evidence(bench.SWEEP_SUMMARY_PATH)
-    assert any("PR 7" in r for r in bench.evidence_staleness(sweep))
-    # A fresh-shaped capture clears every detector.
-    fresh = {
-        "provenance": {"git_commit": "abc1234", "pallas_enabled": True,
-                       "fusion": 1024},
-        "rows": [
-            {"config": "none", "imgs_per_sec": 1.0, "fusion": None,
-             "grace_params": {"communicator": "allreduce"}},
-            {"config": "topk1pct_hier_bs256", "imgs_per_sec": 1.0,
-             "fusion": "flat", "grace_params": {"communicator": "hier"}},
-            {"config": "qsgd4_packed_bucketed_pallas_bs256",
-             "imgs_per_sec": 1.0, "fusion": 1024,
-             "grace_params": {"communicator": "ring"}},
-        ],
-    }
-    assert bench.evidence_staleness(fresh) == []
-    # _mark_stale stamps the carried-along copy, never the clean one.
-    assert "stale" not in bench._mark_stale(fresh)
-    marked = bench._mark_stale(head)
-    assert marked["stale"] == bench.STALE_BANNER
-    assert marked["stale_reasons"]
 
 
 # ---------------------------------------------------------------------------
@@ -464,21 +406,3 @@ def test_numeric_gate_shared_scale_2bit():
     assert numeric_verdict(homo4, TuneTopology(world=4)) is None
     r8 = numeric_verdict(homo4, W8)
     assert r8 is not None and "payload_sum_max_world=7" in r8
-
-
-def test_evidence_summary_stale_banner(tmp_path, monkeypatch):
-    evidence_summary = _load_tool("evidence_summary")
-    monkeypatch.setattr(evidence_summary, "ROOT", str(tmp_path))
-    stale_doc = {"chip": "TPU v5 lite", "captured_at": "2026-08-01",
-                 "rows": [{"config": "topk1pct", "imgs_per_sec": 2264.6,
-                           "vs_baseline": 0.9897}]}
-    (tmp_path / "BENCH_TPU_LAST.json").write_text(json.dumps(stale_doc))
-    md = evidence_summary.build()
-    assert "STALE — predates PRs 7–10" in md
-    assert "bench_all.py --tuned" in md
-    # a fresh doc renders with no banner
-    fresh = {**stale_doc,
-             "provenance": {"pallas_enabled": True, "fusion": None},
-             "rows": [{**stale_doc["rows"][0], "fusion": None}]}
-    (tmp_path / "BENCH_TPU_LAST.json").write_text(json.dumps(fresh))
-    assert "STALE" not in evidence_summary.build()

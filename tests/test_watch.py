@@ -517,23 +517,6 @@ def test_graft_watch_baseline_gates_seeded_regression(tmp_path, capsys):
     assert doc["baseline"] == str(base)
 
 
-@pytest.mark.watch
-def test_evidence_summary_picks_up_watch_last(tmp_path, monkeypatch):
-    evidence_summary = _load_tool("evidence_summary")
-    monkeypatch.setattr(evidence_summary, "ROOT", str(tmp_path))
-    doc = {"tool": "graft_watch", "artifact": "chaos_telemetry.jsonl",
-           "events": 69, "kind_counts": {"telemetry": 60, "watch": 6,
-                                         "anomaly": 3},
-           "anomalies": 3, "anomalous_ranks": [3],
-           "first_anomaly_step": 0, "regressions": [],
-           "captured_at": "2026-08-04T00:00:00+00:00"}
-    (tmp_path / "WATCH_LAST.json").write_text(json.dumps(doc))
-    md = evidence_summary.build()
-    assert "Run health (graft-watch)" in md
-    assert "anomalous rank(s) [3]" in md
-    assert "0 baseline regression(s)" in md
-
-
 # ---------------------------------------------------------------------------
 # telemetry_report watch section + --json (satellite)
 # ---------------------------------------------------------------------------

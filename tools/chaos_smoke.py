@@ -75,7 +75,7 @@ injects NaNs so the guard genuinely trips, and requires the controller to
 register the trip as escalate-and-hold evidence (``escalations > 0``) —
 the ladder-floor-too-loose semantics. Evidence (tighten/loosen counts and
 steps, the tighten-before-guard ordering verdict, the rung trace) lands in
-``--adapt-out`` (ADAPT_LAST.json), rendered by evidence_summary.py;
+``--adapt-out`` (ADAPT_LAST.json);
 ``adapt_*`` events stream into the telemetry JSONL (timeline kind
 ``adapt``).
 
@@ -92,7 +92,7 @@ rejoins, replicas bit-identical after). With ``--hier`` the kill takes the
 flagged rank's WHOLE slice — a K→K−1 DCN-level resize that keeps
 ``slice_size``. Evidence (resize events, rejoin fingerprint pricing,
 convergence-floor verdict, per-world footprint checks) lands in
-``--elastic-out`` (ELASTIC_LAST.json), rendered by evidence_summary.py;
+``--elastic-out`` (ELASTIC_LAST.json);
 ``elastic_*`` events additionally stream into the telemetry JSONL.
 
 Retune scenario (ISSUE 18): ``--retune`` drills fault-tolerant online
@@ -117,10 +117,9 @@ checkpoint BIT-EXACTLY (``state_digest`` witness) within the probation
 window. Every transition leg is bounded by the drain watchdog discipline
 (``--drain-timeout``). Evidence (drift/promote/demote steps, migration
 stats, replica-variant counts, the event-ordering verdict, the bit-exact
-restore witness) lands in ``--retune-out`` (RETUNE_LAST.json), rendered
-by evidence_summary.py; ``retune_*`` events stream into the telemetry
-JSONL (timeline kind ``retune``) and ``retune_promote``/``retune_demote``
-open flight-recorder incidents when ``--incidents`` is set.
+restore witness) lands in ``--retune-out`` (RETUNE_LAST.json);
+``retune_*`` events stream into the telemetry JSONL (timeline kind
+``retune``).
 
 Region scenario (ISSUE 16): ``--region`` runs the cross-region failure
 lifecycle on the 8-device mesh laid out as 2 regions × 2 slices × 2 ranks
@@ -137,8 +136,7 @@ W with stale pre-departure params implanted on every lost rank and must
 pass the consensus-gated rejoin barrier (one region rejoin == one barrier
 repair event; replicas bit-identical after). The guard must stay silent
 throughout the healthy path, and the convergence floor is judged after
-the rejoin. Evidence lands in ``--region-out`` (REGION_LAST.json),
-rendered by evidence_summary.py.
+the rejoin. Evidence lands in ``--region-out`` (REGION_LAST.json).
 
 Usage::
 
@@ -164,63 +162,29 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _write_evidence_doc(doc: dict, out_path: str, *, world: int,
+                        slice_size=None, region_size=None,
+                        label: str = "evidence") -> None:
+    """The one exit for chaos evidence docs: stamp the uniform
+    n_devices/topology/git_rev provenance triple, write atomically."""
+    import json
 
-
-def _evidence_stamp(world, slice_size=None, region_size=None) -> dict:
-    """Uniform provenance stamp for every chaos evidence doc — the same
-    n_devices/topology/git_rev triple bench rows carry (ISSUE 17: the
-    ADAPT/ELASTIC/REGION files used to ship with only a captured_at)."""
-    from grace_tpu.evidence.ledger import git_head_rev
+    from grace_tpu.utils.logging import git_commit
     tiers = ["ici"]
     if slice_size:
         tiers.append("dcn")
     if region_size:
         tiers.append("wan")
-    return {"git_rev": git_head_rev(),
-            "n_devices": world,
-            "topology": {"world": world, "tiers": tiers,
-                         "slice": slice_size or None,
-                         "region": region_size or None}}
-
-
-def _write_evidence_doc(doc: dict, out_path: str, *, ledger_id: str,
-                        metric: str, value, world: int,
-                        slice_size=None, region_size=None,
-                        label: str = "evidence") -> None:
-    """The one exit for chaos evidence docs: stamp provenance, write
-    atomically, append the ledger record (repo-root artifacts only, so a
-    test run against a tmp path never touches the ledger)."""
-    import json
-    doc = {**doc, **_evidence_stamp(world, slice_size, region_size)}
+    doc = {**doc, "git_rev": git_commit(), "n_devices": world,
+           "topology": {"world": world, "tiers": tiers,
+                        "slice": slice_size or None,
+                        "region": region_size or None}}
     tmp = out_path + ".tmp"
     with open(tmp, "w") as f:
         json.dump(doc, f, indent=1)
         f.write("\n")
     os.replace(tmp, out_path)
     print(f"[chaos_smoke] {label}: {out_path}")
-    if os.path.dirname(os.path.abspath(out_path)) != ROOT:
-        return
-    from grace_tpu.evidence.ledger import record_artifact
-    record_artifact(
-        out_path, id=ledger_id, metric=metric, value=value,
-        claim_class="measured", tool="chaos_smoke", platform="cpu",
-        chip="cpu", n_devices=world,
-        topology=doc["topology"], config=doc.get("argv"),
-        lint_clean=None, git_rev=doc["git_rev"])
-
-
-def _incident_sink(jsonl_sink, args, provenance, tag: str):
-    """Wrap the JSONL evidence sink with the flight recorder when
-    --incidents is set: same record stream, plus ledger-attached
-    incident snapshots on guard trips / adapt escalations / drains."""
-    if not getattr(args, "incidents", None) or jsonl_sink is None:
-        return jsonl_sink, None
-    from grace_tpu.evidence.incident import IncidentRecorder
-    from grace_tpu.telemetry import MultiSink
-    recorder = IncidentRecorder(args.incidents, run_tag=tag,
-                                provenance=provenance)
-    return MultiSink(jsonl_sink, recorder), recorder
 
 
 def main(argv=None) -> int:
@@ -240,12 +204,6 @@ def main(argv=None) -> int:
                     help="JSONL telemetry artifact path ('' disables)")
     ap.add_argument("--telemetry-every", type=int, default=25,
                     help="steps per telemetry flush (one device_get each)")
-    ap.add_argument("--incidents", default="",
-                    help="directory for flight-recorder incident "
-                         "snapshots ('' disables): guard trips, adapt "
-                         "escalations and drains each dump the telemetry "
-                         "ring + watch timeline + adapt rung history as "
-                         "a ledger-attached incident record")
     ap.add_argument("--sdc", action="store_true",
                     help="also inject single-rank param SDC (ChaosParams) "
                          "and require the consensus auditor to repair it")
@@ -572,8 +530,6 @@ def main(argv=None) -> int:
             fallback_steps=args.fallback_steps,
             homo=bool(args.homo))
         sink = JSONLSink(args.telemetry_out, provenance=prov)
-        sink, _ = _incident_sink(sink, args, prov,
-                                 "watch" if args.watch else "nan")
         reader = TelemetryReader(sink, every=args.telemetry_every,
                                  anomaly=args.watch)
     monitor = GuardMonitor(sink=sink)
@@ -874,7 +830,6 @@ def _fsdp_main(args) -> int:
             nan_prob=args.nan_prob, steps=args.steps,
             fsdp=fsdp, dp=dp)
         sink = JSONLSink(args.telemetry_out, provenance=prov)
-        sink, _ = _incident_sink(sink, args, prov, "fsdp")
         reader = TelemetryReader(sink, every=args.telemetry_every)
     monitor = GuardMonitor(sink=sink)
     consensus_mon = ConsensusMonitor(sink=sink)
@@ -1106,7 +1061,6 @@ def _adapt_main(args) -> int:
         argv=" ".join(sys.argv[1:]), steps=args.steps,
         adapt=True, adapt_window=window, adapt_rank=args.adapt_rank)
     sink = JSONLSink(args.telemetry_out, provenance=prov)
-    sink, _ = _incident_sink(sink, args, prov, "adapt")
     reader = TelemetryReader(sink, every=args.telemetry_every)
     adapt_mon = AdaptMonitor(sink=sink)
     monitor = GuardMonitor(sink=sink)
@@ -1232,10 +1186,7 @@ def _adapt_main(args) -> int:
             "guard_skips": int(guard_c["notfinite_count"]),
             "final_loss": float(total),
         }
-        _write_evidence_doc(doc, args.adapt_out,
-                            ledger_id="adapt-drill",
-                            metric="adapt_ordering_ok",
-                            value=bool(ordering_ok), world=world,
+        _write_evidence_doc(doc, args.adapt_out, world=world,
                             label="adapt evidence")
 
     if not np.isfinite(total):
@@ -1396,7 +1347,6 @@ def _retune_main(args) -> int:
 
     tape = _Tape()
     sink = MultiSink(JSONLSink(args.telemetry_out, provenance=prov), tape)
-    sink, _ = _incident_sink(sink, args, prov, "retune")
     reader = TelemetryReader(sink, every=tev)
     monitor = GuardMonitor(sink=sink)
 
@@ -1644,10 +1594,7 @@ def _retune_main(args) -> int:
             "first_steps": firsts,
             "final_loss": float(loss),
         }
-        _write_evidence_doc(doc, args.retune_out,
-                            ledger_id="retune-drill",
-                            metric="retune_demote_bit_exact",
-                            value=bool(ev_dem["bit_exact"]), world=world,
+        _write_evidence_doc(doc, args.retune_out, world=world,
                             label="retune evidence")
 
     if not np.isfinite(loss):
@@ -1775,7 +1722,6 @@ def _elastic_main(args) -> int:
             argv=" ".join(sys.argv[1:]), steps=args.steps,
             elastic=True, elastic_rank=doomed, hier=args.hier)
         sink = JSONLSink(args.telemetry_out, provenance=prov)
-        sink, _ = _incident_sink(sink, args, prov, "elastic")
         reader = TelemetryReader(sink, every=args.telemetry_every,
                                  anomaly=True)
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="grace_elastic_")
@@ -1944,10 +1890,7 @@ def _elastic_main(args) -> int:
                       "floor": args.floor, "met": bool(floor_met)},
             "footprint": {str(plan.new_world): fp_down, str(world): fp_up},
         }
-        _write_evidence_doc(doc, args.elastic_out,
-                            ledger_id="elastic-drill",
-                            metric="elastic_floor_met",
-                            value=bool(floor_met), world=world,
+        _write_evidence_doc(doc, args.elastic_out, world=world,
                             slice_size=(args.slice_size if args.hier
                                         else None),
                             label="elastic evidence")
@@ -2079,7 +2022,6 @@ def _region_main(args) -> int:
             argv=" ".join(sys.argv[1:]), steps=args.steps,
             region=True, region_size=rz, slice_size=s)
         sink = JSONLSink(args.telemetry_out, provenance=prov)
-        sink, _ = _incident_sink(sink, args, prov, "region")
         reader = TelemetryReader(sink, every=args.telemetry_every,
                                  anomaly=True)
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="grace_region_")
@@ -2286,10 +2228,7 @@ def _region_main(args) -> int:
                           str(world): fp_up},
             "guard_silent": guard_a["notfinite_count"] == 0,
         }
-        _write_evidence_doc(doc, args.region_out,
-                            ledger_id="region-drill",
-                            metric="region_floor_met",
-                            value=bool(floor_met), world=world,
+        _write_evidence_doc(doc, args.region_out, world=world,
                             slice_size=s, region_size=rz,
                             label="region evidence")
 

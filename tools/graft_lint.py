@@ -139,17 +139,14 @@ def main(argv=None) -> int:
     findings.extend(audit_all(configs, world=args.world, progress=progress))
 
     if args.all_configs and not args.rules_only:
-        # Evidence artifact (same incremental-evidence idiom as the bench
-        # files): the last full-matrix lint verdict, consumed by
-        # tools/evidence_summary.py. Atomic tmp+replace like the rest of
-        # the evidence flow.
+        # Evidence artifact: the last full-matrix lint verdict. Atomic
+        # tmp+replace like the rest of the evidence flow.
         import datetime
         import json as _json
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         # Per-pass finding counts over every pass that could have run —
         # zeros are evidence too (a pass that ran clean is a different
-        # statement than a pass that never ran); consumed by
-        # tools/evidence_summary.py.
+        # statement than a pass that never ran).
         passes_run = sorted({p for e in configs for p in e["passes"]})
         pass_counts = {p: sum(1 for f in findings if f.pass_name == p)
                        for p in passes_run}
@@ -193,23 +190,6 @@ def main(argv=None) -> int:
         except OSError as e:
             print(f"[graft_lint] could not save {path}: {e}",
                   file=sys.stderr)
-        else:
-            if os.path.dirname(os.path.abspath(path)) == root:
-                # Ledger-attach the repo-root artifact (same idiom as the
-                # bench/chaos evidence writers): the README's lint-clean
-                # claim cites this record through the graft-gate. Ad-hoc
-                # --evidence paths stay off the ledger, like ad-hoc bench
-                # output paths do.
-                from grace_tpu.evidence.ledger import record_artifact
-                record_artifact(
-                    path, id="lint-clean", metric="configs_lint_clean",
-                    value=doc["configs_audited"], claim_class="measured",
-                    tool="graft_lint", platform="cpu", chip="cpu",
-                    n_devices=args.world,
-                    config=" ".join(sys.argv[1:] if argv is None
-                                    else argv) or None,
-                    lint_clean=(doc["errors"] == 0),
-                    passes_run=passes_run)
 
     if args.jsonl:
         try:

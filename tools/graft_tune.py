@@ -95,8 +95,8 @@ def main(argv=None) -> int:
                          + " + ".join(DEFAULT_TOPOLOGIES) + ")")
     ap.add_argument("--model", default="toy",
                     help="param tree to price and measure against "
-                         "('toy' — the audit registry's model; resnet rows "
-                         "run through bench_all --tuned)")
+                         "('toy' — the audit registry's model; 'resnet50'"
+                         " is priced statically only)")
     ap.add_argument("--shortlist", type=int, default=3,
                     help="how many ranked survivors to measure (default 3)")
     ap.add_argument("--static-only", action="store_true",
@@ -113,8 +113,7 @@ def main(argv=None) -> int:
                     help="emit the evidence document instead of text")
     ap.add_argument("--out", default=None,
                     help="evidence path ('' disables; default TUNE_LAST."
-                         "json at the repo root, consumed by "
-                         "tools/evidence_summary.py)")
+                         "json at the repo root)")
     args = ap.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -21,9 +21,8 @@ guard/consensus transitions, ``perf_*`` profiling records, and
   regressions. The graft-lint/perf_report idiom: watch facts become
   CI-checkable.
 
-Writes the ``WATCH_LAST.json`` evidence document consumed by
-``tools/evidence_summary.py`` (``--out ''`` disables). Pure host-side —
-stdlib only, no jax import, usable on any box that holds the artifact.
+Writes the ``WATCH_LAST.json`` evidence document (``--out ''``
+disables). Pure host-side, usable on any box that holds the artifact.
 
 Exit status: 0 clean, 1 baseline regression, 2 crash — CI-gateable.
 
@@ -156,8 +155,7 @@ def main(argv=None) -> int:
                          "path")
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help="evidence document path ('' disables; default "
-                         "WATCH_LAST.json at the repo root, consumed by "
-                         "tools/evidence_summary.py)")
+                         "WATCH_LAST.json at the repo root)")
     args = ap.parse_args(argv)
 
     from grace_tpu.telemetry.anomaly import WatchMonitor
@@ -200,17 +198,12 @@ def main(argv=None) -> int:
               file=sys.stderr)
 
     if args.out:
-        # Uniform provenance stamp (ISSUE 17): the watch doc carries the
-        # same n_devices/topology/git_rev triple as every other evidence
+        # Uniform provenance stamp: the watch doc carries the same
+        # n_devices/topology/git_rev triple as every other evidence
         # writer, lifted from the artifact's own provenance header.
-        prov = timeline.provenance or {}
-        n_dev = prov.get("n_devices")
-        try:
-            from grace_tpu.evidence.ledger import git_head_rev
-            rev = git_head_rev()
-        except Exception:                                  # noqa: BLE001
-            rev = None
-        stamped = {**doc, "git_rev": rev, "n_devices": n_dev,
+        from grace_tpu.utils.logging import git_commit
+        n_dev = (timeline.provenance or {}).get("n_devices")
+        stamped = {**doc, "git_rev": git_commit(), "n_devices": n_dev,
                    "topology": ({"world": n_dev, "tiers": ["ici"],
                                  "slice": None, "region": None}
                                 if n_dev else None),
@@ -220,22 +213,6 @@ def main(argv=None) -> int:
         except OSError as e:
             print(f"[graft_watch] could not save {args.out}: {e}",
                   file=sys.stderr)
-        else:
-            if os.path.dirname(os.path.abspath(args.out)) == ROOT:
-                try:
-                    from grace_tpu.evidence.ledger import record_artifact
-                    record_artifact(
-                        args.out, id="watch-drill",
-                        metric="watch_anomalies",
-                        value=doc.get("anomalies"),
-                        claim_class="measured", tool="graft_watch",
-                        platform=prov.get("platform"),
-                        chip=prov.get("device"), n_devices=n_dev,
-                        topology=stamped["topology"],
-                        config=args.path, lint_clean=None, git_rev=rev)
-                except Exception as e:                     # noqa: BLE001
-                    print(f"[graft_watch] ledger emission failed: {e}",
-                          file=sys.stderr)
 
     if args.json:
         print(json.dumps(doc, indent=1))

@@ -11,12 +11,9 @@ tiny constant dot — so bytes moved is the static proxy for device time.
 
 This tool evaluates staged-vs-fused bytes over a grid of bucket sizes ×
 pack widths, checks the ≥2× wire-cut target, optionally graft-lints the
-shipping fused-pipelined registry config, writes ``WIRE_LAST.json``, and
-appends a ``claim_class="projected"`` ledger record so
-``tools/graft_gate.py`` can audit any README claim that cites the
-number. The record carries a ``deferred_capture`` note naming the
-measurement that will supersede it — the ledger idiom for "projected
-today, measured later" (same as the multichip wire model rows).
+shipping fused-pipelined registry config and writes ``WIRE_LAST.json``
+with ``claim_class="projected"`` and a ``deferred_capture`` note naming
+the measurement that will supersede it.
 
 Exit status: 0 when every grid point meets the target, 1 otherwise.
 
@@ -38,8 +35,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_OUT = os.path.join(ROOT, "WIRE_LAST.json")
+DEFAULT_OUT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "WIRE_LAST.json")
 
 # The ROADMAP item-2 bar: the fused hop must cut wire-stage HBM traffic
 # at least 2x vs the staged spelling at every shipped pack width.
@@ -59,9 +57,8 @@ WIRE_CONFIG = "qsgd2-ring-packed-pipelined"
 
 DEFERRED_CAPTURE = (
     "hop_hbm_bytes is a static byte model, not a device measurement; "
-    "supersede this record with a measured stage-attribution capture "
-    "(tools/tpu_profile.py stage view of grace/bucket/*/wire on >=2 "
-    "chips) under the same id once the ROADMAP item-1 campaign runs.")
+    "supersede this record with a measured stage attribution of "
+    "grace/bucket/*/wire on >=2 chips (a benchmark cell's traced run).")
 
 
 def _now() -> str:
@@ -131,16 +128,12 @@ def main(argv=None) -> int:
     lint_clean, n_findings = ((None, None) if args.no_lint
                               else lint_wire_config())
 
-    try:
-        from grace_tpu.evidence.ledger import git_head_rev
-        rev = git_head_rev()
-    except Exception:                                      # noqa: BLE001
-        rev = None
+    from grace_tpu.utils.logging import git_commit
 
     doc = {
         "tool": "graft_wire",
         "captured_at": _now(),
-        "git_rev": rev,
+        "git_rev": git_commit(),
         "claim_class": "projected",
         "model": "grace_tpu.ops.pallas_wire.hop_hbm_bytes",
         "target_ratio": TARGET_RATIO,
@@ -173,21 +166,6 @@ def main(argv=None) -> int:
         else:
             print(f"[graft_wire] wire projection -> {args.out}",
                   file=sys.stderr)
-            if os.path.dirname(os.path.abspath(args.out)) == ROOT:
-                try:
-                    from grace_tpu.evidence.ledger import record_artifact
-                    record_artifact(
-                        args.out, id="wire-hop-projection",
-                        metric="wire_hop_hbm_bytes_ratio",
-                        value=min_ratio, claim_class="projected",
-                        tool="graft_wire", platform="static-model",
-                        chip=None, n_devices=None, topology=None,
-                        config=WIRE_CONFIG, lint_clean=lint_clean,
-                        git_rev=rev, unit="staged_over_fused",
-                        deferred_capture=DEFERRED_CAPTURE)
-                except Exception as e:                     # noqa: BLE001
-                    print(f"[graft_wire] ledger emission failed: {e}",
-                          file=sys.stderr)
 
     if args.json:
         print(json.dumps(doc, indent=1, sort_keys=True))

@@ -6,12 +6,12 @@ Renders what ``grace_tpu.profiling.trace_analysis`` extracts from a
 profile directory): the per-stage device-time table over the canonical
 ``grace/...`` vocabulary (summing exactly to total device time), the
 compute-vs-collective split, the **overlap fraction** (collective time
-hidden under compute — the number the bench projection model assumes is
-zero), and step-time percentiles from the trace's step markers.
+hidden under compute — the number the tuner's projection model assumes
+is zero), and step-time percentiles from the trace's step markers.
 
 Optionally gates against a stored baseline with a tolerance band (the
 graft-lint idiom: measured perf facts become CI-checkable), and writes the
-``PROF_LAST.json`` evidence document ``tools/evidence_summary.py`` renders.
+``PROF_LAST.json`` evidence document.
 
 Pure host-side: runs on a CPU-only box with no devices against a saved
 trace (pinned by tests/test_profiling.py on the canned fixture
@@ -140,8 +140,7 @@ def main(argv=None) -> int:
                     help="emit a JSON document instead of text")
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help="evidence document path ('' disables; default "
-                         "PROF_LAST.json at the repo root, consumed by "
-                         "tools/evidence_summary.py)")
+                         "PROF_LAST.json at the repo root)")
     args = ap.parse_args(argv)
 
     # The analyzer is pure host-side (stdlib + numpy over a saved trace),
