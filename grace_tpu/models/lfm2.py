@@ -12,7 +12,15 @@ Equations (``u = RMSNorm(x)`` with a learned weight; no bias anywhere):
   ``Op = W_out (C * c)``.
 * ``full_attention`` operator: grouped-query attention, RMSNorm over each
   query and key head, rotary positions over the whole head (rotate-half),
-  causal softmax of ``q k^T / sqrt(head_dim)``.
+  causal softmax of ``q k^T / sqrt(head_dim)`` in float32. Two spellings
+  of the scores, chosen from what the trace can see (``ops.
+  pallas_attention.engages``: the platform, the sequence length, the head
+  size and the dtype; no field of ``Config`` and no argument): on a TPU, at
+  shapes the fused kernel takes, a whole sequence goes through it, tile by
+  tile in VMEM with an online softmax and the kernel's own backward pass,
+  and no ``(heads, queries, keys)`` tensor reaches HBM; everywhere else
+  ``attn_q_block`` queries are scored at a time against the keys up to
+  the block's end, each block recomputed in the backward pass.
 * dense feed-forward: ``W_2 (silu(W_1 h) * W_3 h)``.
 * expert feed-forward: ``s = sigmoid(W_r h)``; the ``num_experts_per_tok``
   experts are the top of ``s + b`` (``b`` the expert bias, model state, not
@@ -60,6 +68,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from grace_tpu.models import layers as L
+from grace_tpu.ops import pallas_attention
 from grace_tpu.telemetry.scopes import (STAGE_ATTENTION, STAGE_DENSE_FFN,
                                         STAGE_LM_HEAD, STAGE_MOE_COMBINE,
                                         STAGE_MOE_DISPATCH,
@@ -93,7 +102,7 @@ class Config:
     routed_scaling_factor: float = 1.0
     # how the work is walked, not what is computed
     seq_block: int = 1            # sequences recomputed together
-    attn_q_block: int = 1024      # queries scored together
+    attn_q_block: int = 1024      # queries scored together (plain path)
     moe_row_block: int = 0        # rows of one grouped product; 0: by load
 
     def __post_init__(self):
@@ -233,8 +242,25 @@ def _scores_block(q, k, v, start):
     return jnp.einsum("nhgqk,nkhd->nqhgd", a, v)
 
 
+def _scores_in_blocks(q, k, v, q_block: int):
+    """Causal attention ``q_block`` queries at a time, each block's
+    ``(heads, queries, keys)`` scores made, normalised and multiplied into
+    the values by XLA: the plain spelling, and what the kernel is tested
+    against. ``q``: ``(n, T, Hq, D)``; ``k``, ``v``: ``(n, T, Hkv, D)``."""
+    n, t, hq, hd = q.shape
+    hkv = k.shape[2]
+    q = q.reshape(n, t, hkv, hq // hkv, hd)
+    block = jax.checkpoint(_scores_block, static_argnums=(3,))
+    out = [block(q[:, s:s + q_block], k[:, :s + q_block], v[:, :s + q_block],
+                 s) for s in range(0, t, q_block)]
+    return jnp.concatenate(out, axis=1).reshape(n, t, hq, hd)
+
+
 def attention(p, u, cfg: Config):
-    """Grouped-query causal self-attention of normalised ``u``."""
+    """Grouped-query causal self-attention of normalised ``u``. On a TPU, a
+    sequence of whole tiles at a head size the fused kernel takes goes
+    through it (``ops.pallas_attention``: no score tensor in HBM, its own
+    backward pass); everything else through :func:`_scores_in_blocks`."""
     n, t, _ = u.shape
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
@@ -243,13 +269,11 @@ def attention(p, u, cfg: Config):
     v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
     q = L.rotary(L.rms_apply(p["q_norm"], q, cfg.norm_eps), cfg.rope_theta)
     k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta)
-    q = q.reshape(n, t, hkv, hq // hkv, hd)
-    block = jax.checkpoint(_scores_block, static_argnums=(3,))
-    out = [block(q[:, s:s + cfg.attn_q_block],
-                 k[:, :s + cfg.attn_q_block], v[:, :s + cfg.attn_q_block], s)
-           for s in range(0, t, cfg.attn_q_block)]
-    return _dot(jnp.concatenate(out, axis=1).reshape(n, t, hq * hd),
-                p["o_proj"])
+    if pallas_attention.engages(t, hd, q.dtype):
+        out = pallas_attention.causal_gqa(q, k, v)
+    else:
+        out = _scores_in_blocks(q, k, v, cfg.attn_q_block)
+    return _dot(out.reshape(n, t, hq * hd), p["o_proj"])
 
 
 def dense_ffn(p, u):

@@ -1,0 +1,108 @@
+"""Pallas TPU kernel: causal grouped-query attention with an online softmax.
+
+``softmax(q k^T / sqrt(D) + causal mask) v`` of whole sequences without the
+``(heads, queries, keys)`` tensor ever reaching HBM: a tile of scores is
+made in VMEM in float32, masked, exponentiated against a running maximum,
+multiplied into the values and dropped. What reaches HBM is the output and
+one float32 log-sum-exp a query and head; the backward pass is the
+kernel's own (``custom_vjp``): it recomputes each tile from ``q``, ``k``,
+``v``, the output and the log-sum-exp. Tiles wholly above the diagonal are
+skipped, in the grid and in the copies from HBM.
+
+The kernel is JAX's ``splash_attention`` (``jax.experimental.pallas.ops.
+tpu``), wrapped: its multi-head form with fewer key/value heads than query
+heads, a causal mask it evaluates from positions inside the tile (no mask
+tensor), the fused backward kernel (``dk``, ``dv`` and ``dq`` from one
+recomputation of a tile). Scores, running maximum, running sum and output
+accumulator are float32; the forward multiplies float32 probabilities into
+the values, the backward casts the probabilities and the score gradients
+to the gradient's dtype for its products. The tile sizes below were chosen
+by chip runs at ``(1, 4096, 32 | 8, 64)`` bfloat16 on a TPU v5e, where
+JAX's other kernel, ``flash_attention``, read 1.6 times this one's time
+(PERF.md §6, PR 31).
+
+``engages`` is the ONE rule for who takes the kernel: a TPU, a sequence of
+whole tiles, a head size and dtype the kernel takes. Callers ask it and
+keep their plain spelling for everything else (``models/lfm2.py``).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+# Tiles of the forward kernel and of the fused backward kernel alike (no
+# pair of tile sets read better than one for both): queries, keys copied
+# from HBM, keys multiplied at once.
+BLOCK_Q = 1024
+BLOCK_KV = 1024
+BLOCK_KV_COMPUTE = 512
+# A sequence is whole tiles of both kinds.
+TILE = math.lcm(BLOCK_Q, BLOCK_KV)
+HEAD_DIMS = (64,)
+DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+
+
+def _takes(seq_len: int, head_dim: int, dtype) -> bool:
+    return (seq_len > 0 and seq_len % TILE == 0 and head_dim in HEAD_DIMS
+            and jnp.dtype(dtype) in DTYPES)
+
+
+def engages(seq_len: int, head_dim: int, dtype, platform: str | None = None
+            ) -> bool:
+    """Whether :func:`causal_gqa` is the path for such a sequence on
+    ``platform`` (default: the process's backend; a compile for a described
+    chip from a CPU process names it)."""
+    platform = jax.default_backend() if platform is None else platform
+    return platform == "tpu" and _takes(seq_len, head_dim, dtype)
+
+
+@functools.lru_cache(maxsize=8)
+def _kernel(seq_len: int, q_heads: int, interpret: bool):
+    # here, so that asking `engages` costs no one Pallas's import (1 s)
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    blocks = splash.BlockSizes(
+        block_q=BLOCK_Q, block_kv=BLOCK_KV,
+        block_kv_compute=BLOCK_KV_COMPUTE, block_q_dkv=BLOCK_Q,
+        block_kv_dkv=BLOCK_KV, block_kv_dkv_compute=BLOCK_KV_COMPUTE,
+        use_fused_bwd_kernel=True)
+    mask = splash.MultiHeadMask(
+        [splash.CausalMask((seq_len, seq_len))] * q_heads)
+    # the mask's block tables are numpy's work, made into device constants
+    # here and not inside whatever trace asked first
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret)
+
+
+def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,
+               interpret: bool = False) -> jax.Array:
+    """Causal attention of ``q`` ``(n, T, Hq, D)`` over ``k``, ``v``
+    ``(n, T, Hkv, D)``, query head ``h`` reading key/value head
+    ``h // (Hq // Hkv)``: ``(n, T, Hq, D)`` in ``q``'s dtype. ``T`` is a
+    multiple of :data:`TILE` and ``D`` one of :data:`HEAD_DIMS` (see
+    :func:`engages`); ``interpret`` runs the kernel in Pallas's interpreter,
+    for tests without the chip."""
+    n, t, hq, d = q.shape
+    if k.shape != v.shape or k.shape[:2] != (n, t) or k.shape[3] != d:
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape} are not "
+                         "(n, T, Hq, D), (n, T, Hkv, D), (n, T, Hkv, D)")
+    if hq % k.shape[2]:
+        raise ValueError("query heads must divide over key/value heads")
+    if not _takes(t, d, q.dtype):
+        raise ValueError(
+            f"the kernel takes sequences of whole tiles of {TILE}, head "
+            f"sizes {HEAD_DIMS}, bfloat16 or float32; got T={t}, D={d}, "
+            f"{q.dtype}")
+    kernel = _kernel(t, hq, interpret)
+    # the kernel leaves the scale to its caller; 1/sqrt(64) is a power of
+    # two, so scaling q first rounds nothing
+    scale = jnp.asarray(1.0 / math.sqrt(d), q.dtype)
+    heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+    out = jax.vmap(kernel)(heads_first(q * scale), heads_first(k),
+                           heads_first(v))
+    return heads_first(out)

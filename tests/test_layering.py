@@ -47,7 +47,8 @@ ALLOWED = {
     "utils": {"core"},
     "checkpoint": set(),
     "compressors": {"core", "ops", "telemetry"},
-    "models": {"telemetry"},
+    # the fused attention kernel is an op the LFM2 model calls
+    "models": {"telemetry", "ops"},
     "data": {"parallel"},
     "comm": {"core", "memories", "telemetry", "utils"},
     # the optax transform: compensate, compress, exchange, decompress

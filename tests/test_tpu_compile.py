@@ -19,6 +19,7 @@ only the worker that runs this file may load the library. A compile that
 passes here is not a chip run and is never reported as one.
 """
 
+import functools
 import json
 import os
 import re
@@ -36,7 +37,7 @@ from grace_tpu import grace_from_params
 from grace_tpu.compressors.topk import static_k
 from grace_tpu.memories import ResidualMemory
 from grace_tpu.models import lfm2, resnet
-from grace_tpu.ops import sparse
+from grace_tpu.ops import pallas_attention, sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
                                         quantize_stochastic, sign_pack)
 from grace_tpu.ops.pallas_topk import (chunk_aggregate_dense,
@@ -374,11 +375,76 @@ def test_topk_chunk_leaf_has_no_relayout_loop(one_chip, monkeypatch, shape,
     assert ("grace/decompress/row_slices" in text) == sliced
 
 
-def _lfm2_shapes():
+# ---------------------------------------------------------------------------
+# the fused attention of the LFM2 step (PR 31)
+# ---------------------------------------------------------------------------
+
+# a (heads, 1,024 queries, keys) float32 tensor: a block of scores in HBM
+SCORE_BLOCK = re.compile(r"f32\[(?:1,)?(?:32|8,4),1024,(?:1024|2048|3072|4096)\]")
+
+
+def _attention_part_text(one_chip):
+    """The attention operator of the benchmark's LFM2 configuration on one
+    sequence (4,096 x 2,048, bfloat16, 32/8 heads of 64), as the step runs
+    it: recomputed from its input, forward and gradient."""
+    cfg = _lfm2_config()
+    layer = cfg.layer_types.index("full_attention")
+    part = lfm2._operator_part("full_attention", cfg)
+
+    def loss(p, x):
+        y = lfm2._over_sequences(part, p, x, cfg.seq_block)
+        return jnp.sum(y.astype(jnp.float32))
+
+    p = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        _lfm2_shapes()["layers"][layer])
+    x = jax.ShapeDtypeStruct((1, 4096, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim) == (32, 8, 64)
+    return compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
+
+
+def test_lfm2_attention_compiles_to_the_fused_kernel(one_chip, monkeypatch):
+    """With ``engages`` answering as on the chip (its ``platform`` argument:
+    this process's backend is the CPU), the part holds the kernel three
+    times — forward, the recomputation, the fused backward — each under
+    ``grace/attention`` (the ``op_name`` stands on a later line of the
+    instruction: JAX prints the kernel's metadata with line breaks), and no
+    block of float32 scores is left in the text."""
+    monkeypatch.setattr(
+        pallas_attention, "engages",
+        functools.partial(pallas_attention.engages, platform="tpu"))
+    text = _attention_part_text(one_chip)
+    op_names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
+        re.S)
+    assert len(op_names) == text.count('custom_call_target="tpu_custom_call"')
+    assert all("grace/attention" in name for name in op_names), op_names
+    kernels = sorted(name.split("/")[-2] for name in op_names)
+    assert kernels == ["splash_mha_dkv_no_residuals",
+                       "splash_mha_fwd_residuals",
+                       "splash_mha_fwd_residuals"]
+    assert not SCORE_BLOCK.search(text)
+
+
+def test_lfm2_attention_still_compiles_without_the_kernel(one_chip):
+    """The fallback: where ``engages`` says no (here: a CPU process), the
+    same part compiles for the chip from the plain spelling, score blocks
+    and all."""
+    text = _attention_part_text(one_chip)
+    assert "tpu_custom_call" not in text
+    assert SCORE_BLOCK.search(text)
+
+
+def _lfm2_config():
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "lfm2-24b-a2b-ep8.json")) as f:
-        sizes = json.load(f)
-    cfg = lfm2_moe.model_config(sizes)
+        return lfm2_moe.model_config(json.load(f))
+
+
+def _lfm2_shapes():
+    cfg = _lfm2_config()
     return jax.eval_shape(lambda k: lfm2.init(k, cfg)[0], jax.random.key(0))
 
 
