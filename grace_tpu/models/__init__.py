@@ -15,12 +15,18 @@ checkpoints with orbax alongside them.
                       holds a share, next-token loss; ``(params, model_state,
                       ids) -> (loss, model_state)`` (the benchmark's
                       ``lfm2-24b-a2b-ep8`` configuration)
+* ``deepseek_v3``   — DeepSeek-V3-shaped causal decoder: multi-head latent
+                      attention (one latent and one shared rotary key for
+                      all heads), shared experts beside routed ones of which
+                      a chip holds a share (the expert layer, the head and
+                      the loss are ``lfm2``'s); the benchmark's
+                      ``kanana-2-30b-a3b-ep16`` configuration
 * ``vgg``           — VGG-11/13/16/19 (the communication-bound classic of the
                       reference's synthetic-benchmark model list)
 """
 
-from grace_tpu.models import (layers, lenet, lfm2, resnet, resnet_cifar,
-                              transformer, vgg)
+from grace_tpu.models import (deepseek_v3, layers, lenet, lfm2, resnet,
+                              resnet_cifar, transformer, vgg)
 
-__all__ = ["layers", "lenet", "lfm2", "resnet", "resnet_cifar", "transformer",
-           "vgg"]
+__all__ = ["deepseek_v3", "layers", "lenet", "lfm2", "resnet", "resnet_cifar",
+           "transformer", "vgg"]

@@ -20,6 +20,7 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 Params = dict
@@ -158,6 +159,28 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def rotary_pairs(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over neighbouring pairs (``rope_interleave``):
+    entries ``2i`` and ``2i + 1`` of the last axis turn by the angle
+    :func:`rotary` gives entries ``i`` and ``i + d/2``. Same layout of
+    ``x``. An entry's partner (``-x[2i + 1]`` for ``2i``, ``x[2i]`` for ``2i
+    + 1``) is reached by a product with a signed permutation matrix, which
+    is exact in any dtype (one term a sum) and shuffles no lanes: two
+    shifts along the 64-wide last axis and a select read 20 ms a layer more
+    at 8 x 4,096 tokens and 32 heads on a TPU v5e (PERF.md section 6,
+    PR 32)."""
+    t, d = x.shape[-3], x.shape[-1]
+    freq = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None, None] * freq
+    swap = np.zeros((d, d), np.float32)
+    swap[np.arange(1, d, 2), np.arange(0, d, 2)] = -1.0
+    swap[np.arange(0, d, 2), np.arange(1, d, 2)] = 1.0
+    partner = jnp.matmul(x, jnp.asarray(swap, x.dtype),
+                         precision=lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * jnp.cos(ang)
+            + partner.astype(jnp.float32) * jnp.sin(ang)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

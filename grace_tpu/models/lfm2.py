@@ -100,6 +100,7 @@ class Config:
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
     routed_scaling_factor: float = 1.0
+    route_eps: float = 1e-6       # added to the sum the gates are divided by
     # how the work is walked, not what is computed
     seq_block: int = 1            # sequences recomputed together
     attn_q_block: int = 1024      # queries scored together (plain path)
@@ -178,15 +179,18 @@ def init(key: jax.Array, cfg: Config) -> Tuple[L.Params, L.ModelState]:
     return params, init_state(cfg)
 
 
-def init_state(cfg: Config) -> L.ModelState:
-    def moe():
-        return {"expert_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
-                "drawn": jnp.zeros((cfg.num_experts,), jnp.float32),
-                "held": jnp.zeros((), jnp.float32),
-                "dropped": jnp.zeros((), jnp.float32)}
+def expert_layer_state(num_experts: int) -> L.ModelState:
+    """An expert layer's model state: the router's bias and the counters
+    of the last step, all zero."""
+    return {"expert_bias": jnp.zeros((num_experts,), jnp.float32),
+            "drawn": jnp.zeros((num_experts,), jnp.float32),
+            "held": jnp.zeros((), jnp.float32),
+            "dropped": jnp.zeros((), jnp.float32)}
 
-    return {"layers": [moe() if cfg.is_moe(i) else {}
-                       for i in range(len(cfg.layer_types))]}
+
+def init_state(cfg: Config) -> L.ModelState:
+    return {"layers": [expert_layer_state(cfg.num_experts) if cfg.is_moe(i)
+                       else {} for i in range(len(cfg.layer_types))]}
 
 
 # ---------------------------------------------------------------------------
@@ -246,14 +250,15 @@ def _scores_in_blocks(q, k, v, q_block: int):
     """Causal attention ``q_block`` queries at a time, each block's
     ``(heads, queries, keys)`` scores made, normalised and multiplied into
     the values by XLA: the plain spelling, and what the kernel is tested
-    against. ``q``: ``(n, T, Hq, D)``; ``k``, ``v``: ``(n, T, Hkv, D)``."""
+    against. ``q``: ``(n, T, Hq, D)``; ``k``: ``(n, T, Hkv, D)``; ``v``:
+    ``(n, T, Hkv, Dv)``, and so the result's heads."""
     n, t, hq, hd = q.shape
     hkv = k.shape[2]
     q = q.reshape(n, t, hkv, hq // hkv, hd)
     block = jax.checkpoint(_scores_block, static_argnums=(3,))
     out = [block(q[:, s:s + q_block], k[:, :s + q_block], v[:, :s + q_block],
                  s) for s in range(0, t, q_block)]
-    return jnp.concatenate(out, axis=1).reshape(n, t, hq, hd)
+    return jnp.concatenate(out, axis=1).reshape(n, t, hq, v.shape[-1])
 
 
 def attention(p, u, cfg: Config):
@@ -269,8 +274,11 @@ def attention(p, u, cfg: Config):
     v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
     q = L.rotary(L.rms_apply(p["q_norm"], q, cfg.norm_eps), cfg.rope_theta)
     k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta)
-    if pallas_attention.engages(t, hd, q.dtype):
-        out = pallas_attention.causal_gqa(q, k, v)
+    if pallas_attention.engages(t, hd, hd, q.dtype):
+        # the kernel applies no scale; 1/sqrt(64) is a power of two, so q
+        # times it is exact in q's dtype
+        scale = jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
+        out = pallas_attention.causal_gqa(q * scale, k, v)
     else:
         out = _scores_in_blocks(q, k, v, cfg.attn_q_block)
     return _dot(out.reshape(n, t, hq * hd), p["o_proj"])
@@ -293,7 +301,7 @@ def route(p, bias, x, cfg: Config):
     # numbers runs one at a time on the chip)
     chosen = experts[..., None] == jnp.arange(cfg.num_experts)
     g = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
-    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6)
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + cfg.route_eps)
     return experts, g * cfg.routed_scaling_factor
 
 
@@ -540,9 +548,15 @@ def next_token_loss(params, model_state, ids, cfg: Config,
     against token ``t + 1`` (a sequence's last position has no target):
     ``(loss, new_model_state)``."""
     x, new_state = hidden_states(params, model_state, ids, cfg, dtype)
+    return loss_of_hidden_states(params, x, ids, cfg), new_state
+
+
+def loss_of_hidden_states(params, x, ids, cfg):
+    """The next-token loss of the last layer's output ``x`` ``(n, T, d)``:
+    final norm, head and cross-entropy, ``seq_block`` sequences at a time."""
     n, t = ids.shape
     sums = _over_sequences(
         _head_part(cfg),
         {"final_norm": params["final_norm"], "head": params["head"]},
         (x[:, :-1], ids[:, 1:]), cfg.seq_block)
-    return jnp.sum(sums) / (n * (t - 1)), new_state
+    return jnp.sum(sums) / (n * (t - 1))

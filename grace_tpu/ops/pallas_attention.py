@@ -1,6 +1,6 @@
 """Pallas TPU kernel: causal grouped-query attention with an online softmax.
 
-``softmax(q k^T / sqrt(D) + causal mask) v`` of whole sequences without the
+``softmax(q k^T + causal mask) v`` of whole sequences without the
 ``(heads, queries, keys)`` tensor ever reaching HBM: a tile of scores is
 made in VMEM in float32, masked, exponentiated against a running maximum,
 multiplied into the values and dropped. What reaches HBM is the output and
@@ -11,19 +11,35 @@ skipped, in the grid and in the copies from HBM.
 
 The kernel is JAX's ``splash_attention`` (``jax.experimental.pallas.ops.
 tpu``), wrapped: its multi-head form with fewer key/value heads than query
-heads, a causal mask it evaluates from positions inside the tile (no mask
-tensor), the fused backward kernel (``dk``, ``dv`` and ``dq`` from one
-recomputation of a tile). Scores, running maximum, running sum and output
-accumulator are float32; the forward multiplies float32 probabilities into
-the values, the backward casts the probabilities and the score gradients
-to the gradient's dtype for its products. The tile sizes below were chosen
-by chip runs at ``(1, 4096, 32 | 8, 64)`` bfloat16 on a TPU v5e, where
-JAX's other kernel, ``flash_attention``, read 1.6 times this one's time
-(PERF.md §6, PR 31).
+heads, a head size for queries and keys and one for values, a causal mask
+it evaluates from positions inside the tile (no mask tensor), the fused
+backward kernel (``dk``, ``dv`` and ``dq`` from one recomputation of a
+tile). Scores, running maximum, running sum and output accumulator are
+float32; the forward multiplies float32 probabilities into the values, the
+backward casts the probabilities and the score gradients to the
+gradient's dtype for its products.
+
+Two pairs of head sizes are taken (:data:`HEAD_DIMS`, queries and keys |
+values): ``(64, 64)``, grouped-query attention as ``models/lfm2.py`` has
+it, and ``(192, 128)``, latent attention as ``models/deepseek_v3.py`` has
+it (a 128-wide part without positions beside a 64-wide rotary part). The
+tile sizes were chosen by chip runs on a TPU v5e at ``(1, 4096, 32 | 8,
+64)`` bfloat16, where JAX's other kernel, ``flash_attention``, read 1.6
+times this one's time (PERF.md §6, PR 31), and read again at ``(1, 4096,
+32, 192 | 128)`` (PERF.md §6, PR 32). Mosaic takes the 192 lanes as they
+are (a tile and a half of 128): no zero padding to 256.
+
+**The scale.** The kernel has none, and neither has this wrapper: what it
+is given as ``q`` is what it multiplies into the keys, so ``softmax(q k^T +
+causal mask) v`` is what comes back. Each caller scales ``q`` where that
+rounds nothing its plain spelling does not round: ``models/lfm2.py``
+multiplies ``q`` by ``1 / sqrt(64)`` in ``q``'s dtype (a power of two:
+exact), ``models/deepseek_v3.py`` folds ``1 / sqrt(192)`` into the query
+projection's weights in float32 as it casts them, so ``q`` is rounded once.
 
 ``engages`` is the ONE rule for who takes the kernel: a TPU, a sequence of
-whole tiles, a head size and dtype the kernel takes. Callers ask it and
-keep their plain spelling for everything else (``models/lfm2.py``).
+whole tiles, a pair of head sizes and a dtype the kernel takes. Callers
+ask it and keep their plain spelling for everything else.
 """
 
 from __future__ import annotations
@@ -42,22 +58,25 @@ BLOCK_KV = 1024
 BLOCK_KV_COMPUTE = 512
 # A sequence is whole tiles of both kinds.
 TILE = math.lcm(BLOCK_Q, BLOCK_KV)
-HEAD_DIMS = (64,)
+# (queries and keys, values)
+HEAD_DIMS = ((64, 64), (192, 128))
 DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
 
 
-def _takes(seq_len: int, head_dim: int, dtype) -> bool:
-    return (seq_len > 0 and seq_len % TILE == 0 and head_dim in HEAD_DIMS
+def _takes(seq_len: int, head_dim_qk: int, head_dim_v: int, dtype) -> bool:
+    return (seq_len > 0 and seq_len % TILE == 0
+            and (head_dim_qk, head_dim_v) in HEAD_DIMS
             and jnp.dtype(dtype) in DTYPES)
 
 
-def engages(seq_len: int, head_dim: int, dtype, platform: str | None = None
-            ) -> bool:
+def engages(seq_len: int, head_dim_qk: int, head_dim_v: int, dtype,
+            platform: str | None = None) -> bool:
     """Whether :func:`causal_gqa` is the path for such a sequence on
     ``platform`` (default: the process's backend; a compile for a described
     chip from a CPU process names it)."""
     platform = jax.default_backend() if platform is None else platform
-    return platform == "tpu" and _takes(seq_len, head_dim, dtype)
+    return platform == "tpu" and _takes(seq_len, head_dim_qk, head_dim_v,
+                                        dtype)
 
 
 @functools.lru_cache(maxsize=8)
@@ -81,28 +100,27 @@ def _kernel(seq_len: int, q_heads: int, interpret: bool):
 
 def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,
                interpret: bool = False) -> jax.Array:
-    """Causal attention of ``q`` ``(n, T, Hq, D)`` over ``k``, ``v``
-    ``(n, T, Hkv, D)``, query head ``h`` reading key/value head
-    ``h // (Hq // Hkv)``: ``(n, T, Hq, D)`` in ``q``'s dtype. ``T`` is a
-    multiple of :data:`TILE` and ``D`` one of :data:`HEAD_DIMS` (see
-    :func:`engages`); ``interpret`` runs the kernel in Pallas's interpreter,
-    for tests without the chip."""
+    """Causal attention of ``q`` ``(n, T, Hq, D)`` over ``k`` ``(n, T, Hkv,
+    D)`` and ``v`` ``(n, T, Hkv, Dv)``, query head ``h`` reading key/value
+    head ``h // (Hq // Hkv)``: ``(n, T, Hq, Dv)`` in ``q``'s dtype. ``T`` is
+    a multiple of :data:`TILE` and ``(D, Dv)`` one of :data:`HEAD_DIMS`
+    (see :func:`engages`). No scale is applied: the caller's ``q`` carries
+    it (the module's docstring). ``interpret`` runs the kernel in Pallas's
+    interpreter, for tests without the chip."""
     n, t, hq, d = q.shape
-    if k.shape != v.shape or k.shape[:2] != (n, t) or k.shape[3] != d:
+    dv = v.shape[3]
+    if (k.shape[:3] != v.shape[:3] or k.shape[:2] != (n, t)
+            or k.shape[3] != d):
         raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape} are not "
-                         "(n, T, Hq, D), (n, T, Hkv, D), (n, T, Hkv, D)")
+                         "(n, T, Hq, D), (n, T, Hkv, D), (n, T, Hkv, Dv)")
     if hq % k.shape[2]:
         raise ValueError("query heads must divide over key/value heads")
-    if not _takes(t, d, q.dtype):
+    if not _takes(t, d, dv, q.dtype):
         raise ValueError(
             f"the kernel takes sequences of whole tiles of {TILE}, head "
-            f"sizes {HEAD_DIMS}, bfloat16 or float32; got T={t}, D={d}, "
-            f"{q.dtype}")
+            f"sizes (queries and keys, values) {HEAD_DIMS}, bfloat16 or "
+            f"float32; got T={t}, D={d}, Dv={dv}, {q.dtype}")
     kernel = _kernel(t, hq, interpret)
-    # the kernel leaves the scale to its caller; 1/sqrt(64) is a power of
-    # two, so scaling q first rounds nothing
-    scale = jnp.asarray(1.0 / math.sqrt(d), q.dtype)
     heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
-    out = jax.vmap(kernel)(heads_first(q * scale), heads_first(k),
-                           heads_first(v))
+    out = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
     return heads_first(out)

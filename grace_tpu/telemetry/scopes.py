@@ -33,7 +33,7 @@ __all__ = ["trace_stage", "match_stage", "ALL_STAGES",
            "STAGE_PIPELINE", "STAGE_ATTENTION", "STAGE_SHORT_CONV",
            "STAGE_DENSE_FFN", "STAGE_MOE_ROUTER", "STAGE_MOE_DISPATCH",
            "STAGE_MOE_EXPERTS", "STAGE_MOE_COMBINE", "STAGE_LM_HEAD",
-           "MODEL_STAGES"]
+           "STAGE_MLA_LATENT", "STAGE_SHARED_EXPERT", "MODEL_STAGES"]
 
 # Canonical stage names — one vocabulary for the profiler, the report tool,
 # and the docs. Keep in sync with README "Observability".
@@ -80,7 +80,8 @@ STAGE_ADAPT = "grace/adapt"
 # nest inside it; match_stage's rightmost rule still attributes their ops
 # to ring_hop/exchange as before.
 STAGE_PIPELINE = "grace/pipeline"
-# The parts of a model's forward and backward pass (models/lfm2.py): they
+# The parts of a model's forward and backward pass (models/lfm2.py,
+# models/deepseek_v3.py): they
 # nest inside STAGE_FWD_BWD, and the rightmost rule attributes a part's
 # forward, recomputed and backward operations to it, so what is left
 # under "grace/forward_backward" is what no part names (embedding,
@@ -98,9 +99,19 @@ STAGE_MOE_DISPATCH = "grace/moe_dispatch"      # sort, gather
 STAGE_MOE_EXPERTS = "grace/moe_experts"        # masks, activation, gates
 STAGE_MOE_COMBINE = "grace/moe_combine"
 STAGE_LM_HEAD = "grace/lm_head"                # final norm, head, loss
+# Latent attention's products around the scores (models/deepseek_v3.py):
+# the query projection, the projection down to the latent and the shared
+# rotary key, the latent's norm, the projection up to every head's keys
+# and values, the rotation, the shared key's broadcast and its gradient's
+# sum over the heads, the output projection. The scores themselves stand
+# under STAGE_ATTENTION, nested inside.
+STAGE_MLA_LATENT = "grace/mla_latent"
+# The expert every token passes and every chip of a layer computes whole.
+STAGE_SHARED_EXPERT = "grace/shared_expert"
 MODEL_STAGES = (STAGE_ATTENTION, STAGE_SHORT_CONV, STAGE_DENSE_FFN,
                 STAGE_MOE_ROUTER, STAGE_MOE_DISPATCH, STAGE_MOE_EXPERTS,
-                STAGE_MOE_COMBINE, STAGE_LM_HEAD)
+                STAGE_MOE_COMBINE, STAGE_LM_HEAD, STAGE_MLA_LATENT,
+                STAGE_SHARED_EXPERT)
 
 # The canonical stage vocabulary, longest-prefix-matchable: the profiler,
 # tools/telemetry_report.py, and the static auditor's finding attribution

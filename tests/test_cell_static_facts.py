@@ -1,10 +1,10 @@
-"""The static decisions the five compiled steps depend on, at the cells' own
+"""The static decisions the six compiled steps depend on, at the cells' own
 shapes.
 
 Every route the transform takes for a leaf follows from static facts: the
 cell's ``grace`` parameters, the leaf's shape, the world size. ``PERF.md``
 states them in prose ("no ResNet-50 leaf takes the row-slices route at W=1",
-"27 of LFM2's 50 leaves do"); here they are assertions, on parameter trees
+"27 of LFM2's 50 leaves do", "32 of kanana's 69"); here they are assertions, on parameter trees
 taken with ``jax.eval_shape`` from the benchmark's own builders at the sizes
 in ``benchmarks/configs/*.json`` (read, never edited; no weight is made).
 The expected values are written down, not computed by the code under test:
@@ -45,6 +45,9 @@ CELLS = {
                                "PowerSGDMemory", "Allreduce"),
     "lfm2-24b-a2b-topk1pct-w1": ("lfm2-24b-a2b-ep8", "TopKCompressor",
                                  "ResidualMemory", "Allgather"),
+    "kanana-2-30b-a3b-topk1pct-w1": ("kanana-2-30b-a3b-ep16",
+                                     "TopKCompressor", "ResidualMemory",
+                                     "Allgather"),
 }
 
 # configuration -> (leaves, parameters, leaves on the row-slices route under
@@ -53,6 +56,7 @@ CONFIGS = {
     "resnet50-imagenet": (161, 25_557_032, 0, 2_044_104),
     "lfm2-24b-a2b-ep8": (50, 486_062_208, 27, 38_884_848),
     "bert-base-squad": (150, 108_793_346, None, 3_369_912),
+    "kanana-2-30b-a3b-ep16": (69, 424_960_512, 32, 33_996_704),
 }
 
 # Top-k 1 % chunk, per distinct leaf shape:
@@ -107,6 +111,25 @@ TOPK_LEAVES = {
         ((8, 1536, 2048), 4, 25165824, 251658, 101, True),
         ((8, 2048, 1536), 8, 25165824, 251658, 101, True),
     ],
+    "kanana-2-30b-a3b-ep16": [
+        ((512,), 5, 512, 5, 103, False),            # the latent's norm
+        ((2048,), 11, 2048, 20, 103, False),
+        # W_kvb: exactly 2**22 elements, as LFM2's (2048, 2048)
+        ((512, 8192), 5, 4194304, 41943, 101, True),
+        # the shared expert's three, 3,177,157 elements in the view: under
+        # the constant
+        ((1536, 2048), 4, 3145728, 31457, 101, False),
+        ((2048, 1536), 8, 3145728, 31457, 101, False),
+        ((2048, 128), 4, 262144, 2621, 101, False),         # the router
+        ((2048, 576), 5, 1179648, 11796, 101, False),       # W_kva
+        ((2048, 6144), 7, 12582912, 125829, 101, True),     # W_q; dense w1, w3
+        ((6144, 2048), 1, 12582912, 125829, 101, True),
+        ((4096, 2048), 5, 8388608, 83886, 101, True),       # W_o
+        ((2048, 16032), 1, 32833536, 328335, 101, True),
+        ((16032, 2048), 1, 32833536, 328335, 101, True),
+        ((8, 768, 2048), 4, 12582912, 125829, 101, True),
+        ((8, 2048, 768), 8, 12582912, 125829, 101, True),
+    ],
 }
 
 # PowerSGD rank 4 on BERT-base, per distinct leaf shape: (shape, leaves of
@@ -116,6 +139,7 @@ TOPK_LEAVES = {
 # compressing cells is the same)
 CODEC_CELL = {"resnet50-imagenet": "resnet50-topk1pct-w1",
               "lfm2-24b-a2b-ep8": "lfm2-24b-a2b-topk1pct-w1",
+              "kanana-2-30b-a3b-ep16": "kanana-2-30b-a3b-topk1pct-w1",
               "bert-base-squad": "bert-base-powersgd4-w1"}
 
 POWERSGD_LEAVES = [

@@ -47,7 +47,9 @@ ALLOWED = {
     "utils": {"core"},
     "checkpoint": set(),
     "compressors": {"core", "ops", "telemetry"},
-    # the fused attention kernel is an op the LFM2 model calls
+    # the fused attention kernel is an op the two decoders call (lfm2,
+    # and deepseek_v3, which also imports what it shares from lfm2: the
+    # same unit)
     "models": {"telemetry", "ops"},
     "data": {"parallel"},
     "comm": {"core", "memories", "telemetry", "utils"},

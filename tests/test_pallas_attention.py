@@ -3,9 +3,11 @@ replaces on the chip (``models.lfm2._scores_block`` over the whole
 sequence), in Pallas's interpreter on the CPU: output and the gradients of
 ``q``, ``k`` and ``v``, over dtype and group size, at a sequence of two
 tiles so that a tile above the diagonal (skipped), on it (masked inside)
-and below it (whole) all occur. And who takes the kernel: ``engages``
-alone decides, from platform and shape, and ``lfm2.attention`` gives the
-plain path's bits wherever it says no.
+and below it (whole) all occur; at both pairs of head sizes the kernel
+takes, ``64 | 64`` (LFM2's grouped queries) and ``192 | 128`` (latent
+attention's queries and keys | values). And who takes the kernel:
+``engages`` alone decides, from platform and shape, and ``lfm2.attention``
+and ``deepseek_v3.mla`` give the plain path's bits wherever it says no.
 
 Whether Mosaic accepts the kernel at the benchmark's size is
 ``tests/test_tpu_compile.py``'s; what it does to the step is the chip's
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grace_tpu.models import deepseek_v3
 from grace_tpu.models import layers as L
 from grace_tpu.models import lfm2
 from grace_tpu.ops import pallas_attention
@@ -27,25 +30,31 @@ from grace_tpu.ops.pallas_attention import TILE, causal_gqa, engages
 
 T = 2 * TILE
 D = 64
+GQA, MLA = (64, 64), (192, 128)       # queries and keys | values
 HKV = 2
 
 
-def _inputs(group, dtype, t=T, key=0):
+def _inputs(group, dtype, t=T, key=0, dims=GQA):
     ks = jax.random.split(jax.random.key(key), 4)
 
-    def normal(k, heads):
-        return jax.random.normal(k, (1, t, heads, D), jnp.float32
+    def normal(k, heads, d):
+        return jax.random.normal(k, (1, t, heads, d), jnp.float32
                                  ).astype(dtype)
 
-    return (normal(ks[0], HKV * group), normal(ks[1], HKV),
-            normal(ks[2], HKV), normal(ks[3], HKV * group))
+    return (normal(ks[0], HKV * group, dims[0]), normal(ks[1], HKV, dims[0]),
+            normal(ks[2], HKV, dims[1]), normal(ks[3], HKV * group, dims[1]))
 
 
 def _plain(q, k, v):
     n, t, hq, d = q.shape
     hkv = k.shape[2]
     out = lfm2._scores_block(q.reshape(n, t, hkv, hq // hkv, d), k, v, 0)
-    return out.reshape(n, t, hq, d)
+    return out.reshape(n, t, hq, v.shape[-1])
+
+
+def _scaled(q):
+    """``q / sqrt(D)``, the scale the plain spelling puts on its scores."""
+    return (q.astype(jnp.float32) / np.sqrt(q.shape[-1])).astype(q.dtype)
 
 
 def _weighted(fn):
@@ -68,15 +77,21 @@ def _gap(a, b):
 TOLERANCE = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
-@pytest.mark.parametrize("group", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("dims, group", [(GQA, 1), (GQA, 4), (MLA, 1)],
+                         ids=["mha", "gqa4", "mla192-128"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-def test_kernel_agrees_with_the_plain_spelling(dtype, group):
-    q, k, v, w = _inputs(group, dtype)
-    kernel = _weighted(lambda q, k, v: causal_gqa(q, k, v, interpret=True))
+def test_kernel_agrees_with_the_plain_spelling(dtype, dims, group):
+    """The kernel applies no scale, so ``q`` is handed over scaled: through
+    float32, which at 192 (no power of two) is one rounding more than the
+    plain spelling's scaled scores in bfloat16, well inside that dtype's
+    tolerance."""
+    q, k, v, w = _inputs(group, dtype, dims=dims)
+    kernel = _weighted(lambda q, k, v: causal_gqa(_scaled(q), k, v,
+                                                  interpret=True))
     (_, out), grads = kernel(q, k, v, w)
     (_, want), want_grads = _weighted(_plain)(q, k, v, w)
-    assert out.dtype == dtype and out.shape == q.shape
+    assert out.dtype == dtype and out.shape == q.shape[:3] + (dims[1],)
     tol = TOLERANCE[dtype]
     assert _gap(out, want) < tol
     for name, got, ref in zip("qkv", grads, want_grads):
@@ -90,12 +105,13 @@ def test_bfloat16_kernel_is_no_further_from_float32_than_the_plain_path():
     bfloat16 inputs it is the closer of the two, not only within reach."""
     q, k, v, w = _inputs(4, jnp.bfloat16, key=3)
     exact = _plain(*(a.astype(jnp.float32) for a in (q, k, v)))
-    fused = causal_gqa(q, k, v, interpret=True)
+    fused = causal_gqa(_scaled(q), k, v, interpret=True)
     assert _gap(fused, exact) <= _gap(_plain(q, k, v), exact)
 
 
-def test_the_kernel_is_causal():
-    q, k, v, _ = _inputs(4, jnp.float32)
+@pytest.mark.parametrize("dims", [GQA, MLA], ids=["gqa", "mla192-128"])
+def test_the_kernel_is_causal(dims):
+    q, k, v, _ = _inputs(4 if dims is GQA else 1, jnp.float32, dims=dims)
     out = causal_gqa(q, k, v, interpret=True)
     cut = TILE + 37           # inside the second tile's diagonal block
     k2 = k.at[:, cut:].set(7.0)
@@ -106,27 +122,46 @@ def test_the_kernel_is_causal():
     assert not np.allclose(np.asarray(out[:, cut:]), np.asarray(out2[:, cut:]))
 
 
-@pytest.mark.parametrize("seq_len, head_dim, dtype, platform, taken", [
-    (4096, 64, jnp.bfloat16, "tpu", True),
-    (TILE, 64, jnp.float32, "tpu", True),
-    (4096, 64, jnp.bfloat16, "cpu", False),
-    (4096, 64, jnp.bfloat16, None, False),        # this process: the CPU
-    (TILE + 128, 64, jnp.bfloat16, "tpu", False),
-    (16, 8, jnp.float32, "tpu", False),           # lfm2.tiny()
-    (4096, 8, jnp.bfloat16, "tpu", False),
-    (4096, 64, jnp.float16, "tpu", False),
-], ids=["cell", "one-tile-f32", "cpu", "here", "part-tile", "tiny", "head8",
-        "float16"])
-def test_who_takes_the_kernel(seq_len, head_dim, dtype, platform, taken):
-    assert engages(seq_len, head_dim, dtype, platform) is taken
+@pytest.mark.parametrize("seq_len, dims, dtype, platform, taken", [
+    (4096, GQA, jnp.bfloat16, "tpu", True),
+    (TILE, GQA, jnp.float32, "tpu", True),
+    (4096, MLA, jnp.bfloat16, "tpu", True),
+    (TILE, MLA, jnp.float32, "tpu", True),
+    (4096, GQA, jnp.bfloat16, "cpu", False),
+    (4096, MLA, jnp.bfloat16, None, False),       # this process: the CPU
+    (TILE + 128, GQA, jnp.bfloat16, "tpu", False),
+    (TILE + 128, MLA, jnp.bfloat16, "tpu", False),
+    (16, (8, 8), jnp.float32, "tpu", False),      # lfm2.tiny()
+    (16, (12, 8), jnp.float32, "tpu", False),     # deepseek_v3.tiny()
+    (4096, (8, 8), jnp.bfloat16, "tpu", False),
+    (4096, (192, 192), jnp.bfloat16, "tpu", False),   # values as wide as keys
+    (4096, (128, 128), jnp.bfloat16, "tpu", False),   # no pair of the two
+    (4096, (64, 128), jnp.bfloat16, "tpu", False),
+    (4096, GQA, jnp.float16, "tpu", False),
+], ids=["lfm2-cell", "one-tile-f32", "kanana-cell", "mla-one-tile-f32", "cpu",
+        "here", "part-tile", "mla-part-tile", "tiny", "mla-tiny", "head8",
+        "192-192", "128-128", "64-128", "float16"])
+def test_who_takes_the_kernel(seq_len, dims, dtype, platform, taken):
+    assert engages(seq_len, *dims, dtype, platform) is taken
 
 
-@pytest.mark.parametrize("shape", [(1, TILE + 128, 4, 64), (1, T, 4, 8)],
-                         ids=["part-tile", "head8"])
-def test_a_refused_shape_raises_in_the_kernel(shape):
+@pytest.mark.parametrize("shape, dv", [
+    ((1, TILE + 128, 4, 64), 64), ((1, T, 4, 8), 8), ((1, T, 4, 192), 192),
+    ((1, T, 4, 64), 128)],
+    ids=["part-tile", "head8", "192-192", "64-128"])
+def test_a_refused_shape_raises_in_the_kernel(shape, dv):
     q = jnp.zeros(shape, jnp.float32)
-    kv = jnp.zeros(shape[:2] + (2, shape[3]), jnp.float32)
-    with pytest.raises(ValueError, match="whole tiles"):
+    k = jnp.zeros(shape[:2] + (2, shape[3]), jnp.float32)
+    v = jnp.zeros(shape[:2] + (2, dv), jnp.float32)
+    with pytest.raises(ValueError, match="whole tiles.*queries and keys, "
+                                         "values"):
+        causal_gqa(q, k, v, interpret=True)
+
+
+def test_keys_of_another_size_than_the_queries_are_refused():
+    q = jnp.zeros((1, TILE, 2, 192), jnp.float32)
+    kv = jnp.zeros((1, TILE, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match=r"\(n, T, Hkv, Dv\)"):
         causal_gqa(q, kv, kv, interpret=True)
 
 
@@ -156,9 +191,10 @@ def test_a_refused_shape_takes_the_plain_path_bit_for_bit(monkeypatch, cfg, t):
     want = lfm2.attention(p, u, cfg)
     asked = []
 
-    def as_on_tpu(seq_len, head_dim, dtype):
-        asked.append((seq_len, head_dim))
-        return engages(seq_len, head_dim, dtype, platform="tpu")
+    def as_on_tpu(seq_len, head_dim_qk, head_dim_v, dtype):
+        asked.append((seq_len, head_dim_qk, head_dim_v))
+        return engages(seq_len, head_dim_qk, head_dim_v, dtype,
+                       platform="tpu")
 
     def no_kernel(*a, **kw):
         raise AssertionError("the kernel was called")
@@ -166,7 +202,7 @@ def test_a_refused_shape_takes_the_plain_path_bit_for_bit(monkeypatch, cfg, t):
     monkeypatch.setattr(pallas_attention, "engages", as_on_tpu)
     monkeypatch.setattr(pallas_attention, "causal_gqa", no_kernel)
     got = lfm2.attention(p, u, cfg)
-    assert asked == [(t, cfg.head_dim)]
+    assert asked == [(t, cfg.head_dim, cfg.head_dim)]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -197,3 +233,68 @@ def test_attention_takes_the_kernel_where_it_engages(monkeypatch):
     for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(grads),
                                    jax.tree_util.tree_leaves(want_grads)):
         assert _gap(got_leaf, want_leaf) < 2e-5
+
+
+def _mla_case(t, **kw):
+    """Latent attention's weights as ``deepseek_v3.init`` lays them out (two
+    heads, scaled up so that the softmax is far from uniform), and
+    normalised input for one sequence."""
+    cfg = deepseek_v3.tiny(num_attention_heads=2, **kw)
+    p = deepseek_v3.init(jax.random.key(6), cfg)[0]["layers"][0]["attn"]
+    p = jax.tree_util.tree_map(lambda x: x * 6 if x.ndim == 2 else x, p)
+    return cfg, p, jax.random.normal(jax.random.key(7), (1, t, 32))
+
+
+def test_a_refused_latent_shape_takes_the_plain_path_bit_for_bit(monkeypatch):
+    """Heads of 12 | 8 as on a TPU: ``mla`` asks with both head sizes, is
+    refused, folds no scale and gives the bits it gives without a kernel."""
+    cfg, p, u = _mla_case(16)
+    want = deepseek_v3.mla(p, u, cfg)
+    asked = []
+
+    def as_on_tpu(seq_len, head_dim_qk, head_dim_v, dtype):
+        asked.append((seq_len, head_dim_qk, head_dim_v))
+        return engages(seq_len, head_dim_qk, head_dim_v, dtype,
+                       platform="tpu")
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("the kernel was called")
+
+    monkeypatch.setattr(pallas_attention, "engages", as_on_tpu)
+    monkeypatch.setattr(pallas_attention, "causal_gqa", no_kernel)
+    got = deepseek_v3.mla(p, u, cfg)
+    assert asked == [(16, 12, 8)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_latent_attention_takes_the_kernel_where_it_engages(monkeypatch):
+    """With ``engages`` answering as on a TPU and the kernel interpreted,
+    ``deepseek_v3.mla`` of a whole tile at the published head sizes (128 +
+    64 | 128) goes through the kernel with the scale folded into ``W_q``
+    (the kernel applies none) and agrees with the plain path, values and
+    parameter gradients: the shared rotary key's among them."""
+    cfg, p, u = _mla_case(TILE, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                          v_head_dim=128, attn_q_block=256)
+
+    def loss(p, u):
+        return jnp.sum(deepseek_v3.mla(p, u, cfg) ** 2)
+
+    want, want_grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    calls = []
+
+    def interpreted(q, k, v):
+        calls.append((q.shape, k.shape, v.shape))
+        return causal_gqa(q, k, v, interpret=True)
+
+    monkeypatch.setattr(pallas_attention, "engages",
+                        functools.partial(engages, platform="tpu"))
+    monkeypatch.setattr(pallas_attention, "causal_gqa", interpreted)
+    got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    assert calls == [((1, TILE, 2, 192), (1, TILE, 2, 192),
+                      (1, TILE, 2, 128))]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for (path, got_leaf), want_leaf in zip(
+            jax.tree_util.tree_flatten_with_path(grads)[0],
+            jax.tree_util.tree_leaves(want_grads)):
+        assert _gap(got_leaf, want_leaf) < 2e-5, jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(want_leaf))) > 0

@@ -36,7 +36,7 @@ import grace_tpu.ops
 from grace_tpu import grace_from_params
 from grace_tpu.compressors.topk import static_k
 from grace_tpu.memories import ResidualMemory
-from grace_tpu.models import lfm2, resnet
+from grace_tpu.models import deepseek_v3, lfm2, resnet
 from grace_tpu.ops import pallas_attention, sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
                                         quantize_stochastic, sign_pack)
@@ -50,6 +50,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
     sys.path.insert(0, REPO)
 
+from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
 
 N = 25_557_032            # ResNet-50's flat gradient
@@ -383,26 +384,31 @@ def test_topk_chunk_leaf_has_no_relayout_loop(one_chip, monkeypatch, shape,
 SCORE_BLOCK = re.compile(r"f32\[(?:1,)?(?:32|8,4),1024,(?:1024|2048|3072|4096)\]")
 
 
-def _attention_part_text(one_chip):
-    """The attention operator of the benchmark's LFM2 configuration on one
-    sequence (4,096 x 2,048, bfloat16, 32/8 heads of 64), as the step runs
-    it: recomputed from its input, forward and gradient."""
-    cfg = _lfm2_config()
-    layer = cfg.layer_types.index("full_attention")
-    part = lfm2._operator_part("full_attention", cfg)
-
+def _part_text(part, layer_shapes, cfg, one_chip):
+    """One part of a decoder layer on one sequence (4,096 x hidden,
+    bfloat16), as the step runs it: recomputed from its input, forward and
+    gradient, compiled for the described chip."""
     def loss(p, x):
         y = lfm2._over_sequences(part, p, x, cfg.seq_block)
         return jnp.sum(y.astype(jnp.float32))
 
     p = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        _lfm2_shapes()["layers"][layer])
+        layer_shapes)
     x = jax.ShapeDtypeStruct((1, 4096, cfg.hidden_size), jnp.bfloat16,
                              sharding=one_chip)
+    return compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
+
+
+def _attention_part_text(one_chip):
+    """The attention operator of the benchmark's LFM2 configuration (32/8
+    heads of 64)."""
+    cfg = _lfm2_config()
+    layer = cfg.layer_types.index("full_attention")
     assert (cfg.num_attention_heads, cfg.num_key_value_heads,
             cfg.head_dim) == (32, 8, 64)
-    return compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
+    return _part_text(lfm2._operator_part("full_attention", cfg),
+                      _lfm2_shapes()["layers"][layer], cfg, one_chip)
 
 
 def test_lfm2_attention_compiles_to_the_fused_kernel(one_chip, monkeypatch):
@@ -433,6 +439,60 @@ def test_lfm2_attention_still_compiles_without_the_kernel(one_chip):
     same part compiles for the chip from the plain spelling, score blocks
     and all."""
     text = _attention_part_text(one_chip)
+    assert "tpu_custom_call" not in text
+    assert SCORE_BLOCK.search(text)
+
+
+# ---------------------------------------------------------------------------
+# the same kernel at latent attention's head sizes, 192 | 128 (PR 32)
+# ---------------------------------------------------------------------------
+
+def _kanana_config():
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "kanana-2-30b-a3b-ep16.json")) as f:
+        return kanana.model_config(json.load(f))
+
+
+def _mla_part_text(one_chip):
+    """Latent attention of the benchmark's kanana configuration (32 heads
+    of 192 | 128)."""
+    cfg = _kanana_config()
+    shapes = jax.eval_shape(lambda k: deepseek_v3.init(k, cfg)[0],
+                            jax.random.key(0))
+    assert (cfg.num_attention_heads, cfg.qk_head_dim, cfg.v_head_dim,
+            cfg.kv_lora_rank) == (32, 192, 128, 512)
+    return _part_text(deepseek_v3._mla_part(cfg), shapes["layers"][1], cfg,
+                      one_chip)
+
+
+def test_latent_attention_compiles_to_the_fused_kernel(one_chip, monkeypatch):
+    """Mosaic takes the kernel at 192 lanes of queries and keys and 128 of
+    values, unpadded: the part holds it three times (forward, the
+    recomputation, the fused backward), each under ``grace/mla_latent/
+    grace/attention``, with operands of the published head sizes, and no
+    block of float32 scores is left in the text."""
+    monkeypatch.setattr(
+        pallas_attention, "engages",
+        functools.partial(pallas_attention.engages, platform="tpu"))
+    text = _mla_part_text(one_chip)
+    op_names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
+        re.S)
+    assert len(op_names) == text.count('custom_call_target="tpu_custom_call"')
+    assert all("grace/mla_latent" in name and "grace/attention" in name
+               and name.rfind("grace/attention")
+               > name.rfind("grace/mla_latent") for name in op_names), op_names
+    kernels = sorted(name.split("/")[-2] for name in op_names)
+    assert kernels == ["splash_mha_dkv_no_residuals",
+                       "splash_mha_fwd_residuals",
+                       "splash_mha_fwd_residuals"]
+    assert "bf16[32,4096,192]" in text and "bf16[32,4096,128]" in text
+    assert "bf16[32,4096,256]" not in text           # no padding to 256
+    assert not SCORE_BLOCK.search(text)
+
+
+def test_latent_attention_still_compiles_without_the_kernel(one_chip):
+    text = _mla_part_text(one_chip)
     assert "tpu_custom_call" not in text
     assert SCORE_BLOCK.search(text)
 
