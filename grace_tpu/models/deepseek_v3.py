@@ -50,8 +50,9 @@ cast to the activation dtype: ``q`` is rounded once, as the plain
 spelling's is, and its float32 scores are scaled where they are made.
 
 Memory as in ``lfm2.py``: every part is recomputed in the backward pass
-from its input, attention and the dense feed-forward ``seq_block``
-sequences at a time; parameters float32, cast inside a block, so a
+from its input (but for the fused kernel's output and log-sum-exp, which
+are kept), attention and the dense feed-forward ``seq_block`` sequences at
+a time; parameters float32, cast inside a block, so a
 weight's gradient is summed over the blocks in float32. Model state: per
 expert layer the correction bias and the counters ``drawn``, ``held``,
 ``dropped`` of ``lfm2``.

@@ -45,9 +45,11 @@ this file does not have.
 Memory: every part of a layer (operator, feed-forward, head with loss) is
 recomputed in the backward pass from its input, the dense parts
 ``seq_block`` sequences at a time, so the step holds two activations a
-layer and one block's intermediates. Parameters are cast to the
-activation dtype inside a block, so a weight's gradient is summed over
-the blocks in float32.
+layer and one block's intermediates; and, where attention took the fused
+kernel, the kernel's output and log-sum-exp of every sequence, which its
+backward pass reads in place of a second run of the forward kernel.
+Parameters are cast to the activation dtype inside a block, so a weight's
+gradient is summed over the blocks in float32.
 
 Model state carries, per expert layer, the expert bias and three counters
 of the last step (float32, so that the step's mean over replicas keeps
@@ -199,9 +201,14 @@ def init_state(cfg: Config) -> L.ModelState:
 
 def _over_sequences(fn, p, x, block: int):
     """``fn(p, x_block)`` over blocks of ``block`` sequences, one after
-    another, each recomputed from its input in the backward pass. ``x`` and
-    what ``fn`` returns are trees whose leaves lead with the sequences."""
-    fn = jax.checkpoint(fn)
+    another, each recomputed from its input in the backward pass but for
+    what the fused attention kernel names (``pallas_attention.
+    RESIDUAL_NAME``: its output and log-sum-exp, which only the kernel can
+    make again; a part without the kernel holds no such name and keeps
+    nothing). ``x`` and what ``fn`` returns are trees whose leaves lead with
+    the sequences."""
+    fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.
+                        save_only_these_names(pallas_attention.RESIDUAL_NAME))
     n = jax.tree_util.tree_leaves(x)[0].shape[0]
     if block >= n:
         return fn(p, x)

@@ -37,6 +37,16 @@ multiplies ``q`` by ``1 / sqrt(64)`` in ``q``'s dtype (a power of two:
 exact), ``models/deepseek_v3.py`` folds ``1 / sqrt(192)`` into the query
 projection's weights in float32 as it casts them, so ``q`` is rounded once.
 
+**What a recomputed part keeps.** The kernel's backward pass needs two
+things that only its forward can make: the output and the log-sum-exp. The
+forward rule names both :data:`RESIDUAL_NAME` (``jax.ad_checkpoint.
+checkpoint_name``). Inside a ``jax.checkpoint`` whose policy keeps that
+name (``save_only_these_names``: ``models/lfm2.py::_over_sequences``) the
+backward pass reads the forward's output and log-sum-exp and the forward
+kernel is not run again: two calls a part (forward, backward) where there
+were three (forward, recomputation, backward) until PR 33. Under a policy
+that does not know the name, or under none, nothing changes.
+
 ``engages`` is the ONE rule for who takes the kernel: a TPU, a sequence of
 whole tiles, a pair of head sizes and a dtype the kernel takes. Callers
 ask it and keep their plain spelling for everything else.
@@ -61,6 +71,10 @@ TILE = math.lcm(BLOCK_Q, BLOCK_KV)
 # (queries and keys, values)
 HEAD_DIMS = ((64, 64), (192, 128))
 DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+# What the forward rule calls its output and its log-sum-exp: a
+# `jax.checkpoint` policy that saves this name spares the backward pass the
+# forward kernel (the module's docstring).
+RESIDUAL_NAME = "attention_residuals"
 
 
 def _takes(seq_len: int, head_dim_qk: int, head_dim_v: int, dtype) -> bool:
@@ -95,7 +109,8 @@ def _kernel(seq_len: int, q_heads: int, interpret: bool):
     # here and not inside whatever trace asked first
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
-                                      q_seq_shards=1, interpret=interpret)
+                                      q_seq_shards=1, interpret=interpret,
+                                      residual_checkpoint_name=RESIDUAL_NAME)
 
 
 def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -37,3 +37,17 @@ def mesh():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def keep_nothing(monkeypatch):
+    """``keep_nothing()``: from then on ``models.lfm2._over_sequences``
+    recomputes its parts under a ``jax.checkpoint`` that keeps nothing, as
+    it did until PR 33 (its policy keeps what the fused attention kernel
+    names). For tests that hold the two against each other; trace a
+    function of its own on each side, since JAX answers a second trace of
+    the same one from its cache."""
+    def switch():
+        monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                            lambda *names: None)
+    return switch

@@ -442,6 +442,55 @@ def test_a_share_outside_the_routers_experts_is_refused():
 
 
 # ---------------------------------------------------------------------------
+# what a recomputed part keeps (PR 33): nothing, where there is no kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("which", ["plain_latent_attention", "dense_layer"])
+def test_a_part_without_the_kernel_keeps_nothing(keep_nothing, which):
+    """On the CPU latent attention scores in its plain blocks (``engages``
+    says no), so neither it nor the dense layer names anything for
+    ``_over_sequences`` to keep: the gradient's jaxpr (but for the line
+    that prints the policy's address) and its lowered text are those of a
+    ``jax.checkpoint`` that keeps nothing."""
+    cfg = dsv3.tiny()
+    p = dsv3.init(jax.random.key(3), cfg)[0]["layers"][0]
+    x = jax.random.normal(jax.random.key(4), (4, 16, cfg.hidden_size))
+    part = (dsv3._mla_part(cfg) if which == "plain_latent_attention"
+            else lfm2._dense_part(cfg))
+
+    def texts():
+        # a function of its own each time (`keep_nothing`)
+        grad = jax.value_and_grad(
+            lambda p, x: jnp.sum(lfm2._over_sequences(part, p, x, 1) ** 2),
+            argnums=(0, 1))
+        jaxpr = [line for line in str(jax.make_jaxpr(grad)(p, x)).splitlines()
+                 if "policy=" not in line]
+        return jaxpr, jax.jit(grad).lower(p, x).as_text()
+
+    jaxpr, lowered = texts()
+    assert not any("pallas_call" in line or "name[" in line
+                   for line in jaxpr)
+    assert any("remat" in line for line in jaxpr)
+    keep_nothing()
+    plain_jaxpr, plain_lowered = texts()
+    assert jaxpr == plain_jaxpr
+    assert lowered == plain_lowered
+
+
+def test_the_whole_model_is_the_same_bits_under_a_plain_checkpoint(
+        keep_nothing, float32_pair):
+    """All parts together: loss and every gradient are the bits a
+    ``jax.checkpoint`` that keeps nothing gives."""
+    (want_loss, want, _), _ = float32_pair
+    keep_nothing()
+    loss, grads, _ = _run(_program_loss(SIZES))
+    assert loss == want_loss
+    for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
 # on the normal path: the compressed training step, its counters, its scopes
 # ---------------------------------------------------------------------------
 

@@ -24,6 +24,7 @@ from benchmarks.reference import lfm2_moe as plain  # noqa: E402
 from benchmarks.trace_reduce import STAGE, stage_of  # noqa: E402
 from grace_tpu.models import layers as L  # noqa: E402
 from grace_tpu.models import lfm2  # noqa: E402
+from grace_tpu.ops import pallas_attention  # noqa: E402
 from grace_tpu.telemetry import scopes  # noqa: E402
 
 # A share of a small model in the configuration file's own keys: 2 experts
@@ -432,6 +433,78 @@ def test_blocks_that_do_not_divide_the_batch_are_refused():
     ids = jnp.zeros((4, 8), jnp.int32)
     with pytest.raises(ValueError, match="do not divide"):
         lfm2.next_token_loss(params, state, ids, cfg)
+
+
+# ---------------------------------------------------------------------------
+# what a recomputed part keeps (PR 33): nothing, where there is no kernel
+# ---------------------------------------------------------------------------
+
+def _gradient_texts(loss, *args):
+    """The gradient's jaxpr and lowered text of ``loss``, through a function
+    of its own each time (``keep_nothing``). The jaxpr prints the policy's
+    address on a line of its own: left out."""
+    grad = jax.value_and_grad(lambda *a: loss(*a), argnums=(0, 1))
+    jaxpr = "\n".join(line for line in str(jax.make_jaxpr(grad)(*args))
+                      .splitlines() if "policy=" not in line)
+    return jaxpr, jax.jit(grad).lower(*args).as_text()
+
+
+def _part_case(which):
+    """A part of a tiny decoder without the fused kernel, as the step walks
+    it (four sequences, one at a time): ``(loss, weights, input)``."""
+    cfg = lfm2.tiny()
+    params, _ = lfm2.init(jax.random.key(3), cfg)
+    x = jax.random.normal(jax.random.key(4), (4, 16, cfg.hidden_size))
+    if which == "head":
+        ids = jax.random.randint(jax.random.key(5), (4, 16), 0,
+                                 cfg.vocab_size)
+        return (lambda p, x: lfm2.loss_of_hidden_states(p, x, ids, cfg),
+                params, x)
+    kind = {"short_conv": "conv", "plain_attention": "full_attention"}.get(
+        which)
+    part, layer = ((lfm2._dense_part(cfg), 0) if kind is None else
+                   (lfm2._operator_part(kind, cfg),
+                    cfg.layer_types.index(kind)))
+    return (lambda p, x: jnp.sum(lfm2._over_sequences(part, p, x, 1) ** 2),
+            params["layers"][layer], x)
+
+
+@pytest.mark.parametrize("which", ["short_conv", "dense_ffn", "head",
+                                   "plain_attention"])
+def test_a_part_without_the_kernel_keeps_nothing(keep_nothing, which):
+    """``_over_sequences`` keeps what the fused attention kernel names. A
+    part that does not hold the kernel (here, on the CPU, attention too:
+    ``engages`` says no and the scores go through their plain blocks)
+    names nothing, so its gradient is, equation for equation and in the
+    lowered text, what a ``jax.checkpoint`` that keeps nothing gives."""
+    loss, *args = _part_case(which)
+    jaxpr, lowered = _gradient_texts(loss, *args)
+    assert pallas_attention.RESIDUAL_NAME not in jaxpr
+    assert "pallas_call" not in jaxpr
+    keep_nothing()
+    plain_jaxpr, plain_lowered = _gradient_texts(loss, *args)
+    assert jaxpr == plain_jaxpr
+    assert lowered == plain_lowered
+    assert "remat" in jaxpr       # the part is recomputed, as before
+
+
+def test_the_whole_model_is_the_same_bits_under_a_plain_checkpoint(
+        keep_nothing, float32_pair):
+    """All parts together (the lowered text of the whole model names its
+    shared functions by the order JAX met them, so here the numbers are
+    compared): loss and every gradient are the bits a ``jax.checkpoint``
+    that keeps nothing gives."""
+    (want_loss, want, _), _ = float32_pair
+    keep_nothing()
+    with jax.default_matmul_precision("highest"):
+        params, state = builder.init(jax.random.key(1), SIZES)
+        ids = builder.make_batch(jax.random.key(2), 4, SIZES)
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            _program_loss(SIZES), has_aux=True))(params, state, ids)
+    assert float(loss) == want_loss
+    for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------

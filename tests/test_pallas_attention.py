@@ -298,3 +298,99 @@ def test_latent_attention_takes_the_kernel_where_it_engages(monkeypatch):
             jax.tree_util.tree_leaves(want_grads)):
         assert _gap(got_leaf, want_leaf) < 2e-5, jax.tree_util.keystr(path)
         assert float(jnp.max(jnp.abs(want_leaf))) > 0
+
+
+# ---------------------------------------------------------------------------
+# what a recomputed part keeps of the kernel (PR 33)
+# ---------------------------------------------------------------------------
+
+def _lfm2_part():
+    """The attention operator of a decoder layer at head size 64, with its
+    weights and two sequences of one tile."""
+    cfg = dataclasses.replace(lfm2.tiny(), head_dim=64, attn_q_block=256)
+    layer = cfg.layer_types.index("full_attention")
+    p = lfm2.init(jax.random.key(8), cfg)[0]["layers"][layer]
+    return lfm2._operator_part("full_attention", cfg), p, cfg.hidden_size
+
+
+def _latent_part():
+    """Latent attention of a decoder layer at the published head sizes
+    (128 + 64 | 128), two heads."""
+    cfg = deepseek_v3.tiny(num_attention_heads=2, qk_nope_head_dim=128,
+                           qk_rope_head_dim=64, v_head_dim=128,
+                           attn_q_block=256)
+    p = deepseek_v3.init(jax.random.key(8), cfg)[0]["layers"][0]
+    return deepseek_v3._mla_part(cfg), p, cfg.hidden_size
+
+
+PARTS = {"lfm2-attention": _lfm2_part, "latent-attention": _latent_part}
+
+
+@pytest.fixture
+def kernel_interpreted(monkeypatch):
+    """``engages`` answering as on a TPU, the kernel in Pallas's
+    interpreter."""
+    monkeypatch.setattr(pallas_attention, "engages",
+                        functools.partial(engages, platform="tpu"))
+    monkeypatch.setattr(pallas_attention, "causal_gqa",
+                        functools.partial(causal_gqa, interpret=True))
+
+
+def _walked(part, p, hidden):
+    """Value and gradient of a part walked over two sequences of one tile,
+    one after the other, as the step walks it (a function of its own each
+    time: ``keep_nothing``)."""
+    x = jax.random.normal(jax.random.key(9), (2, TILE, hidden))
+
+    def loss(p, x):
+        return jnp.sum(lfm2._over_sequences(part, p, x, 1) ** 2)
+
+    return jax.value_and_grad(loss, argnums=(0, 1)), p, x
+
+
+def test_the_forward_rule_names_its_output_and_log_sum_exp():
+    """The name is on the kernel whoever calls it: under no
+    ``jax.checkpoint`` at all the gradient's jaxpr holds it twice (output,
+    log-sum-exp) and nothing else changes."""
+    q, k, v, _ = _inputs(2, jnp.float32, t=TILE)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q: jnp.sum(causal_gqa(q, k, v, interpret=True))))(q))
+    assert text.count(f"name[name={pallas_attention.RESIDUAL_NAME}]") == 2
+    assert text.count("pallas_call") == 2       # forward, fused backward
+
+
+@pytest.mark.parametrize("which", sorted(PARTS))
+def test_a_recomputed_part_runs_the_forward_kernel_once(
+        kernel_interpreted, keep_nothing, which):
+    """Through ``_over_sequences`` the gradient of an attention part holds
+    the kernel twice, forward and fused backward: the backward pass reads
+    the kept output and log-sum-exp. Under a ``jax.checkpoint`` that keeps
+    nothing it holds a third call, the forward run again."""
+    grad, p, x = _walked(*PARTS[which]())
+    kept = str(jax.make_jaxpr(grad)(p, x))
+    keep_nothing()
+    grad, p, x = _walked(*PARTS[which]())
+    recomputed = str(jax.make_jaxpr(grad)(p, x))
+    assert kept.count("pallas_call") == 2
+    assert recomputed.count("pallas_call") == 3
+    name = f"name[name={pallas_attention.RESIDUAL_NAME}]"
+    assert kept.count(name) == 2 and name in recomputed
+
+
+@pytest.mark.parametrize("which", sorted(PARTS))
+def test_the_kept_residuals_are_the_recomputed_ones_bit_for_bit(
+        kernel_interpreted, keep_nothing, which):
+    """Value, parameter gradients and input gradient of an attention part
+    are the same bits whether the backward pass reads what the forward
+    kernel wrote or runs it again."""
+    grad, p, x = _walked(*PARTS[which]())
+    kept = jax.jit(grad)(p, x)
+    keep_nothing()
+    grad, p, x = _walked(*PARTS[which]())
+    recomputed = jax.jit(grad)(p, x)
+    for (path, got), want in zip(
+            jax.tree_util.tree_flatten_with_path(kept)[0],
+            jax.tree_util.tree_leaves(recomputed)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=jax.tree_util.keystr(path))
+    assert float(jnp.max(jnp.abs(kept[1][1]))) > 0

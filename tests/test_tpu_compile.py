@@ -382,12 +382,17 @@ def test_topk_chunk_leaf_has_no_relayout_loop(one_chip, monkeypatch, shape,
 
 # a (heads, 1,024 queries, keys) float32 tensor: a block of scores in HBM
 SCORE_BLOCK = re.compile(r"f32\[(?:1,)?(?:32|8,4),1024,(?:1024|2048|3072|4096)\]")
+# the kernel's calls in a part whose forward output and log-sum-exp are
+# kept for the backward pass, and in one recomputed whole
+KEPT = ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"]
+RECOMPUTED = KEPT + ["splash_mha_fwd_residuals"]
 
 
-def _part_text(part, layer_shapes, cfg, one_chip):
-    """One part of a decoder layer on one sequence (4,096 x hidden,
-    bfloat16), as the step runs it: recomputed from its input, forward and
-    gradient, compiled for the described chip."""
+def _part_text(part, layer_shapes, cfg, one_chip, sequences=1):
+    """One part of a decoder layer on ``sequences`` sequences (4,096 x
+    hidden, bfloat16), as the step runs it: one sequence after another,
+    recomputed from its input but for what the fused kernel names, forward
+    and gradient, compiled for the described chip."""
     def loss(p, x):
         y = lfm2._over_sequences(part, p, x, cfg.seq_block)
         return jnp.sum(y.astype(jnp.float32))
@@ -395,12 +400,32 @@ def _part_text(part, layer_shapes, cfg, one_chip):
     p = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         layer_shapes)
-    x = jax.ShapeDtypeStruct((1, 4096, cfg.hidden_size), jnp.bfloat16,
-                             sharding=one_chip)
+    x = jax.ShapeDtypeStruct((sequences, 4096, cfg.hidden_size),
+                             jnp.bfloat16, sharding=one_chip)
     return compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
 
 
-def _attention_part_text(one_chip):
+def _kernel_calls(text):
+    """The Pallas calls of a compiled text by kernel name, sorted, each
+    with its ``op_name`` (read instruction by instruction: JAX prints a
+    kernel's metadata with line breaks, so the ``op_name`` stands lines
+    below the call's name)."""
+    op_names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
+        re.S)
+    assert len(op_names) == text.count('custom_call_target="tpu_custom_call"')
+    return sorted(name.split("/")[-2] for name in op_names), op_names
+
+
+def _as_on_the_chip(monkeypatch):
+    """``engages`` answering for a TPU (its ``platform`` argument: this
+    process's backend is the CPU)."""
+    monkeypatch.setattr(
+        pallas_attention, "engages",
+        functools.partial(pallas_attention.engages, platform="tpu"))
+
+
+def _attention_part_text(one_chip, sequences=1):
     """The attention operator of the benchmark's LFM2 configuration (32/8
     heads of 64)."""
     cfg = _lfm2_config()
@@ -408,29 +433,23 @@ def _attention_part_text(one_chip):
     assert (cfg.num_attention_heads, cfg.num_key_value_heads,
             cfg.head_dim) == (32, 8, 64)
     return _part_text(lfm2._operator_part("full_attention", cfg),
-                      _lfm2_shapes()["layers"][layer], cfg, one_chip)
+                      _lfm2_shapes()["layers"][layer], cfg, one_chip,
+                      sequences)
 
 
 def test_lfm2_attention_compiles_to_the_fused_kernel(one_chip, monkeypatch):
-    """With ``engages`` answering as on the chip (its ``platform`` argument:
-    this process's backend is the CPU), the part holds the kernel three
-    times — forward, the recomputation, the fused backward — each under
-    ``grace/attention`` (the ``op_name`` stands on a later line of the
-    instruction: JAX prints the kernel's metadata with line breaks), and no
-    block of float32 scores is left in the text."""
-    monkeypatch.setattr(
-        pallas_attention, "engages",
-        functools.partial(pallas_attention.engages, platform="tpu"))
+    """With ``engages`` answering as on the chip, the part holds the kernel
+    twice — forward and the fused backward — each under ``grace/attention``,
+    and no block of float32 scores is left in the text. Two and not three
+    (PR 33): ``_over_sequences`` keeps the output and the log-sum-exp the
+    forward kernel names, which is all of the forward that the kernel's
+    backward reads, so recomputing the part does not run the forward kernel
+    again."""
+    _as_on_the_chip(monkeypatch)
     text = _attention_part_text(one_chip)
-    op_names = re.findall(
-        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
-        re.S)
-    assert len(op_names) == text.count('custom_call_target="tpu_custom_call"')
+    kernels, op_names = _kernel_calls(text)
     assert all("grace/attention" in name for name in op_names), op_names
-    kernels = sorted(name.split("/")[-2] for name in op_names)
-    assert kernels == ["splash_mha_dkv_no_residuals",
-                       "splash_mha_fwd_residuals",
-                       "splash_mha_fwd_residuals"]
+    assert kernels == KEPT
     assert not SCORE_BLOCK.search(text)
 
 
@@ -453,7 +472,7 @@ def _kanana_config():
         return kanana.model_config(json.load(f))
 
 
-def _mla_part_text(one_chip):
+def _mla_part_text(one_chip, sequences=1):
     """Latent attention of the benchmark's kanana configuration (32 heads
     of 192 | 128)."""
     cfg = _kanana_config()
@@ -462,30 +481,23 @@ def _mla_part_text(one_chip):
     assert (cfg.num_attention_heads, cfg.qk_head_dim, cfg.v_head_dim,
             cfg.kv_lora_rank) == (32, 192, 128, 512)
     return _part_text(deepseek_v3._mla_part(cfg), shapes["layers"][1], cfg,
-                      one_chip)
+                      one_chip, sequences)
 
 
 def test_latent_attention_compiles_to_the_fused_kernel(one_chip, monkeypatch):
     """Mosaic takes the kernel at 192 lanes of queries and keys and 128 of
-    values, unpadded: the part holds it three times (forward, the
-    recomputation, the fused backward), each under ``grace/mla_latent/
-    grace/attention``, with operands of the published head sizes, and no
-    block of float32 scores is left in the text."""
-    monkeypatch.setattr(
-        pallas_attention, "engages",
-        functools.partial(pallas_attention.engages, platform="tpu"))
+    values, unpadded: the part holds it twice (forward and the fused
+    backward: since PR 33 the backward reads the forward's kept output and
+    log-sum-exp, where it ran the forward kernel a second time), each under
+    ``grace/mla_latent/grace/attention``, with operands of the published
+    head sizes, and no block of float32 scores is left in the text."""
+    _as_on_the_chip(monkeypatch)
     text = _mla_part_text(one_chip)
-    op_names = re.findall(
-        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text,
-        re.S)
-    assert len(op_names) == text.count('custom_call_target="tpu_custom_call"')
+    kernels, op_names = _kernel_calls(text)
     assert all("grace/mla_latent" in name and "grace/attention" in name
                and name.rfind("grace/attention")
                > name.rfind("grace/mla_latent") for name in op_names), op_names
-    kernels = sorted(name.split("/")[-2] for name in op_names)
-    assert kernels == ["splash_mha_dkv_no_residuals",
-                       "splash_mha_fwd_residuals",
-                       "splash_mha_fwd_residuals"]
+    assert kernels == KEPT
     assert "bf16[32,4096,192]" in text and "bf16[32,4096,128]" in text
     assert "bf16[32,4096,256]" not in text           # no padding to 256
     assert not SCORE_BLOCK.search(text)
@@ -495,6 +507,48 @@ def test_latent_attention_still_compiles_without_the_kernel(one_chip):
     text = _mla_part_text(one_chip)
     assert "tpu_custom_call" not in text
     assert SCORE_BLOCK.search(text)
+
+
+# ---------------------------------------------------------------------------
+# what the recomputed part keeps of the kernel, in both decoders (PR 33)
+# ---------------------------------------------------------------------------
+
+# the part's compiled text over so many sequences, and the value heads' size
+DECODER_PARTS = {"lfm2": (_attention_part_text, 64),
+                 "kanana": (_mla_part_text, 128)}
+
+
+@pytest.mark.parametrize("decoder", sorted(DECODER_PARTS))
+def test_the_kept_pair_is_two_stacked_buffers(one_chip, monkeypatch, decoder):
+    """Walked over two sequences, the part keeps the kernel's output and
+    log-sum-exp of both: one bfloat16 buffer of the heads-major output at
+    the published sizes and one float32 number a query and head, a row a
+    sequence, written by the forward loop and read by the backward loop;
+    the kernel still runs twice a sequence, and one sequence alone (above)
+    has no stack."""
+    part_text, dv = DECODER_PARTS[decoder]
+    _as_on_the_chip(monkeypatch)
+    text = part_text(one_chip, sequences=2)
+    assert _kernel_calls(text)[0] == KEPT
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert sum(f"bf16[2,1,32,4096,{dv}]" in line
+               and "f32[2,1,32,4096]" in line for line in loops) == 2
+
+
+@pytest.mark.parametrize("decoder", sorted(DECODER_PARTS))
+def test_the_name_alone_changes_nothing(one_chip, monkeypatch, keep_nothing,
+                                        decoder):
+    """Under a ``jax.checkpoint`` that keeps nothing (``_over_sequences``
+    until PR 33) the kernel, its residuals named as they now are, is in the
+    part three times — forward, the recomputation, the fused backward — and
+    no output is stacked: it is the policy that keeps the pair."""
+    part_text, dv = DECODER_PARTS[decoder]
+    _as_on_the_chip(monkeypatch)
+    keep_nothing()
+    text = part_text(one_chip, sequences=2)
+    assert _kernel_calls(text)[0] == RECOMPUTED
+    assert f"bf16[2,1,32,4096,{dv}]" not in text
+    assert "f32[2,1,32,4096]" not in text
 
 
 def _lfm2_config():
