@@ -38,7 +38,8 @@ published).
 What is shared with ``models/lfm2.py`` is imported from it, not copied: the
 router, the expert layer told which experts it holds (``moe_ffn``: routes
 over all ``num_experts``, computes its own experts' part over the sorted
-assignments, drops none), the dense feed-forward and its part of a layer,
+assignments in tiles of one expert each, as many as hold a row, and drops
+none), the dense feed-forward and its part of a layer,
 the walk over sequences, the head with the loss. The shared expert is
 computed whole by every chip of a layer and added to the routed part. Two
 spellings of the scores, as there: the fused kernel where ``ops.pallas_attention.engages`` says so (the
@@ -55,7 +56,7 @@ are kept), attention and the dense feed-forward ``seq_block`` sequences at
 a time; parameters float32, cast inside a block, so a
 weight's gradient is summed over the blocks in float32. Model state: per
 expert layer the correction bias and the counters ``drawn``, ``held``,
-``dropped`` of ``lfm2``.
+``computed``, ``dropped`` of ``lfm2``.
 """
 
 from __future__ import annotations
@@ -115,7 +116,8 @@ class Config:
     # how the work is walked, not what is computed
     seq_block: int = 1            # sequences recomputed together
     attn_q_block: int = 1024      # queries scored together (plain path)
-    moe_row_block: int = 0        # rows of one grouped product; 0: by load
+    moe_row_block: int = 0        # rows of one tile of the expert walk; 0:
+                                  # from the shapes
 
     def __post_init__(self):
         if not 0 <= self.first_expert <= (self.num_experts
@@ -258,7 +260,7 @@ def hidden_states(params, model_state, ids, cfg: Config,
         if cfg.is_moe(i):
             # recomputed from x, all sequences together: the routed experts'
             # own forward is not needed again (their backward recomputes
-            # block by block), and the shared expert's weight gradients are
+            # tile by tile), and the shared expert's weight gradients are
             # one product each, not a float32 sum over sequences
             x, s = jax.checkpoint(_moe_part(cfg))(p, s, x)
         else:

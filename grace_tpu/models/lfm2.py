@@ -30,17 +30,19 @@ Equations (``u = RMSNorm(x)`` with a learned weight; no bias anywhere):
 
 **The expert layer is told which experts it holds** (``first_expert``,
 ``experts_held``): it routes over all ``num_experts``, and computes the
-part of the result its own experts give, as grouped matrix products
-(``lax.ragged_dot``) over the assignments sorted by expert. No assignment
-is dropped: the sorted rows are bounded by the worst case (every one of a
-token's ``k`` assignments held here) and walked in blocks, each gathering
-its rows, running the grouped products and adding the results to their
-tokens; a block past the rows in use is skipped, so the work follows the
-load and not the bound, and a visited block is computed whole, so the
-time does not follow the load inside it. A block is ``moe_row_block``
-rows, or twice a balanced router's load. With all experts held the layer is the
-whole one; with a share it is what that chip computes before an exchange
-this file does not have.
+part of the result its own experts give, over the assignments sorted by
+expert. No assignment is dropped: the walk is bounded by the worst case
+(every one of a token's ``k`` assignments held here) and runs over the
+tiles in use only, so the work follows the load and not the bound. A tile
+is ``moe_row_block`` sorted rows, or a quarter of a balanced router's load
+on one expert; each held expert's rows start on a tile's boundary, so a
+tile belongs to one expert: it gathers its rows, multiplies them with that
+expert's three matrices (plain products, under their stage's name) and
+adds the results to their tokens, and backwards adds its weight gradients
+to that expert's float32 slices in place. What is multiplied beyond the
+rows held is less than a tile an expert. With all experts held the layer
+is the whole one; with a share it is what that chip computes before an
+exchange this file does not have.
 
 Memory: every part of a layer (operator, feed-forward, head with loss) is
 recomputed in the backward pass from its input, the dense parts
@@ -51,11 +53,14 @@ backward pass reads in place of a second run of the forward kernel.
 Parameters are cast to the activation dtype inside a block, so a weight's
 gradient is summed over the blocks in float32.
 
-Model state carries, per expert layer, the expert bias and three counters
+Model state carries, per expert layer, the expert bias and four counters
 of the last step (float32, so that the step's mean over replicas keeps
 their type): ``drawn`` (assignments each of the ``num_experts`` experts
-drew), ``held`` (assignments to held experts that were computed) and
-``dropped`` (assignments to held experts that were not: always 0).
+drew), ``held`` (assignments to held experts that were computed),
+``computed`` (rows multiplied for them: the tiles in use, padding
+included) and ``dropped`` (assignments to held experts that were not
+computed: always 0). A state made without one of the counters comes back
+without it.
 """
 
 from __future__ import annotations
@@ -106,7 +111,8 @@ class Config:
     # how the work is walked, not what is computed
     seq_block: int = 1            # sequences recomputed together
     attn_q_block: int = 1024      # queries scored together (plain path)
-    moe_row_block: int = 0        # rows of one grouped product; 0: by load
+    moe_row_block: int = 0        # rows of one tile of the expert walk; 0:
+                                  # from the shapes
 
     def __post_init__(self):
         if not 0 <= self.first_expert <= (self.num_experts
@@ -187,6 +193,7 @@ def expert_layer_state(num_experts: int) -> L.ModelState:
     return {"expert_bias": jnp.zeros((num_experts,), jnp.float32),
             "drawn": jnp.zeros((num_experts,), jnp.float32),
             "held": jnp.zeros((), jnp.float32),
+            "computed": jnp.zeros((), jnp.float32),
             "dropped": jnp.zeros((), jnp.float32)}
 
 
@@ -331,106 +338,117 @@ _sorted.defvjp(
     lambda inverse, g: (jnp.take(g, inverse, axis=0), None, None))
 
 
-def _row_block(cfg: Config, tokens: int) -> int:
-    """Rows of one grouped product: ``moe_row_block``, or twice the load of
-    a balanced router, in whole tiles of 512. Twice, because a router
-    trained on a share learns to prefer the experts that answer: the load
-    here grew by a quarter in 45 steps (PERF.md, PR 28), and a step's time
-    should not jump when it crosses a block's end. Small blocks are no way
-    out as the layer stands: a block of 2,048 rows costs 1.7 times as much
-    a row on the chip (each block's backward pass adds the whole float32
-    weight-gradient stacks once more), so the step came out 1.3 % slower
-    than with this default, and less steady."""
+def _tile_rows(cfg: Config, tokens: int) -> int:
+    """Rows of one tile of the walk over the sorted assignments:
+    ``moe_row_block`` as given, or a quarter of the rows a balanced router
+    sends one expert (``tokens * num_experts_per_tok / num_experts``), in
+    whole multiples of 128. A held expert's rows start on a tile's
+    boundary, so each pads half a tile on average: an eighth of its
+    balanced load at this default. Smaller tiles pad less and pay more for
+    what every tile costs whatever its rows, the float32 sum into its
+    expert's slice of each weight gradient (PERF.md, PR 37)."""
     if cfg.moe_row_block:
         return cfg.moe_row_block
-    mean = tokens * cfg.num_experts_per_tok * cfg.experts_held / cfg.num_experts
-    return max(512, -(-int(2 * mean) // 512) * 512)
-
-
-def _expert_rows(w, rows, gates, sizes, n_valid):
-    """The held experts' gated feed-forward of one block of sorted rows,
-    weighted. ``sizes``: rows of each held expert in this block, all of
-    the block's rows among them; the rows from ``n_valid`` on are no
-    token's, go in as zeros and are held at zero."""
-    valid = (jnp.arange(rows.shape[0]) < n_valid)[:, None]
-
-    def grouped(x, w):
-        return jnp.where(valid, lax.ragged_dot(x, w, sizes), 0)
-
-    rows = jnp.where(valid, rows, 0)
-    h = jax.nn.silu(grouped(rows, w["w1"])) * grouped(rows, w["w3"])
-    return grouped(h, w["w2"]) * gates[:, None].astype(rows.dtype)
-
-
-def _blocks(tokens, total, visit, carry):
-    """``visit(c, n_valid, carry)`` for every block of sorted rows that holds
-    a row; the blocks past ``total`` rows are skipped, so the work follows
-    the rows there are and not the bound on them."""
-    n_blocks, block = tokens.shape
-
-    def body(c, carry):
-        n_valid = jnp.clip(total - c * block, 0, block)
-        return lax.cond(n_valid > 0, lambda carry: visit(c, n_valid, carry),
-                        lambda carry: carry, carry)
-
-    return lax.fori_loop(0, n_blocks, body, carry)
+    balanced = tokens * cfg.num_experts_per_tok // cfg.num_experts
+    return max(128, balanced // 4 // 128 * 128)
 
 
 def _cast(w, dtype):
     return jax.tree_util.tree_map(lambda a: a.astype(dtype), w)
 
 
-@jax.custom_vjp
-def held_experts(w, x, tokens, gates, sizes, total):
+def _tile_of(tokens, gates, tiles, t, tile: int):
+    """Tile ``t`` of the walk: its expert, the tokens of its ``tile`` sorted
+    rows and their gates, and which of the rows are its expert's. The rows
+    past those are the next expert's or nobody's: their gate is zero here,
+    so they add nothing to a token, and nothing to a gradient."""
+    expert, first, n_valid = (a[t] for a in tiles)
+    valid = jnp.arange(tile) < n_valid
+    tok = lax.dynamic_slice(tokens, (first,), (tile,))
+    g = jnp.where(valid, lax.dynamic_slice(gates, (first,), (tile,)), 0)
+    return expert, first, valid, tok, g
+
+
+def _expert(wa, expert):
+    """One held expert's matrices of the cast stacks."""
+    return {k: lax.dynamic_index_in_dim(a, expert, keepdims=False)
+            for k, a in wa.items()}
+
+
+def _gated(a, b):
+    return jax.nn.silu(a) * b
+
+
+def _dot_rows(a, b):
+    """``a^T b`` summed over the rows both lead with, in float32."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(tile: int, w, x, tokens, gates, tiles, in_use):
     """Sum over the sorted rows of the held experts' weighted results, by
-    token: ``(N, d)``. ``tokens``, ``gates``: ``(blocks, rows)``, the token
-    and the weight of each sorted row; ``sizes``: ``(blocks, experts held)``;
-    ``total``: the rows in use. Forward gathers a block's rows, runs the
-    grouped products and adds the results to their tokens; backward does
-    the same the other way round, recomputing the block."""
+    token: ``(N, d)``. ``tokens``, ``gates``: the token and the weight of
+    each sorted row, with a tile of padding behind; ``tiles``: per tile of
+    ``tile`` rows its expert, its first sorted row and how many of its rows
+    are that expert's; ``in_use``: the tiles that hold a row. Forward walks
+    the tiles in use: a tile gathers its rows, multiplies them with its one
+    expert's matrices and adds the results to their tokens. Backward walks
+    them again, recomputing the tile, and adds the tile's weight gradients
+    to that expert's float32 slices in place."""
     wa = _cast(w, x.dtype)
 
-    def visit(c, n_valid, y):
+    def visit(t, y):
+        expert, _, _, tok, g = _tile_of(tokens, gates, tiles, t, tile)
         with jax.named_scope(STAGE_MOE_DISPATCH):
-            rows = jnp.take(x, tokens[c], axis=0)
+            xt = jnp.take(x, tok, axis=0)
         with jax.named_scope(STAGE_MOE_EXPERTS):
-            out = _expert_rows(wa, rows, gates[c], sizes[c], n_valid)
+            we = _expert(wa, expert)
+            out = (_gated(xt @ we["w1"], xt @ we["w3"]) @ we["w2"]
+                   * g[:, None].astype(xt.dtype))
         with jax.named_scope(STAGE_MOE_COMBINE):
-            return y.at[tokens[c]].add(out.astype(jnp.float32))
+            return y.at[tok].add(out.astype(jnp.float32))
 
-    y = _blocks(tokens, total, visit, jnp.zeros(x.shape, jnp.float32))
+    y = lax.fori_loop(0, in_use, visit, jnp.zeros(x.shape, jnp.float32))
     return y.astype(x.dtype)
 
 
-def _held_experts_fwd(w, x, tokens, gates, sizes, total):
-    return (held_experts(w, x, tokens, gates, sizes, total),
-            (w, x, tokens, gates, sizes, total))
+def _held_experts_fwd(tile, w, x, tokens, gates, tiles, in_use):
+    return (held_experts(tile, w, x, tokens, gates, tiles, in_use),
+            (w, x, tokens, gates, tiles, in_use))
 
 
-def _held_experts_bwd(res, dy):
-    w, x, tokens, gates, sizes, total = res
+def _held_experts_bwd(tile, res, dy):
+    w, x, tokens, gates, tiles, in_use = res
     wa = _cast(w, x.dtype)
 
-    def visit(c, n_valid, carry):
+    def visit(t, carry):
         dw, dx, dgates = carry
+        expert, first, valid, tok, g = _tile_of(tokens, gates, tiles, t, tile)
         with jax.named_scope(STAGE_MOE_DISPATCH):
-            rows = jnp.take(x, tokens[c], axis=0)
+            xt = jnp.take(x, tok, axis=0)
         with jax.named_scope(STAGE_MOE_COMBINE):
-            dout = jnp.take(dy, tokens[c], axis=0)
+            dout = jnp.take(dy, tok, axis=0)
         with jax.named_scope(STAGE_MOE_EXPERTS):
-            _, pull = jax.vjp(
-                lambda wa, rows, g: _expert_rows(wa, rows, g, sizes[c],
-                                                 n_valid),
-                wa, rows, gates[c])
-            dwa, drows, dg = pull(dout)
-            dw = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(jnp.float32), dw, dwa)
+            we = _expert(wa, expert)
+            h, pull = jax.vjp(_gated, xt @ we["w1"], xt @ we["w3"])
+            dg = jnp.sum(dout.astype(jnp.float32)
+                         * (h @ we["w2"]).astype(jnp.float32), axis=-1)
+            dout = dout * g[:, None].astype(dout.dtype)
+            da, db = pull(dout @ we["w2"].T)
+            dxt = da @ we["w1"].T + db @ we["w3"].T
+            dwe = {"w1": _dot_rows(xt, da), "w3": _dot_rows(xt, db),
+                   "w2": _dot_rows(h, dout)}
+            dw = {k: dw[k].at[expert].add(dwe[k]) for k in dw}
         with jax.named_scope(STAGE_MOE_DISPATCH):
-            dx = dx.at[tokens[c]].add(drows.astype(jnp.float32))
-        return dw, dx, dgates.at[c].set(dg)
+            dx = dx.at[tok].add(dxt.astype(jnp.float32))
+            # the rows past the tile's own are a later tile's to write
+            dg = jnp.where(valid, dg.astype(dgates.dtype),
+                           lax.dynamic_slice(dgates, (first,), (tile,)))
+        return dw, dx, lax.dynamic_update_slice(dgates, dg, (first,))
 
-    dw, dx, dgates = _blocks(
-        tokens, total, visit,
+    dw, dx, dgates = lax.fori_loop(
+        0, in_use, visit,
         (jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32), w),
          jnp.zeros(x.shape, jnp.float32), jnp.zeros(gates.shape, gates.dtype)))
     return (jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), dw, w),
@@ -440,13 +458,16 @@ def _held_experts_bwd(res, dy):
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _route_and_sort(p, state, u, cfg: Config):
-    """Route the tokens ``u`` ``(N, d)`` and sort their assignments by held
-    expert: what :func:`held_experts` takes, and the layer's counters."""
+def _route_and_sort(p, state, u, cfg: Config, tile: int):
+    """Route the tokens ``u`` ``(N, d)``, sort their assignments by held
+    expert and lay tiles of ``tile`` rows over the sorted rows, each held
+    expert's first tile at its first row: what :func:`held_experts` takes,
+    and the layer's counters. The tiles are bounded by the worst case,
+    every assignment held here and every expert's last tile all but
+    empty."""
     k, held = cfg.num_experts_per_tok, cfg.experts_held
     n_slots = u.shape[0] * k
-    block = _row_block(cfg, u.shape[0])
-    n_rows = -(-n_slots // block) * block         # the bound, in whole blocks
+    n_tiles = -(-n_slots // tile) + held
     with jax.named_scope(STAGE_MOE_ROUTER):
         experts, gates = route(p, state["expert_bias"], u, cfg)
         drawn = _count(experts.reshape(-1), cfg.num_experts)
@@ -456,34 +477,48 @@ def _route_and_sort(p, state, u, cfg: Config):
         group = jnp.where(here, local, held)          # the rest sort last
         slot_of_row = jnp.argsort(group, stable=True)
         row_of_slot = jnp.argsort(slot_of_row)
-        ends = jnp.cumsum(_count(group, held + 1))[:held]
-        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
-        # rows of each held expert inside each block of the grouped product
-        lo = jnp.arange(0, n_rows, block)[:, None]
-        sizes = (jnp.clip(ends, lo, lo + block)
-                 - jnp.clip(starts, lo, lo + block))
-        computed = jnp.sum(sizes).astype(jnp.float32)
-        # A visited block's rows past the load go to the last expert as
-        # zeros, so that a block costs the same however full it is and the
-        # step's time does not follow the router's drift.
-        sizes = sizes.at[:, -1].add(block - jnp.sum(sizes, axis=1))
-        pad = (0, n_rows - n_slots)
-        tokens = jnp.pad(slot_of_row // k, pad).reshape(-1, block)
+        counts = _count(group, held + 1)[:held]
+        ends = jnp.cumsum(counts)
+        tiles_of = -(-counts // tile)
+        tile_ends = jnp.cumsum(tiles_of)
+        # per tile: its expert, and by a compare and a sum (as in route)
+        # where that expert's rows and tiles start and its rows end
+        t = jnp.arange(n_tiles)
+        expert = jnp.minimum(
+            jnp.sum(t[:, None] >= tile_ends[None, :], axis=1), held - 1)
+        mine = expert[:, None] == jnp.arange(held)[None, :]
+
+        def of_expert(v):
+            return jnp.sum(jnp.where(mine, v[None, :], 0), axis=1)
+
+        first = (of_expert(ends - counts)
+                 + (t - of_expert(tile_ends - tiles_of)) * tile)
+        n_valid = jnp.clip(of_expert(ends) - first, 0, tile)
+        in_use = tile_ends[-1]
+        # a tile of padding, so that the last tile's window lies inside
+        pad = (0, tile)
+        tokens = jnp.pad(slot_of_row // k, pad)
         row_gates = jnp.pad(_sorted(gates.reshape(-1), slot_of_row,
-                                    row_of_slot), pad).reshape(-1, block)
-    assigned = jnp.sum(here).astype(jnp.float32)
+                                    row_of_slot), pad)
+    held_rows = jnp.sum(n_valid).astype(jnp.float32)
     counters = {"expert_bias": state["expert_bias"],
-                "drawn": drawn.astype(jnp.float32), "held": computed,
-                "dropped": assigned - computed}
-    return (tokens, row_gates, sizes, ends[-1]), counters
+                "drawn": drawn.astype(jnp.float32), "held": held_rows,
+                "computed": (in_use * tile).astype(jnp.float32),
+                "dropped": jnp.sum(here).astype(jnp.float32) - held_rows}
+    # a state made without a counter (the benchmark's own makes the four it
+    # knows) goes on without it
+    return ((tokens, row_gates, (expert, first, n_valid), in_use),
+            {name: counters[name] for name in state})
 
 
 def moe_ffn(p, state, u, cfg: Config):
     """The held experts' part of the expert layer's result for normalised
     ``u`` ``(n, T, d)``, and the layer's new state (the counters)."""
     x = u.reshape(-1, u.shape[-1])
-    sorted_rows, counters = _route_and_sort(p, state, x, cfg)
-    y = held_experts({k: p[k] for k in ("w1", "w3", "w2")}, x, *sorted_rows)
+    tile = _tile_rows(cfg, x.shape[0])
+    sorted_rows, counters = _route_and_sort(p, state, x, cfg, tile)
+    y = held_experts(tile, {k: p[k] for k in ("w1", "w3", "w2")}, x,
+                     *sorted_rows)
     return y.reshape(u.shape), counters
 
 
@@ -529,7 +564,7 @@ def hidden_states(params, model_state, ids, cfg: Config,
         x = _over_sequences(_operator_part(kind, cfg), p, x, cfg.seq_block)
         if cfg.is_moe(i):
             # recomputed from x; the experts' own forward is not needed
-            # again (their backward recomputes block by block) and falls away
+            # again (their backward recomputes tile by tile) and falls away
             x, s = jax.checkpoint(_moe_part(cfg))(p, s, x)
         else:
             x = _over_sequences(_dense_part(cfg), p, x, cfg.seq_block)

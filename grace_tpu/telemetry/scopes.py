@@ -86,17 +86,16 @@ STAGE_PIPELINE = "grace/pipeline"
 # forward, recomputed and backward operations to it, so what is left
 # under "grace/forward_backward" is what no part names (embedding,
 # residual adds). Lower case and underscores only: the benchmark's
-# reducer reads ``grace/[a-z_]+``. One exception, measured on the chip
-# (PERF.md, PR 28): XLA runs ``lax.ragged_dot`` as a kernel of its own
-# naming (``ragged-dot-none``) that keeps no scope, so in a device trace
-# the grouped products stand under no stage, and "grace/moe_experts" holds
-# only what is traced around them (row masks, activation, gate weights).
+# reducer reads ``grace/[a-z_]+``. The expert layer's products are plain
+# ones and stand under "grace/moe_experts" (PERF.md, PR 37; until then they
+# were ``lax.ragged_dot``s, which XLA runs as a kernel of its own naming,
+# ``ragged-dot-none``, that keeps no scope).
 STAGE_ATTENTION = "grace/attention"
 STAGE_SHORT_CONV = "grace/short_conv"
 STAGE_DENSE_FFN = "grace/dense_ffn"
 STAGE_MOE_ROUTER = "grace/moe_router"
 STAGE_MOE_DISPATCH = "grace/moe_dispatch"      # sort, gather
-STAGE_MOE_EXPERTS = "grace/moe_experts"        # masks, activation, gates
+STAGE_MOE_EXPERTS = "grace/moe_experts"        # a tile's products, gates
 STAGE_MOE_COMBINE = "grace/moe_combine"
 STAGE_LM_HEAD = "grace/lm_head"                # final norm, head, loss
 # Latent attention's products around the scores (models/deepseek_v3.py):

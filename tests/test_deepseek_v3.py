@@ -136,8 +136,12 @@ def test_the_program_reads_the_tree_the_benchmark_makes():
                                       jax.random.key(0))
     assert (jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made))
-    assert (jax.tree_util.tree_structure(own_state)
-            == jax.tree_util.tree_structure(made_state))
+    # the program's own state counts the rows multiplied too (PR 37)
+    for own_layer, made_layer in zip(own_state["layers"],
+                                     made_state["layers"]):
+        assert set(own_layer) - set(made_layer) == (
+            {"computed"} if made_layer else set())
+        assert {k: own_layer[k] for k in made_layer} == made_layer
     assert len(jax.tree_util.tree_leaves(own)) == 3 + 10 + 2 * 14
     assert cfg.route_eps == 1e-20 and lfm2.Config().route_eps == 1e-6
 
@@ -167,10 +171,11 @@ def test_the_published_configuration_counts_its_parameters():
             cfg.num_hidden_layers) == (128, 6, 8, 0, 1, 5)
     assert (cfg.norm_eps, cfg.rope_theta, cfg.routed_scaling_factor,
             cfg.route_eps) == (1e-6, 1e6, 2.448, 1e-20)
-    # expert rows provisioned for twice the balanced load: the program's
-    # default, no key of the file
+    # the walk's tile is chosen from the shapes, no key of the file: a
+    # quarter of a balanced expert's 1,536 rows, in whole multiples of 128
+    # (PR 37; until then one block of twice the balanced load, 24,576 rows)
     assert cfg.moe_row_block == 0 and "expert_row_block" not in sizes
-    assert lfm2._row_block(cfg, 32768) == 2 * 32768 * 6 * 8 // 128 == 24576
+    assert lfm2._tile_rows(cfg, 32768) == 32768 * 6 // 128 // 4 == 384
     shapes, counts = _counts(cfg)
     assert len(counts) == 69 and sum(counts) == sizes["parameters_held"]
     assert sum(counts) == 424_960_512
@@ -422,6 +427,56 @@ def test_no_assignment_is_dropped_under_a_skewed_router(target, row_block):
     assert float(counters["held"]) >= 48.0
     assert float(counters["dropped"]) == 0.0
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+# the bias on the scores of the two held experts, 2 and 3 of 8
+ROUTERS = {"balanced": (0.0, 0.0), "skewed": (10.0, 0.0),
+           "idle": (-10.0, 0.0), "worst_case": (10.0, 10.0)}
+
+
+@pytest.mark.parametrize("tile", [0, 8, 40])
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_six_a_token_in_expert_aligned_tiles(router, tile):
+    """This decoder's routing (6 a token, gates scaled) through the tiles
+    of ``lfm2.moe_ffn`` (PR 37): the routed part and its gradients against
+    the plain reference, nothing dropped, and less than a tile an expert
+    multiplied beyond the rows held, whatever the router does."""
+    sizes, whole, u, _ = _expert_layer(6)
+    sizes = dict(sizes, num_experts_per_tok=6)
+    b = jnp.zeros((8,)).at[2:4].set(jnp.asarray(ROUTERS[router]))
+    cfg = dsv3.tiny(first_expert=2, experts_held=2, num_experts_per_tok=6,
+                    moe_row_block=tile)
+    part = dict(sizes, n_routed_experts=2, share=1)
+    share = _share_of(whole, 2, 2)
+    state = dict(lfm2.expert_layer_state(8), expert_bias=b)
+
+    def program(p, x):
+        y, counters = lfm2.moe_ffn(p, state, x, cfg)
+        return jnp.sum(jnp.sin(y)), (y, counters)
+
+    def reference(p, x):
+        y = jax.vmap(lambda row: plain._routed(p, b, row, part, 2))(x)
+        return jnp.sum(jnp.sin(y)), y
+
+    routed = {k: share[k] for k in ("router", "w1", "w3", "w2")}
+    with jax.default_matmul_precision("highest"):
+        (_, (got, counters)), grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1), has_aux=True))(routed, u)
+        (_, want), want_grads = jax.value_and_grad(
+            reference, argnums=(0, 1), has_aux=True)(routed, u)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-6)
+    rows = tile or 128
+    held = float(counters["drawn"][2] + counters["drawn"][3])
+    assert float(counters["dropped"]) == 0.0
+    assert float(counters["held"]) == held
+    assert 0 <= float(counters["computed"]) - held < 2 * rows
+    assert float(counters["computed"]) % rows == 0
+    assert {"balanced": 48 < held < 96, "skewed": held > 48,
+            "idle": float(counters["drawn"][2]) == 0 and held > 0,
+            "worst_case": held == 96}[router]
 
 
 def test_both_decoders_configs_keep_the_fields_the_shared_parts_read():

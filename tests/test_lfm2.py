@@ -140,8 +140,14 @@ def test_the_program_reads_the_tree_the_benchmark_makes():
                                       jax.random.key(0))
     assert (jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made))
-    assert (jax.tree_util.tree_structure(own_state)
-            == jax.tree_util.tree_structure(made_state))
+    # the program's own state counts the rows multiplied too (PR 37); the
+    # benchmark's makes the four counters it knows, and the layer goes on
+    # with those
+    for own_layer, made_layer in zip(own_state["layers"],
+                                     made_state["layers"]):
+        assert set(own_layer) - set(made_layer) == (
+            {"computed"} if made_layer else set())
+        assert {k: own_layer[k] for k in made_layer} == made_layer
     assert len(jax.tree_util.tree_leaves(own)) == 50
 
 
@@ -311,6 +317,105 @@ def test_expert_gradients_agree_and_the_bias_takes_none():
     assert float(jnp.max(jnp.abs(got[0]["router"]))) > 0
 
 
+# Routers the walk has to take (PR 37): the bias added to the scores of the
+# four held experts 2..5 of 8, two a token over 48 tokens, so that a
+# balanced router sends 48 rows here and the walk's old block was 96.
+ROUTERS = {
+    "balanced": (0.0, 0.0, 0.0, 0.0),
+    "skewed_to_one": (0.0, 10.0, 0.0, 0.0),
+    "one_draws_nothing": (0.0, 0.0, -10.0, 0.0),
+    "over_the_old_block": (10.0, 0.3, 0.3, 0.3),
+    "worst_case": (10.0, 10.0, 0.0, 0.0),
+}
+TILES = [0, 8, 16, 40, 128]
+
+
+def _routed_layer(router):
+    sizes, whole, u, bias = _expert_layer(8)
+    bias = (bias * 0.1).at[2:6].add(jnp.asarray(ROUTERS[router]))
+    return dict(sizes, num_experts=4, share=1), _share_of(whole, 2, 4), u, bias
+
+
+@pytest.mark.parametrize("tile", TILES)
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_tiles_compute_every_held_row_and_little_more(router, tile):
+    """Result, every leaf's gradient and the counters of the layer walked
+    in expert-aligned tiles of ``tile`` rows (0: chosen from the shapes),
+    against the plain reference, whatever the router does: nothing is
+    dropped, ``held`` is what the routing says, and what is multiplied
+    beyond it is less than a tile an expert."""
+    part, share, u, bias = _routed_layer(router)
+    cfg = lfm2.tiny(first_expert=2, experts_held=4, moe_row_block=tile)
+    state = dict(lfm2.expert_layer_state(8), expert_bias=bias)
+
+    def program(p, b, x):
+        y, counters = lfm2.moe_ffn(p, dict(state, expert_bias=b), x, cfg)
+        return jnp.sum(jnp.sin(y)), (y, counters)
+
+    def reference(p, b, x):
+        y = jax.vmap(lambda row: plain._experts(p, b, row, part, first=2))(x)
+        return jnp.sum(jnp.sin(y)), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, (got, counters)), grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 2), has_aux=True))(share, bias, u)
+        (_, want), want_grads = jax.value_and_grad(
+            reference, argnums=(0, 2), has_aux=True)(share, bias, u)
+        experts, _ = lfm2.route(share, bias, u.reshape(-1, 32), cfg)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-6)
+    drawn = np.bincount(np.asarray(experts).ravel(), minlength=8)
+    held = int(drawn[2:6].sum())
+    rows = lfm2._tile_rows(cfg, 48)
+    assert rows == (tile or 128)
+    assert float(counters["dropped"]) == 0.0
+    assert float(counters["held"]) == held
+    np.testing.assert_array_equal(counters["drawn"], drawn)
+    # every held expert's rows in whole tiles of its own
+    assert float(counters["computed"]) == sum(
+        -(-int(n) // rows) * rows for n in drawn[2:6])
+    assert 0 <= float(counters["computed"]) - held < 4 * rows
+    # the routers are what their names say
+    assert {"balanced": 24 < held < 72,
+            "skewed_to_one": drawn[3] == 48,
+            "one_draws_nothing": drawn[4] == 0 and held > 0,
+            "over_the_old_block": drawn[2] == 48 and 48 < held < 96,
+            "worst_case": held == 96}[router]
+
+
+def test_a_state_without_the_new_counter_goes_on_without_it():
+    """``computed`` is a counter of the program's own state; a state made
+    without it (the benchmark's, from before PR 37) comes back with the
+    keys it had, the others unchanged."""
+    _, share, u, bias = _routed_layer("balanced")
+    cfg = lfm2.tiny(first_expert=2, experts_held=4, moe_row_block=16)
+    own = dict(lfm2.expert_layer_state(8), expert_bias=bias)
+    assert set(own) == {"expert_bias", "drawn", "held", "computed", "dropped"}
+    assert set(lfm2.init_state(lfm2.tiny())["layers"][1]) == set(own)
+    y, counters = lfm2.moe_ffn(share, own, u, cfg)
+    y_old, old = lfm2.moe_ffn(share, _state(bias), u, cfg)
+    assert set(counters) == set(own) and set(old) == set(_state(bias))
+    np.testing.assert_array_equal(y, y_old)
+    for name in old:
+        np.testing.assert_array_equal(old[name], counters[name])
+
+
+def test_the_tile_is_chosen_from_the_shapes():
+    """A quarter of the rows a balanced router sends one expert, in whole
+    multiples of 128, and ``moe_row_block`` as given where it is set: 512
+    rows for the published LFM2 share on 32,768 tokens (2,048 an
+    expert)."""
+    cfg = lfm2.Config(first_expert=0, experts_held=8)
+    assert cfg.moe_row_block == 0
+    assert lfm2._tile_rows(cfg, 32768) == 32768 * 4 // 64 // 4 == 512
+    assert lfm2._tile_rows(cfg, 4096) == 128         # never under 128
+    assert lfm2._tile_rows(cfg, 3 * 32768) == 1536
+    assert lfm2._tile_rows(dataclasses.replace(cfg, moe_row_block=24),
+                           32768) == 24
+
+
 def test_a_share_outside_the_routers_experts_is_refused():
     with pytest.raises(ValueError, match="not among"):
         lfm2.tiny(first_expert=7, experts_held=2)
@@ -414,7 +519,7 @@ def test_rms_norm():
 @pytest.mark.parametrize("walk", [
     {"seq_block": 1}, {"seq_block": 4}, {"attn_q_block": 16},
     {"attn_q_block": 4}, {"moe_row_block": 8}, {"moe_row_block": 128},
-    {"moe_row_block": 0}])
+    {"moe_row_block": 0}, {"moe_row_block": 24}, {"moe_row_block": 56}])
 def test_walking_the_work_in_other_blocks_changes_nothing(walk, float32_pair):
     (want_loss, want, _), _ = float32_pair
     with jax.default_matmul_precision("highest"):

@@ -19,6 +19,7 @@ only the worker that runs this file may load the library. A compile that
 passes here is not a chip run and is never reported as one.
 """
 
+import dataclasses
 import functools
 import json
 import os
@@ -52,6 +53,7 @@ if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
 
 from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
+from benchmarks.trace_reduce import stage_of  # noqa: E402
 
 N = 25_557_032            # ResNet-50's flat gradient
 K = N // 100              # top-k 1 %
@@ -549,6 +551,87 @@ def test_the_name_alone_changes_nothing(one_chip, monkeypatch, keep_nothing,
     assert _kernel_calls(text)[0] == RECOMPUTED
     assert f"bf16[2,1,32,4096,{dv}]" not in text
     assert "f32[2,1,32,4096]" not in text
+
+
+# ---------------------------------------------------------------------------
+# the expert part of both decoders: expert-aligned tiles (PR 37)
+# ---------------------------------------------------------------------------
+
+def _instructions(text, opcode):
+    """``(result shape, op_name)`` of every ``opcode`` instruction of a
+    compiled text, fused ones included."""
+    return re.findall(
+        r"= (\w+\[[\d,]*\])[^\n]*? %s\([^\n]*?op_name=\"([^\"]*)\""
+        % re.escape(opcode), text)
+
+
+@pytest.mark.parametrize("decoder", ["kanana", "lfm2"])
+def test_the_expert_part_walks_tiles_of_one_expert(one_chip, decoder):
+    """The expert layer of each benchmark configuration as the step runs it
+    (recomputed from its input), forward and gradient, on one sequence of
+    4,096 tokens at the tile the cell's 32,768 tokens choose. The walk's
+    two loops run to a count the program computes (the tiles in use), the
+    products are plain ones under ``grace/moe_experts`` (``lax.ragged_dot``,
+    which XLA runs as its own kernel without the scope's name, is gone),
+    and a tile's weight gradient is added into its one expert's float32
+    slice: nowhere in the part is a whole ``(experts held, d, f)`` stack
+    added."""
+    make_cfg, mod = {"lfm2": (_lfm2_config, lfm2),
+                     "kanana": (_kanana_config, deepseek_v3)}[decoder]
+    cfg = make_cfg()
+    tile = lfm2._tile_rows(cfg, 8 * 4096)
+    assert tile == {"lfm2": 512, "kanana": 384}[decoder]
+    cfg = dataclasses.replace(cfg, moe_row_block=tile)
+    params, state = jax.eval_shape(lambda k: mod.init(k, cfg),
+                                   jax.random.key(0))
+    part = jax.checkpoint(mod._moe_part(cfg))
+
+    def loss(p, x, s):
+        y, s = part(p, s, x)
+        return jnp.sum(y.astype(jnp.float32)), s
+
+    def avals(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    text = compile_text(
+        jax.value_and_grad(loss, argnums=(0, 1), has_aux=True),
+        avals(params["layers"][-1]),
+        jax.ShapeDtypeStruct((1, 4096, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip),
+        avals(state["layers"][-1]))
+    held, d, f = cfg.experts_held, cfg.hidden_size, cfg.moe_intermediate_size
+    assert (held, d, f) == {"lfm2": (8, 2048, 1536),
+                            "kanana": (8, 2048, 768)}[decoder]
+    assert "ragged" not in text and "tpu_custom_call" not in text
+    # forward and backward loop, neither with a trip count known in advance
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 2 and not any("known_trip_count" in line
+                                       for line in loops)
+    # every product of the part under its stage: the experts' in the loops,
+    # the router's and (kanana) the shared expert's outside them
+    products = _instructions(text, "convolution")
+    stages = [stage_of(name) for _, name in products]
+    assert set(stages) == {"grace/moe_experts", "grace/moe_router"} | (
+        {"grace/shared_expert"} if decoder == "kanana" else set())
+    in_tiles = [shape for (shape, name), stage in zip(products, stages)
+                if stage == "grace/moe_experts"]
+    assert len(in_tiles) >= 11 and all("/while/body/" in name for _, name in
+                                       products if "moe_experts" in name)
+    # a tile's weight gradients: float32 products of one expert's shape ...
+    slices = [f"f32[{d},{f}]", f"f32[{f},{d}]"]
+    assert sorted(x for x in in_tiles if x.startswith("f32")) == sorted(
+        [slices[0]] * 2 + [slices[1]])
+    # ... each added to that expert's slice of the stack, in place
+    adds = [shape for shape, _ in _instructions(text, "add")]
+    assert adds.count(f"f32[1,{d},{f}]") == 2 and adds.count(
+        f"f32[1,{f},{d}]") == 1
+    assert not any(x in (f"f32[{held},{d},{f}]", f"f32[{held},{f},{d}]")
+                   for x in adds)
+    updates = [shape for shape, _ in
+               _instructions(text, "dynamic-update-slice")]
+    assert updates.count(f"f32[{held},{d},{f}]") == 2 and updates.count(
+        f"f32[{held},{f},{d}]") == 1
 
 
 def _lfm2_config():
