@@ -9,6 +9,12 @@ name-keyed dicts. See SURVEY.md at the repo root for the full mapping to the
 reference.
 """
 
+# First, before anything else of the package runs: the host ledger's clock
+# for "grace_tpu began to import" is the moment this module loads.
+from grace_tpu.telemetry import host as _host
+
+_importing = _host.LEDGER.begin("import", at=_host.LEDGER.made)
+
 from grace_tpu.core import Communicator, Compressor, Memory
 from grace_tpu.comm import (Allgather, Allreduce, Broadcast,
                             HierarchicalAllreduce, Identity, RingAllreduce,
@@ -43,3 +49,6 @@ __all__ = [
     "data_parallel_mesh", "make_mesh",
     "__version__",
 ]
+
+_host.LEDGER.end(_importing)
+del _importing

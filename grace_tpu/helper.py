@@ -28,6 +28,7 @@ from grace_tpu import memories as M
 from grace_tpu.core import (DEFAULT_AXIS, Communicator, Compressor,
                             LinkBytes, Memory, Topology,
                             negotiation_bytes_for)
+from grace_tpu.telemetry import host
 from grace_tpu.transform import MeshSpec, grace_transform, leaf_path_str, \
     normalize_routes, route_for
 
@@ -258,6 +259,7 @@ def _build_communicator(params: Dict[str, Any], axis: str) -> Communicator:
     raise ValueError(f"unknown communicator {name!r}")
 
 
+@host.spanned("grace_from_params")
 def grace_from_params(params: Dict[str, Any]) -> Grace:
     """Configure the triad from the reference's params-dict schema.
 

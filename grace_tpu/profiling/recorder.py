@@ -9,10 +9,31 @@ watches the program's lowerings and catches whatever escapes static analysis
 recompiles. It promotes :class:`grace_tpu.utils.profiling.StepTimer` from a
 bench-local helper into the long-run observability stack:
 
+* **set-up** — one ``perf_setup`` record at the first flush, the host
+  ledger's summary (:mod:`grace_tpu.telemetry.host`):
+  ``to_first_step_s`` (process start through that step, its compile
+  included) beside its named parts,
+  ``pre_program_s`` (process start until ``grace_tpu`` began to import;
+  ``backends_ready_at_load`` says whether the chip was reached in it),
+  ``jit_wall_s`` (JAX's trace, lowering and compile events, of which
+  ``cache_read_s`` reading the persistent cache), ``program_s`` (the
+  program's own Python on the set-up path), the ``spans`` one by one with
+  their self time and the operating system's counters, and the ``built``
+  mark (the process's CPU seconds, run-queue wait, faults and the
+  machine's pressure when the last program was built);
 * **step-time percentiles** (mean/p50/p90/p99/max over the steady window),
   emitted every flush as ``perf_step_times`` records — stamped with
   ``sync_missing`` when the timer only ever measured async dispatch, so a
-  meaningless number carries its own caveat;
+  meaningless number carries its own caveat, and with ``stalls``, the
+  count of stalled steps so far;
+* **stalled steps** — one ``perf_stall`` record a step whose wall time was
+  over 1.5 times the running steady median and at least 50 ms over it
+  (:class:`~grace_tpu.utils.profiling.StepTimer` decides), with what the
+  loop's thread did meanwhile, from the operating system's counters:
+  ``wall_s``, ``cpu_s``, ``runq_s`` (runnable, waiting for a CPU),
+  ``blocked_s`` (asleep in the runtime), ``major_faults`` and the largest
+  part as ``cause`` (``runq`` | ``cpu`` | ``blocked``; ``compile`` when the
+  compile ledger saw a new lowering during the step);
 * **compile/retrace events** — ``perf_compile`` for the step function's
   first lowering, ``perf_retrace`` for each later one, read from the
   compile ledger (:mod:`grace_tpu.telemetry.compiles`, JAX's own events)
@@ -37,12 +58,13 @@ artifact carries the whole run — ``tools/telemetry_report.py`` renders the
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from grace_tpu.telemetry import compiles
+from grace_tpu.telemetry import compiles, host
 from grace_tpu.utils.profiling import StepTimer
 
 __all__ = ["ProfileRecorder", "device_memory_watermarks",
@@ -212,6 +234,8 @@ class ProfileRecorder:
             getattr(step_fn, "fun_name", None)
             or getattr(step_fn, "__name__", None))
         self._seen: Optional[dict] = None     # the ledger's sums last reported
+        self._stalls_emitted = 0
+        self._first_step_done: Optional[float] = None    # time.time()
 
     # -- timing (delegates to the promoted StepTimer) -----------------------
     def step(self):
@@ -226,6 +250,8 @@ class ProfileRecorder:
         ledger every iteration — a retrace must be attributed to the step
         that caused it, not to a flush boundary — and emits the windowed
         records on every ``every``-th call."""
+        if self._first_step_done is None:
+            self._first_step_done = time.time()
         records = self._check_retrace(step)
         if (step + 1) % self.every == 0:
             records.extend(self.flush(step))
@@ -252,16 +278,39 @@ class ProfileRecorder:
         self._emit([rec])
         return [rec]
 
+    def _setup_record(self, step: int) -> dict:
+        """The host ledger's summary: what the process did from its start
+        through the loop's first step (the first ``update``, or this
+        flush), that step's compile included."""
+        rec = {"event": "perf_setup", "step": step, **host.LEDGER.summary()}
+        began = rec["process_began"]
+        done = self._first_step_done or time.time()
+        rec["to_first_step_s"] = None if began is None else done - began
+        return rec
+
     def flush(self, step: int) -> List[dict]:
-        """Emit the windowed records: step-time percentiles and (when the
-        backend reports allocator stats) the memory watermark."""
+        """Emit the windowed records: a ``perf_stall`` for each step that
+        stalled since the last flush, step-time percentiles and (when the
+        backend reports allocator stats) the memory watermark; before them,
+        at the first flush, one ``perf_setup``. ``step`` is the loop's
+        index of the last step timed: a stall's own index is counted back
+        from it."""
         records: List[dict] = []
+        if not self.flushes:
+            records.append(self._setup_record(step))
         if len(self.timer):
+            # the loop's index of the timer's step 0
+            first = step - (len(self.timer) - 1)
+            for row in self.timer.stalls[self._stalls_emitted:]:
+                records.append({"event": "perf_stall", **row,
+                                "step": first + row["step"]})
+            self._stalls_emitted = len(self.timer.stalls)
             arr = self.timer.steady * 1e3
             rec = {"event": "perf_step_times", "step": step,
                    "n_steps": int(arr.size),
                    "mean_ms": float(arr.mean()),
-                   "max_ms": float(arr.max())}
+                   "max_ms": float(arr.max()),
+                   "stalls": len(self.timer.stalls)}
             for q in self.percentiles:
                 rec[f"p{q:g}_ms"] = float(np.percentile(arr, q))
             if self.timer.measured_async_dispatch:

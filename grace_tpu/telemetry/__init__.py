@@ -33,7 +33,9 @@ metadata so ``utils.profiling.trace`` captures attributable Perfetto spans,
 and the compile ledger (:mod:`~grace_tpu.telemetry.compiles`): every trace,
 lowering, compile and cache read of the process, by function, from JAX's
 own events. It is imported here so that it is listening before any entry
-point builds a step.
+point builds a step. The host ledger (:mod:`~grace_tpu.telemetry.host`) is
+imported before everything else of the package: the moment it loads is
+"``grace_tpu`` began to import" in the set-up it accounts for.
 
 IMPORT CONSTRAINT: modules in this package must not import
 ``grace_tpu.core`` / ``transform`` / ``resilience`` at module level —
@@ -41,6 +43,7 @@ IMPORT CONSTRAINT: modules in this package must not import
 reader's ``GuardState`` lookup is deliberately lazy.
 """
 
+from grace_tpu.telemetry import host  # first: see above
 from grace_tpu.telemetry import compiles
 from grace_tpu.telemetry.aggregate import (WATCH_FIELDS, WatchConfig,
                                            WatchState, watch_init,
@@ -65,5 +68,5 @@ __all__ = [
     "TelemetryReader",
     "Sink", "JSONLSink", "TensorBoardSink", "MultiSink",
     "trace_stage",
-    "compiles",
+    "compiles", "host",
 ]

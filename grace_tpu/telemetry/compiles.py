@@ -17,7 +17,14 @@ event. A set-up fires thousands (every ``jnp`` function a step calls is
 traced as a program of its own, nested inside the step's trace), so the
 ledger keeps no span one by one: running sums per function, which the
 recorder and the step's metrics read, and the union of all spans as
-disjoint intervals, which ``setup_jit_wall_s`` reads.
+disjoint intervals, which ``setup_jit_wall_s`` reads (and the host ledger,
+:mod:`~grace_tpu.telemetry.host`, to take JAX's time out of its own spans).
+
+JAX also reports a plain duration when a compile is answered by the
+persistent cache: ``/jax/compilation_cache/cache_retrieval_time_sec``, the
+read and deserialisation, part of that program's ``compile`` span. A third
+listener sums it (``setup_cache_read_s``, and the recorder's
+``perf_setup``).
 
 The ledger is per process, as JAX's listeners are: :data:`LEDGER` is the one
 the listeners feed, and the module-level functions read it. JAX's events
@@ -30,7 +37,8 @@ from __future__ import annotations
 
 from jax import monitoring
 
-__all__ = ["CompileLedger", "LEDGER", "summary", "wall_s", "counts", "reset"]
+__all__ = ["CompileLedger", "LEDGER", "summary", "wall_s", "intervals",
+           "counts", "durations", "lowerings", "reset"]
 
 _KINDS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
@@ -40,6 +48,9 @@ _KINDS = {
 _COUNTS = {
     "/jax/compilation_cache/cache_hits": "cache_hits",
     "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
 }
 _NO_SPANS = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
              "lowerings": 0, "cache_hits": 0}
@@ -62,6 +73,8 @@ class CompileLedger:
         self._by_name: dict[str, dict] = {}
         self._intervals: list[tuple[float, float]] = []   # disjoint, by start
         self._counts = dict.fromkeys(_COUNTS.values(), 0)
+        self._durations = dict.fromkeys(_DURATIONS.values(), 0.0)
+        self._lowerings = 0
         self._hits_attributed = 0
 
     # -- the listeners ------------------------------------------------------
@@ -78,6 +91,7 @@ class CompileLedger:
             # A trace span says nothing of a retrace: JAX's tracing cache
             # answers in one too, a few microseconds long.
             sums["lowerings"] += 1
+            self._lowerings += 1
         elif kind == "compile":
             # a hit is counted inside the compile span that it answers
             hits = self._counts["cache_hits"]
@@ -96,6 +110,11 @@ class CompileLedger:
         if key is not None:
             self._counts[key] += 1
 
+    def on_duration(self, event, duration_s, **_) -> None:
+        key = _DURATIONS.get(event)
+        if key is not None:
+            self._durations[key] += duration_s
+
     # -- reads --------------------------------------------------------------
     def summary(self, fun_name) -> dict:
         """``trace_s``, ``lower_s``, ``compile_s`` of the function's own
@@ -110,15 +129,34 @@ class CompileLedger:
         nested trace twice)."""
         return sum(e - s for s, e in self._intervals)
 
+    def intervals(self) -> list[tuple[float, float]]:
+        """The union of all spans as disjoint ``(start, end)`` intervals on
+        ``time.time()``'s clock, by start."""
+        return list(self._intervals)
+
     def counts(self) -> dict:
         return dict(self._counts)
+
+    def durations(self) -> dict:
+        """``cache_read_s``: seconds spent reading and deserialising
+        persistent-cache entries."""
+        return dict(self._durations)
+
+    def lowerings(self) -> int:
+        """Lowerings of every function so far: a step during which this
+        rises built a program."""
+        return self._lowerings
 
 
 LEDGER = CompileLedger()
 monitoring.register_event_time_span_listener(LEDGER.on_span)
 monitoring.register_event_listener(LEDGER.on_event)
+monitoring.register_event_duration_secs_listener(LEDGER.on_duration)
 
 summary = LEDGER.summary
 wall_s = LEDGER.wall_s
+intervals = LEDGER.intervals
 counts = LEDGER.counts
+durations = LEDGER.durations
+lowerings = LEDGER.lowerings
 reset = LEDGER.reset

@@ -40,6 +40,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from grace_tpu.core import DEFAULT_AXIS
 from grace_tpu.parallel import replicated, shard_map
+from grace_tpu.telemetry import host
 from grace_tpu.telemetry.scopes import (STAGE_APPLY, STAGE_CONSENSUS,
                                         STAGE_FWD_BWD, STAGE_OPTIMIZER,
                                         trace_stage)
@@ -102,19 +103,21 @@ def _lazy_sharded_step(device_step, mesh: Mesh, axis_name, donate: bool,
         key = jax.tree_util.tree_structure(state)
         fn = cache.get(key)
         if fn is None:
-            specs = _apply_param_specs(
-                partition_specs(state, mesh_spec), state, param_specs)
-            sharded = shard_map(
-                device_step, mesh=mesh,
-                in_specs=(specs, P(mesh_spec.dp_axis)),
-                out_specs=(specs, P()),
-                check_vma=False)
-            fn = jax.jit(sharded, donate_argnums=(0,) if donate else ())
+            with host.span("wrap_step"):
+                specs = _apply_param_specs(
+                    partition_specs(state, mesh_spec), state, param_specs)
+                sharded = shard_map(
+                    device_step, mesh=mesh,
+                    in_specs=(specs, P(mesh_spec.dp_axis)),
+                    out_specs=(specs, P()),
+                    check_vma=False)
+                fn = jax.jit(sharded, donate_argnums=(0,) if donate else ())
             cache[key] = fn
         return fn(state, batch)
 
-    # Callers (bench.py MFU accounting) can reach the underlying jitted fns
-    # for AOT introspection (lower().cost_analysis()) without re-wrapping.
+    # Callers (benchmarks/harness.Program, chip_smoke.py) reach the jitted
+    # functions here to compile ahead of time (fn.lower(...).compile())
+    # without wrapping the step again.
     step.jit_cache = cache
     # What grace_tpu.telemetry.compiles.summary is asked for, for this step
     # and no other of the process.
@@ -122,6 +125,7 @@ def _lazy_sharded_step(device_step, mesh: Mesh, axis_name, donate: bool,
     return step
 
 
+@host.spanned("make_train_step")
 def make_train_step(loss_fn: Callable[[Any, Any], jax.Array],
                     optimizer: optax.GradientTransformation,
                     mesh: Mesh,
@@ -204,6 +208,7 @@ def _consensus_step(tree, config, axis_name):
     return consensus_step(tree, config, axis_name)
 
 
+@host.spanned("make_stateful_train_step")
 def make_stateful_train_step(loss_fn: Callable[[Any, Any, Any],
                                                Tuple[jax.Array, Any]],
                              optimizer: optax.GradientTransformation,
@@ -257,6 +262,7 @@ def make_stateful_train_step(loss_fn: Callable[[Any, Any, Any],
                               param_specs=param_specs)
 
 
+@host.spanned("init_opt_state")
 def init_opt_state(params: Any, optimizer: optax.GradientTransformation,
                    mesh: Mesh, axis_name=DEFAULT_AXIS,
                    param_specs=None) -> Any:
@@ -307,6 +313,7 @@ def init_opt_state(params: Any, optimizer: optax.GradientTransformation,
 _init_opt_state = init_opt_state
 
 
+@host.spanned("init_train_state")
 def init_train_state(params: Any, optimizer: optax.GradientTransformation,
                      mesh: Mesh, axis_name=DEFAULT_AXIS,
                      param_specs=None) -> TrainState:
@@ -327,6 +334,7 @@ def init_train_state(params: Any, optimizer: optax.GradientTransformation,
                                   param_specs=param_specs))
 
 
+@host.spanned("init_stateful_train_state")
 def init_stateful_train_state(params: Any, model_state: Any,
                               optimizer: optax.GradientTransformation,
                               mesh: Mesh, axis_name: str = DEFAULT_AXIS

@@ -60,6 +60,7 @@ from jax import lax
 from grace_tpu.core import (Communicator, Compressor, DEFAULT_AXIS,
                             LinkBytes, Memory, State, Topology, axis_size,
                             negotiation_bytes_for)
+from grace_tpu.telemetry import host
 from grace_tpu.telemetry.aggregate import (normalize_watch,
                                            watch_gather_bytes, watch_init,
                                            watch_record)
@@ -665,6 +666,7 @@ def _normalize_telemetry(telemetry) -> Optional[TelemetryConfig]:
                     f"got {type(telemetry).__name__}")
 
 
+@host.spanned("transform")
 def grace_transform(compressor: Compressor, memory: Memory,
                     communicator: Communicator, seed: int = 0,
                     fusion: Optional[int | str] = None,

@@ -20,11 +20,14 @@ from __future__ import annotations
 
 import os
 
+from grace_tpu.telemetry import host
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 DEFAULT_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
 
 
+@host.spanned("place_compile_cache")
 def place_compile_cache(platform: str = "tpu") -> str | None:
     """Apply the rule above; returns the directory in effect (None = off).
 

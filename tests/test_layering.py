@@ -37,14 +37,18 @@ ROOT_MODULES = {
 # unit -> the units it may import. Lower layers first: a unit's row names
 # only units above it in this list (its own files are always allowed).
 ALLOWED = {
-    # named scopes, the compile ledger, the telemetry ring and its sinks
+    # named scopes, the compile and host ledgers, the telemetry ring and
+    # its sinks
     "telemetry": set(),
     # Compressor / Memory / Communicator, Topology
     "core": {"telemetry"},
     "ops": {"telemetry"},
     "parallel": {"core"},
     "memories": {"core"},
-    "utils": {"core"},
+    # the host ledger (telemetry/host.py) is the lowest thing there is:
+    # StepTimer takes its thread snapshot, place_compile_cache is one of
+    # its spans
+    "utils": {"core", "telemetry"},
     "checkpoint": set(),
     "compressors": {"core", "ops", "telemetry"},
     # the fused attention kernel is an op the two decoders call (lfm2,
@@ -56,8 +60,8 @@ ALLOWED = {
     # the optax transform: compensate, compress, exchange, decompress
     "transform": {"comm", "core", "telemetry", "utils"},
     # params dict -> the configured triad
-    "helper": {"comm", "compressors", "core", "memories", "transform",
-               "utils"},
+    "helper": {"comm", "compressors", "core", "memories", "telemetry",
+               "transform", "utils"},
     # the jitted shard_map step
     "train": {"core", "parallel", "telemetry", "transform"},
     # guard, consensus, adapt; and the host-side controllers that drive
@@ -179,6 +183,16 @@ def test_unit_imports_stay_inside_its_layer(unit):
     assert not paid, (
         f"grace_tpu/{unit} no longer imports {sorted(paid)}: take the "
         "row out of KNOWN_UPWARD")
+
+
+def test_the_host_ledger_imports_nothing_of_the_package_above_telemetry():
+    """``telemetry/host.py`` is imported by the first line of
+    ``grace_tpu/__init__.py``: whatever it imported would load before the
+    import's span began. Not even the debts ``KNOWN_UPWARD`` allows
+    ``telemetry`` are its to use."""
+    modules = imported_modules(os.path.join(PACKAGE, "telemetry", "host.py"))
+    ours = {m for m in modules if m.split(".")[0] == "grace_tpu"}
+    assert ours <= {"grace_tpu.telemetry", "grace_tpu.telemetry.compiles"}
 
 
 # ---------------------------------------------------------------------------

@@ -357,10 +357,73 @@ def _render_lint(lint: List[dict]) -> List[str]:
     return out
 
 
+_STALL_CAUSES = {
+    "runq": "runnable, but the machine gave the thread no CPU: the host's",
+    "cpu": "the thread computed (Python, a collection): the program's",
+    "compile": "a program was built during the step: a retrace",
+    "blocked": "asleep in the runtime: the device, a transfer, an "
+               "allocation, a lock",
+}
+
+
+def _s(value, digits: int = 2) -> str:
+    """Seconds, or ``n/a`` for what the platform does not count."""
+    return "n/a" if value is None else f"{value:.{digits}f}"
+
+
+def _render_setup(e: dict) -> List[str]:
+    """The host ledger's summary (``perf_setup``): process start through
+    the first step, split into its named parts and what is left."""
+    parts = [e.get(k) for k in ("pre_program_s", "jit_wall_s", "program_s")]
+    total = e.get("to_first_step_s")
+    left = (None if total is None or None in parts else total - sum(parts))
+    ready = {True: "the chip was reached in it",
+             False: "the chip was not reached yet: that lies between the "
+                    "spans below"}.get(e.get("backends_ready_at_load"), "")
+    out = [f"  set-up: {_s(total)} s from process start through the first "
+           "step",
+           f"    before grace_tpu began to import: {_s(parts[0])} s"
+           + (f" ({ready})" if ready else ""),
+           f"    JAX's trace, lowering and compile events: {_s(parts[1])} s, "
+           f"of which reading the persistent cache {_s(e.get('cache_read_s'))}",
+           f"    the program's own Python (the spans' self time): "
+           f"{_s(parts[2])} s",
+           f"    unnamed (the caller's code between the spans, the first "
+           f"step's run): {_s(left)} s"]
+    began = e.get("process_began")
+    for span in e.get("spans", []):
+        at = None if began is None else span["start"] - began
+        out.append(
+            f"    span {span['name']} at {_s(at)} s: "
+            f"{_s(span['end'] - span['start'], 3)} s, self "
+            f"{_s(span.get('self_s'), 3)}, process cpu {_s(span.get('cpu'), 3)}"
+            f", runq {_s(span.get('runq'), 3)}, major faults "
+            f"{span.get('major_faults', '?')}")
+    if e.get("spans_dropped"):
+        out.append(f"    spans not kept: {e['spans_dropped']}")
+    built = e.get("built")
+    if built:
+        pressure = ", ".join(
+            f"{r} {_s(built.get('pressure_' + r))}"
+            for r in ("cpu", "memory", "io"))
+        out.append(
+            f"    when the last of {e.get('builds', '?')} programs was built:"
+            f" process cpu {_s(built.get('cpu'))} s, runnable but waiting "
+            f"{_s(built.get('runq'))} s, major faults "
+            f"{built.get('major_faults', '?')}, involuntary switches "
+            f"{built.get('involuntary_switches', '?')}; the machine's "
+            f"pressure since boot (s): {pressure}")
+    return out
+
+
 def _render_perf(perf: List[dict]) -> List[str]:
-    """Step-time percentiles (last window wins — they are cumulative),
-    compile/retrace events, memory watermarks, footprint check."""
+    """Set-up's parts, step-time percentiles (last window wins — they are
+    cumulative), stalled steps, compile/retrace events, memory watermarks,
+    footprint check."""
     out = []
+    for e in perf:
+        if e["event"] == "perf_setup":
+            out.extend(_render_setup(e))
     times = [e for e in perf if e["event"] == "perf_step_times"]
     if times:
         t = times[-1]
@@ -374,6 +437,20 @@ def _render_perf(perf: List[dict]) -> List[str]:
                        "async-dispatch times, not step times")
         if t.get("failed_steps"):
             out.append(f"  failed steps recorded: {t['failed_steps']}")
+    stalls = [e for e in perf if e["event"] == "perf_stall"]
+    if stalls:
+        out.append(f"  stalled steps: {len(stalls)} (wall over 1.5x the "
+                   "running median and 50 ms over it; the loop's thread, "
+                   "from the operating system's counters)")
+        for e in stalls:
+            runq = e.get("runq_s")
+            out.append(
+                f"    step {e.get('step', '?')}: wall {e['wall_s']:.3f} s = "
+                f"cpu {e['cpu_s']:.3f} + runq "
+                + ("n/a" if runq is None else f"{runq:.3f}")
+                + f" + blocked {e['blocked_s']:.3f}; major faults "
+                f"{e.get('major_faults', '?')}; cause {e['cause']} — "
+                + _STALL_CAUSES.get(e["cause"], "?"))
     compiles = [e for e in perf if e["event"] == "perf_compile"]
     retraces = [e for e in perf if e["event"] == "perf_retrace"]
     if compiles or retraces:
