@@ -38,8 +38,9 @@ published).
 What is shared with ``models/lfm2.py`` is imported from it, not copied: the
 router, the expert layer told which experts it holds (``moe_ffn``: routes
 over all ``num_experts``, computes its own experts' part over the sorted
-assignments in tiles of one expert each, as many as hold a row, and drops
-none), the dense feed-forward and its part of a layer,
+assignments in tiles of one expert each, as many as hold a row, sums the
+tiles' rows by token with sorts and gathers, and drops none), the dense
+feed-forward and its part of a layer,
 the walk over sequences, the head with the loss. The shared expert is
 computed whole by every chip of a layer and added to the routed part. Two
 spellings of the scores, as there: the fused kernel where ``ops.pallas_attention.engages`` says so (the
@@ -56,7 +57,7 @@ are kept), attention and the dense feed-forward ``seq_block`` sequences at
 a time; parameters float32, cast inside a block, so a
 weight's gradient is summed over the blocks in float32. Model state: per
 expert layer the correction bias and the counters ``drawn``, ``held``,
-``computed``, ``dropped`` of ``lfm2``.
+``computed``, ``combined``, ``dropped`` of ``lfm2``.
 """
 
 from __future__ import annotations

@@ -38,11 +38,27 @@ is ``moe_row_block`` sorted rows, or a quarter of a balanced router's load
 on one expert; each held expert's rows start on a tile's boundary, so a
 tile belongs to one expert: it gathers its rows, multiplies them with that
 expert's three matrices (plain products, under their stage's name) and
-adds the results to their tokens, and backwards adds its weight gradients
-to that expert's float32 slices in place. What is multiplied beyond the
-rows held is less than a tile an expert. With all experts held the layer
-is the whole one; with a share it is what that chip computes before an
-exchange this file does not have.
+writes the weighted results where they lie in sorted order, and backwards
+adds its weight gradients to that expert's float32 slices in place. What
+is multiplied beyond the rows held is less than a tile an expert. With all
+experts held the layer is the whole one; with a share it is what that chip
+computes before an exchange this file does not have.
+
+How rows and gates travel between token order and sorted order: by sorts,
+gathers of whole rows and contiguous writes, never one index at a time. On
+the chip a scatter-add takes 0.25 us a row of 2,048 numbers, a gather of
+such rows 0.024 us a row, and a gather of single numbers 7.6 ns a number,
+ten times what a sort of as many keys takes (PERF.md, PRs 37 and 39). So
+the gates come sorted out of the one stable sort that orders the slots by
+held expert, and their gradient goes back through a sort by the slots; a
+tile's results (and backwards its rows' ``dx``) go into a buffer in sorted
+order that nothing fills; and a second pass sums that buffer by token: the
+held rows sorted back by token, windows of tokens one behind another,
+chunks of held rows laid over them as tiles are over experts, a chunk
+gathering its rows and summing them by token with one product of ones and
+zeros, as many chunks as hold a row (and one a window at least, so that
+every token is written). Forward and backward share that pass: it is one
+linear map, and its transpose is the walk's gather of a row's token.
 
 Memory: every part of a layer (operator, feed-forward, head with loss) is
 recomputed in the backward pass from its input, the dense parts
@@ -53,14 +69,15 @@ backward pass reads in place of a second run of the forward kernel.
 Parameters are cast to the activation dtype inside a block, so a weight's
 gradient is summed over the blocks in float32.
 
-Model state carries, per expert layer, the expert bias and four counters
+Model state carries, per expert layer, the expert bias and five counters
 of the last step (float32, so that the step's mean over replicas keeps
 their type): ``drawn`` (assignments each of the ``num_experts`` experts
 drew), ``held`` (assignments to held experts that were computed),
 ``computed`` (rows multiplied for them: the tiles in use, padding
-included) and ``dropped`` (assignments to held experts that were not
-computed: always 0). A state made without one of the counters comes back
-without it.
+included), ``combined`` (rows the second pass gathered and summed by
+token: its chunks in use, padding included, forward only) and ``dropped``
+(assignments to held experts that were not computed: always 0). A state
+made without one of the counters comes back without it.
 """
 
 from __future__ import annotations
@@ -194,6 +211,7 @@ def expert_layer_state(num_experts: int) -> L.ModelState:
             "drawn": jnp.zeros((num_experts,), jnp.float32),
             "held": jnp.zeros((), jnp.float32),
             "computed": jnp.zeros((), jnp.float32),
+            "combined": jnp.zeros((), jnp.float32),
             "dropped": jnp.zeros((), jnp.float32)}
 
 
@@ -327,15 +345,32 @@ def _count(ids, bins: int):
 
 
 @jax.custom_vjp
-def _sorted(v, order, inverse):
-    """``v[order]`` for a permutation ``order``; backwards ``g[inverse]``, a
-    gather where differentiating the gather would scatter."""
-    return jnp.take(v, order, axis=0)
+def _sort_by_group(group, gates):
+    """``(slot_of_row, row_gates)``: the slots ``0 .. N*k`` in the order of
+    their ``group`` (slot order within a group) and their gates in that
+    order, out of one stable sort on one key: the gates ride the sort (a
+    gather of ``N*k`` single numbers through the permutation runs one
+    number at a time on the chip, ten times what the sort takes). Backwards
+    the sorted gates' gradient is sorted by ``slot_of_row``, a permutation,
+    so that sort is its inverse."""
+    slots = jnp.arange(group.shape[0], dtype=jnp.int32)
+    _, slot_of_row, row_gates = lax.sort((group, slots, gates), num_keys=1,
+                                         is_stable=True)
+    return slot_of_row, row_gates
 
 
-_sorted.defvjp(
-    lambda v, order, inverse: (jnp.take(v, order, axis=0), inverse),
-    lambda inverse, g: (jnp.take(g, inverse, axis=0), None, None))
+def _sort_by_group_fwd(group, gates):
+    slot_of_row, row_gates = _sort_by_group(group, gates)
+    return (slot_of_row, row_gates), slot_of_row
+
+
+def _sort_by_group_bwd(slot_of_row, cotangents):
+    _, g = lax.sort((slot_of_row, cotangents[1]), num_keys=1,
+                    is_stable=False)
+    return None, g
+
+
+_sort_by_group.defvjp(_sort_by_group_fwd, _sort_by_group_bwd)
 
 
 def _tile_rows(cfg: Config, tokens: int) -> int:
@@ -353,16 +388,64 @@ def _tile_rows(cfg: Config, tokens: int) -> int:
     return max(128, balanced // 4 // 128 * 128)
 
 
+def _window_tokens(cfg: Config, tokens: int, tile: int) -> int:
+    """Tokens of one window of the pass that sums the sorted rows by token
+    (:func:`_sum_by_token`), which walks windows of tokens one behind
+    another, a chunk of ``tile`` held rows at a time: the tokens whose held
+    rows a balanced router makes half a chunk of (in whole multiples of
+    128, as the tile), so one chunk a window until its load doubles. A
+    chunk costs 8 us on the chip before its first row and 0.024 us a row,
+    and one product of ``window x tile`` ones and zeros with its rows:
+    fewer and fuller chunks win as long as that product stays small
+    (PERF.md, PR 39)."""
+    here = cfg.num_experts_per_tok * cfg.experts_held
+    window = max(1, tile * cfg.num_experts // (2 * here))
+    if window > 128:
+        window = window // 128 * 128
+    return min(tokens, window)
+
+
+def _lay_tiles(counts, tile: int, n_tiles: int, least: int = 0):
+    """Tiles of ``tile`` rows over groups of ``counts`` rows that lie one
+    behind another, each group's first tile at its first row and at least
+    ``least`` tiles a group: per tile (of ``n_tiles``, the bound) its
+    group, its first row, how many of its rows are the group's and whether
+    it is the group's first; and how many tiles are in use. By a compare
+    and a sum, as in :func:`route`."""
+    groups = counts.shape[0]
+    ends = jnp.cumsum(counts)
+    tiles_of = jnp.maximum(-(-counts // tile), least)
+    tile_ends = jnp.cumsum(tiles_of)
+    t = jnp.arange(n_tiles)
+    group = jnp.minimum(
+        jnp.sum(t[:, None] >= tile_ends[None, :], axis=1), groups - 1)
+    mine = group[:, None] == jnp.arange(groups)[None, :]
+
+    def of_group(v):
+        return jnp.sum(jnp.where(mine, v[None, :], 0), axis=1)
+
+    opens = t - of_group(tile_ends - tiles_of)      # the tile's place in it
+    first = of_group(ends - counts) + opens * tile
+    n_valid = jnp.clip(of_group(ends) - first, 0, tile)
+    return (group, first, n_valid, opens == 0), tile_ends[-1]
+
+
 def _cast(w, dtype):
     return jax.tree_util.tree_map(lambda a: a.astype(dtype), w)
+
+
+def _scratch(shape, dtype):
+    """A buffer of which every element that is read has been written
+    before: on the chip it is not filled."""
+    return lax.empty(shape, dtype)
 
 
 def _tile_of(tokens, gates, tiles, t, tile: int):
     """Tile ``t`` of the walk: its expert, the tokens of its ``tile`` sorted
     rows and their gates, and which of the rows are its expert's. The rows
     past those are the next expert's or nobody's: their gate is zero here,
-    so they add nothing to a token, and nothing to a gradient."""
-    expert, first, n_valid = (a[t] for a in tiles)
+    so their results are zeros, and they add nothing to a gradient."""
+    expert, first, n_valid, _ = (a[t] for a in tiles)
     valid = jnp.arange(tile) < n_valid
     tok = lax.dynamic_slice(tokens, (first,), (tile,))
     g = jnp.where(valid, lax.dynamic_slice(gates, (first,), (tile,)), 0)
@@ -385,41 +468,99 @@ def _dot_rows(a, b):
                            preferred_element_type=jnp.float32)
 
 
+def _sum_by_token(rows, by_token, window: int, chunk: int, n_tokens: int):
+    """``y[token] = sum of rows[r] over the token's held rows r``:
+    ``(n_tokens, d)`` in ``rows``' dtype, summed in float32. ``rows``: a
+    buffer in sorted order; ``by_token``: the held rows in the order of
+    their tokens (each one's token's place in its window and its own in
+    ``rows``, a chunk of padding behind), the chunks laid over the windows
+    (per chunk its window, its first held row, how many of its ``chunk``
+    rows are the window's and whether it is the window's first) and the
+    chunks in use. A chunk gathers its rows, sums them by token with one
+    product (ones where a row is a token's: the same float32 sum of the
+    same addends as an add a row), and writes its window's sum so far in
+    its place; every window has a chunk, so all of ``y`` is written. This
+    is the forward's combine and, of the tiles' ``dx`` rows, the
+    backward's too: one linear map, whose transpose is the walk's gather
+    of a row's token."""
+    token_of, row_of, chunks, in_use = by_token
+    n_windows, d = -(-n_tokens // window), rows.shape[1]
+    # a product with ones and zeros is a sum, not a rounding
+    exact = None if rows.dtype == jnp.bfloat16 else lax.Precision.HIGHEST
+
+    def visit(c, carry):
+        y, so_far = carry
+        win, first, n_valid, opens = (a[c] for a in chunks)
+        valid = jnp.arange(chunk) < n_valid
+        tok = lax.dynamic_slice(token_of, (first,), (chunk,))
+        at = lax.dynamic_slice(row_of, (first,), (chunk,))
+        # what lies past the window's rows may never have been written
+        held = jnp.where(valid[:, None], jnp.take(rows, at, axis=0), 0)
+        whose = tok[None, :] == jnp.arange(window)[:, None]
+        part = lax.dot_general(
+            whose.astype(rows.dtype), held, (((1,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32)
+        so_far = jnp.where(opens, part, so_far + part)
+        return lax.dynamic_update_slice(
+            y, so_far.astype(y.dtype)[None], (win, 0, 0)), so_far
+
+    y, _ = lax.fori_loop(
+        0, in_use, visit, (_scratch((n_windows, window, d), rows.dtype),
+                           jnp.zeros((window, d), jnp.float32)))
+    return y.reshape(-1, d)[:n_tokens]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def held_experts(tile: int, w, x, tokens, gates, tiles, in_use):
+def held_experts(sizes, w, x, tokens, gates, tiles, in_use, by_token):
     """Sum over the sorted rows of the held experts' weighted results, by
-    token: ``(N, d)``. ``tokens``, ``gates``: the token and the weight of
-    each sorted row, with a tile of padding behind; ``tiles``: per tile of
-    ``tile`` rows its expert, its first sorted row and how many of its rows
-    are that expert's; ``in_use``: the tiles that hold a row. Forward walks
-    the tiles in use: a tile gathers its rows, multiplies them with its one
-    expert's matrices and adds the results to their tokens. Backward walks
-    them again, recomputing the tile, and adds the tile's weight gradients
-    to that expert's float32 slices in place."""
+    token: ``(N, d)``. ``sizes``: the rows of a tile (and of a chunk of
+    :func:`_sum_by_token`) and the tokens of its window; ``tokens``,
+    ``gates``: the token and the weight of each sorted row, with a tile of
+    padding behind; ``tiles``: per tile its expert, its first sorted row
+    and how many of its rows are that expert's; ``in_use``: the tiles that
+    hold a row; ``by_token``: what :func:`_sum_by_token` takes.
+
+    No row and no gate moves one index at a time. On the chip a
+    scatter-add takes 0.25 us a row of 2,048, a gather of rows 0.024 us a
+    row, a gather of single numbers 7.6 ns a number (PERF.md, PRs 37 and
+    39). So forward walks the tiles in use: a tile gathers its rows,
+    multiplies them with its one expert's matrices and writes the weighted
+    results where they lie in sorted order, a contiguous write into a
+    buffer nothing fills (the rows past the tile's own are zeros, and the
+    next tile's to write); then :func:`_sum_by_token` sums the buffer by
+    token with gathers. Backward walks the tiles again, recomputing each,
+    adds the tile's weight gradients to that expert's float32 slices in
+    place and writes the rows' ``dx`` in sorted order, which the same
+    function sums by token. The gates came sorted out of the sort
+    (:func:`_sort_by_group`), and their gradient goes back through it."""
+    tile, window = sizes
     wa = _cast(w, x.dtype)
 
-    def visit(t, y):
-        expert, _, _, tok, g = _tile_of(tokens, gates, tiles, t, tile)
+    def visit(t, out):
+        expert, first, _, tok, g = _tile_of(tokens, gates, tiles, t, tile)
         with jax.named_scope(STAGE_MOE_DISPATCH):
             xt = jnp.take(x, tok, axis=0)
         with jax.named_scope(STAGE_MOE_EXPERTS):
             we = _expert(wa, expert)
-            out = (_gated(xt @ we["w1"], xt @ we["w3"]) @ we["w2"]
-                   * g[:, None].astype(xt.dtype))
+            o = (_gated(xt @ we["w1"], xt @ we["w3"]) @ we["w2"]
+                 * g[:, None].astype(xt.dtype))
         with jax.named_scope(STAGE_MOE_COMBINE):
-            return y.at[tok].add(out.astype(jnp.float32))
+            return lax.dynamic_update_slice(out, o, (first, 0))
 
-    y = lax.fori_loop(0, in_use, visit, jnp.zeros(x.shape, jnp.float32))
-    return y.astype(x.dtype)
-
-
-def _held_experts_fwd(tile, w, x, tokens, gates, tiles, in_use):
-    return (held_experts(tile, w, x, tokens, gates, tiles, in_use),
-            (w, x, tokens, gates, tiles, in_use))
+    out = lax.fori_loop(0, in_use, visit,
+                        _scratch((tokens.shape[0], x.shape[1]), x.dtype))
+    with jax.named_scope(STAGE_MOE_COMBINE):
+        return _sum_by_token(out, by_token, window, tile, x.shape[0])
 
 
-def _held_experts_bwd(tile, res, dy):
-    w, x, tokens, gates, tiles, in_use = res
+def _held_experts_fwd(sizes, w, x, tokens, gates, tiles, in_use, by_token):
+    return (held_experts(sizes, w, x, tokens, gates, tiles, in_use, by_token),
+            (w, x, tokens, gates, tiles, in_use, by_token))
+
+
+def _held_experts_bwd(sizes, res, dy):
+    tile, window = sizes
+    w, x, tokens, gates, tiles, in_use, by_token = res
     wa = _cast(w, x.dtype)
 
     def visit(t, carry):
@@ -441,8 +582,10 @@ def _held_experts_bwd(tile, res, dy):
                    "w2": _dot_rows(h, dout)}
             dw = {k: dw[k].at[expert].add(dwe[k]) for k in dw}
         with jax.named_scope(STAGE_MOE_DISPATCH):
-            dx = dx.at[tok].add(dxt.astype(jnp.float32))
-            # the rows past the tile's own are a later tile's to write
+            # the rows past the tile's own are a later tile's to write:
+            # zeros here in dx (their gate is zero), kept as they are in
+            # the gates' gradient
+            dx = lax.dynamic_update_slice(dx, dxt, (first, 0))
             dg = jnp.where(valid, dg.astype(dgates.dtype),
                            lax.dynamic_slice(dgates, (first,), (tile,)))
         return dw, dx, lax.dynamic_update_slice(dgates, dg, (first,))
@@ -450,24 +593,29 @@ def _held_experts_bwd(tile, res, dy):
     dw, dx, dgates = lax.fori_loop(
         0, in_use, visit,
         (jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32), w),
-         jnp.zeros(x.shape, jnp.float32), jnp.zeros(gates.shape, gates.dtype)))
+         _scratch((tokens.shape[0], x.shape[1]), x.dtype),
+         jnp.zeros(gates.shape, gates.dtype)))
+    with jax.named_scope(STAGE_MOE_DISPATCH):
+        dx = _sum_by_token(dx, by_token, window, tile, x.shape[0])
     return (jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), dw, w),
-            dx.astype(x.dtype), None, dgates, None, None)
+            dx, None, dgates, None, None, None)
 
 
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _route_and_sort(p, state, u, cfg: Config, tile: int):
+def _route_and_sort(p, state, u, cfg: Config, sizes):
     """Route the tokens ``u`` ``(N, d)``, sort their assignments by held
-    expert and lay tiles of ``tile`` rows over the sorted rows, each held
-    expert's first tile at its first row: what :func:`held_experts` takes,
-    and the layer's counters. The tiles are bounded by the worst case,
-    every assignment held here and every expert's last tile all but
-    empty."""
+    expert and lay tiles over the sorted rows, each held expert's first
+    tile at its first row; sort the held rows back by token and lay chunks
+    over the windows of tokens: what :func:`held_experts` takes, and the
+    layer's counters. A chunk is a tile's rows; tiles and chunks are
+    bounded by the worst case, every assignment held here and every
+    expert's (window's) last tile (chunk) all but empty."""
+    tile, window = sizes
     k, held = cfg.num_experts_per_tok, cfg.experts_held
-    n_slots = u.shape[0] * k
-    n_tiles = -(-n_slots // tile) + held
+    n_tokens, n_slots = u.shape[0], u.shape[0] * k
+    n_windows = -(-n_tokens // window)
     with jax.named_scope(STAGE_MOE_ROUTER):
         experts, gates = route(p, state["expert_bias"], u, cfg)
         drawn = _count(experts.reshape(-1), cfg.num_experts)
@@ -475,39 +623,33 @@ def _route_and_sort(p, state, u, cfg: Config, tile: int):
         local = experts.reshape(-1) - cfg.first_expert
         here = (local >= 0) & (local < held)
         group = jnp.where(here, local, held)          # the rest sort last
-        slot_of_row = jnp.argsort(group, stable=True)
-        row_of_slot = jnp.argsort(slot_of_row)
+        slot_of_row, row_gates = _sort_by_group(group, gates.reshape(-1))
         counts = _count(group, held + 1)[:held]
-        ends = jnp.cumsum(counts)
-        tiles_of = -(-counts // tile)
-        tile_ends = jnp.cumsum(tiles_of)
-        # per tile: its expert, and by a compare and a sum (as in route)
-        # where that expert's rows and tiles start and its rows end
-        t = jnp.arange(n_tiles)
-        expert = jnp.minimum(
-            jnp.sum(t[:, None] >= tile_ends[None, :], axis=1), held - 1)
-        mine = expert[:, None] == jnp.arange(held)[None, :]
-
-        def of_expert(v):
-            return jnp.sum(jnp.where(mine, v[None, :], 0), axis=1)
-
-        first = (of_expert(ends - counts)
-                 + (t - of_expert(tile_ends - tiles_of)) * tile)
-        n_valid = jnp.clip(of_expert(ends) - first, 0, tile)
-        in_use = tile_ends[-1]
+        tiles, in_use = _lay_tiles(counts, tile, -(-n_slots // tile) + held)
         # a tile of padding, so that the last tile's window lies inside
-        pad = (0, tile)
-        tokens = jnp.pad(slot_of_row // k, pad)
-        row_gates = jnp.pad(_sorted(gates.reshape(-1), slot_of_row,
-                                    row_of_slot), pad)
-    held_rows = jnp.sum(n_valid).astype(jnp.float32)
+        tokens = jnp.pad(slot_of_row // k, (0, tile))
+        row_gates = jnp.pad(row_gates, (0, tile))
+        # the held rows by token: their slots sorted, each with its row
+        rows = jnp.arange(n_slots, dtype=jnp.int32)
+        slot, row_of = lax.sort(
+            (jnp.where(rows < jnp.sum(counts), slot_of_row, n_slots), rows),
+            num_keys=1, is_stable=False)
+        per_window = jnp.sum(jnp.pad(
+            here, (0, n_windows * window * k - n_slots)).reshape(
+                n_windows, -1), axis=1, dtype=jnp.int32)
+        chunks, chunks_in_use = _lay_tiles(
+            per_window, tile, -(-n_slots // tile) + n_windows, least=1)
+        by_token = (jnp.pad(slot // k % window, (0, tile)),
+                    jnp.pad(row_of, (0, tile)), chunks, chunks_in_use)
+    held_rows = jnp.sum(tiles[2]).astype(jnp.float32)
     counters = {"expert_bias": state["expert_bias"],
                 "drawn": drawn.astype(jnp.float32), "held": held_rows,
                 "computed": (in_use * tile).astype(jnp.float32),
+                "combined": (chunks_in_use * tile).astype(jnp.float32),
                 "dropped": jnp.sum(here).astype(jnp.float32) - held_rows}
     # a state made without a counter (the benchmark's own makes the four it
     # knows) goes on without it
-    return ((tokens, row_gates, (expert, first, n_valid), in_use),
+    return ((tokens, row_gates, tiles, in_use, by_token),
             {name: counters[name] for name in state})
 
 
@@ -516,8 +658,9 @@ def moe_ffn(p, state, u, cfg: Config):
     ``u`` ``(n, T, d)``, and the layer's new state (the counters)."""
     x = u.reshape(-1, u.shape[-1])
     tile = _tile_rows(cfg, x.shape[0])
-    sorted_rows, counters = _route_and_sort(p, state, x, cfg, tile)
-    y = held_experts(tile, {k: p[k] for k in ("w1", "w3", "w2")}, x,
+    sizes = (tile, _window_tokens(cfg, x.shape[0], tile))
+    sorted_rows, counters = _route_and_sort(p, state, x, cfg, sizes)
+    y = held_experts(sizes, {k: p[k] for k in ("w1", "w3", "w2")}, x,
                      *sorted_rows)
     return y.reshape(u.shape), counters
 

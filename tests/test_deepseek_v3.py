@@ -136,11 +136,12 @@ def test_the_program_reads_the_tree_the_benchmark_makes():
                                       jax.random.key(0))
     assert (jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made))
-    # the program's own state counts the rows multiplied too (PR 37)
+    # the program's own state counts the rows multiplied (PR 37) and the
+    # rows its second pass summed (PR 39) too
     for own_layer, made_layer in zip(own_state["layers"],
                                      made_state["layers"]):
         assert set(own_layer) - set(made_layer) == (
-            {"computed"} if made_layer else set())
+            {"computed", "combined"} if made_layer else set())
         assert {k: own_layer[k] for k in made_layer} == made_layer
     assert len(jax.tree_util.tree_leaves(own)) == 3 + 10 + 2 * 14
     assert cfg.route_eps == 1e-20 and lfm2.Config().route_eps == 1e-6
@@ -434,13 +435,16 @@ ROUTERS = {"balanced": (0.0, 0.0), "skewed": (10.0, 0.0),
            "idle": (-10.0, 0.0), "worst_case": (10.0, 10.0)}
 
 
-@pytest.mark.parametrize("tile", [0, 8, 40])
+@pytest.mark.parametrize("tile", [0, 8, 16, 40])
 @pytest.mark.parametrize("router", sorted(ROUTERS))
 def test_six_a_token_in_expert_aligned_tiles(router, tile):
     """This decoder's routing (6 a token, gates scaled) through the tiles
-    of ``lfm2.moe_ffn`` (PR 37): the routed part and its gradients against
-    the plain reference, nothing dropped, and less than a tile an expert
-    multiplied beyond the rows held, whatever the router does."""
+    of ``lfm2.moe_ffn`` (PR 37) and its sum by token in windows and chunks
+    (PR 39): the routed part and its gradients against the plain
+    reference, nothing dropped, less than a tile an expert multiplied
+    beyond the rows held and less than a chunk a window summed beyond
+    them, whatever the router does (``worst_case``: both experts' 48 rows
+    end on a boundary of the tiles of 8 and 16)."""
     sizes, whole, u, _ = _expert_layer(6)
     sizes = dict(sizes, num_experts_per_tok=6)
     b = jnp.zeros((8,)).at[2:4].set(jnp.asarray(ROUTERS[router]))
@@ -474,6 +478,12 @@ def test_six_a_token_in_expert_aligned_tiles(router, tile):
     assert float(counters["held"]) == held
     assert 0 <= float(counters["computed"]) - held < 2 * rows
     assert float(counters["computed"]) % rows == 0
+    experts, _ = lfm2.route(share, b, u.reshape(-1, 32), cfg)
+    window, chunk = lfm2._window_tokens(cfg, 48, rows), rows
+    mine = np.asarray((experts >= 2) & (experts < 4)).sum(1)
+    assert float(counters["combined"]) == sum(
+        max(1, -(-int(mine[i:i + window].sum()) // chunk)) * chunk
+        for i in range(0, 48, window))
     assert {"balanced": 48 < held < 96, "skewed": held > 48,
             "idle": float(counters["drawn"][2]) == 0 and held > 0,
             "worst_case": held == 96}[router]

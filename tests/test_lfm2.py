@@ -140,13 +140,13 @@ def test_the_program_reads_the_tree_the_benchmark_makes():
                                       jax.random.key(0))
     assert (jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made))
-    # the program's own state counts the rows multiplied too (PR 37); the
-    # benchmark's makes the four counters it knows, and the layer goes on
-    # with those
+    # the program's own state counts the rows multiplied (PR 37) and the
+    # rows its second pass summed (PR 39) too; the benchmark's makes the
+    # four counters it knows, and the layer goes on with those
     for own_layer, made_layer in zip(own_state["layers"],
                                      made_state["layers"]):
         assert set(own_layer) - set(made_layer) == (
-            {"computed"} if made_layer else set())
+            {"computed", "combined"} if made_layer else set())
         assert {k: own_layer[k] for k in made_layer} == made_layer
     assert len(jax.tree_util.tree_leaves(own)) == 50
 
@@ -326,8 +326,22 @@ ROUTERS = {
     "one_draws_nothing": (0.0, 0.0, -10.0, 0.0),
     "over_the_old_block": (10.0, 0.3, 0.3, 0.3),
     "worst_case": (10.0, 10.0, 0.0, 0.0),
+    # two experts draw every token and two none: the load ends on a tile's
+    # boundary (48 rows each) and every window's on a chunk's
+    "ends_on_a_boundary": (10.0, 10.0, -10.0, -10.0),
 }
 TILES = [0, 8, 16, 40, 128]
+
+
+def _combined_by_hand(experts, cfg, first, held):
+    """Rows the second pass gathers, from the routing: every window of
+    tokens in whole chunks of its held rows, and at least one."""
+    n = experts.shape[0]
+    tile = lfm2._tile_rows(cfg, n)
+    window, chunk = lfm2._window_tokens(cfg, n, tile), tile
+    here = np.asarray((experts >= first) & (experts < first + held)).sum(1)
+    per_window = [int(here[i:i + window].sum()) for i in range(0, n, window)]
+    return sum(max(1, -(-c // chunk)) * chunk for c in per_window)
 
 
 def _routed_layer(router):
@@ -377,22 +391,30 @@ def test_tiles_compute_every_held_row_and_little_more(router, tile):
     assert float(counters["computed"]) == sum(
         -(-int(n) // rows) * rows for n in drawn[2:6])
     assert 0 <= float(counters["computed"]) - held < 4 * rows
+    # the second pass: every window of tokens in whole chunks of its rows
+    assert float(counters["combined"]) == _combined_by_hand(experts, cfg, 2, 4)
+    window, chunk = lfm2._window_tokens(cfg, 48, rows), rows
+    assert 0 <= float(counters["combined"]) - held <= -(-48 // window) * chunk
     # the routers are what their names say
-    assert {"balanced": 24 < held < 72,
+    mine = np.asarray((experts >= 2) & (experts < 6)).sum(1)
+    assert {"balanced": 24 < held < 72 and 0 in mine and 2 in mine,
             "skewed_to_one": drawn[3] == 48,
             "one_draws_nothing": drawn[4] == 0 and held > 0,
             "over_the_old_block": drawn[2] == 48 and 48 < held < 96,
-            "worst_case": held == 96}[router]
+            "worst_case": held == 96 and set(mine) == {2},
+            "ends_on_a_boundary": (drawn[2], drawn[3], drawn[4], drawn[5])
+            == (48, 48, 0, 0)}[router]
 
 
 def test_a_state_without_the_new_counter_goes_on_without_it():
-    """``computed`` is a counter of the program's own state; a state made
-    without it (the benchmark's, from before PR 37) comes back with the
-    keys it had, the others unchanged."""
+    """``computed`` and ``combined`` are counters of the program's own
+    state; a state made without them (the benchmark's, from before PR 37)
+    comes back with the keys it had, the others unchanged."""
     _, share, u, bias = _routed_layer("balanced")
     cfg = lfm2.tiny(first_expert=2, experts_held=4, moe_row_block=16)
     own = dict(lfm2.expert_layer_state(8), expert_bias=bias)
-    assert set(own) == {"expert_bias", "drawn", "held", "computed", "dropped"}
+    assert set(own) == {"expert_bias", "drawn", "held", "computed",
+                        "combined", "dropped"}
     assert set(lfm2.init_state(lfm2.tiny())["layers"][1]) == set(own)
     y, counters = lfm2.moe_ffn(share, own, u, cfg)
     y_old, old = lfm2.moe_ffn(share, _state(bias), u, cfg)
@@ -414,6 +436,120 @@ def test_the_tile_is_chosen_from_the_shapes():
     assert lfm2._tile_rows(cfg, 3 * 32768) == 1536
     assert lfm2._tile_rows(dataclasses.replace(cfg, moe_row_block=24),
                            32768) == 24
+
+
+def test_the_window_is_chosen_from_the_shapes():
+    """The second pass's chunk is a tile's rows and its window the tokens
+    whose held rows a balanced router makes half a chunk of, in whole
+    multiples of 128: 512 tokens in both benchmark cells (chunks of 512 and
+    of 384 rows)."""
+    cfg = lfm2.Config(first_expert=0, experts_held=8)
+    assert lfm2._window_tokens(cfg, 32768, 512) == 512
+    kanana = dataclasses.replace(cfg, num_experts=128, num_experts_per_tok=6)
+    assert lfm2._tile_rows(kanana, 32768) == 384
+    assert lfm2._window_tokens(kanana, 32768, 384) == 512
+    assert lfm2._window_tokens(kanana, 4096, 128) == 128        # 170 whole
+    assert lfm2._window_tokens(lfm2.tiny(experts_held=4), 48, 8) == 4
+    assert lfm2._window_tokens(lfm2.tiny(experts_held=4), 48, 128) == 48
+    assert lfm2._window_tokens(lfm2.tiny(experts_held=4), 48, 1) == 1
+
+
+# Both decoders' routing through the one expert layer: LFM2's two of eight,
+# and kanana's six of eight with its scaled gates, six experts held so that
+# a token's six slots can all be held here.
+DECODERS = {
+    "lfm2": dict(first_expert=2, experts_held=4),
+    "kanana": dict(first_expert=2, experts_held=6, num_experts_per_tok=6,
+                   routed_scaling_factor=2.448, route_eps=1e-20),
+}
+
+
+def _plain_held(w, x, experts, gates, first):
+    """The held experts' part, token by token: every held expert's
+    feed-forward of every token, weighted by the token's gate for it (zero
+    where it was not chosen), summed over the experts in float32. The
+    roundings are the program's: products in ``x``'s dtype, the gate cast
+    to it."""
+    total = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w["w1"].shape[0]):
+        we = {k: v[e].astype(x.dtype) for k, v in w.items()}
+        out = (jax.nn.silu(x @ we["w1"]) * (x @ we["w3"])) @ we["w2"]
+        g = jnp.sum(jnp.where(experts == first + e, gates, 0.0), axis=-1)
+        total = total + (out * g[:, None].astype(x.dtype)).astype(jnp.float32)
+    return total.astype(x.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+@pytest.mark.parametrize("decoder", sorted(DECODERS))
+def test_rows_and_gates_travel_by_sorts_and_gathers(monkeypatch, decoder,
+                                                    router, dtype):
+    """The result and the gradients of the weights, the rows **and the
+    gates** (back through the sort that carried them) against the plain
+    per-token spelling at the same routing, in float32 and in bfloat16,
+    for tokens that hold all their slots here, some and none; and the
+    buffers nothing fills hold no number the result reads: filled with
+    NaN, the layer gives the same bits."""
+    sizes, whole, u, bias = _expert_layer(9)
+    kw = DECODERS[decoder]
+    first, held = kw["first_expert"], kw["experts_held"]
+    cfg = lfm2.tiny(moe_row_block=8, **kw)
+    bias = (bias * 0.1).at[2:6].add(jnp.asarray(ROUTERS[router]))
+    share = _share_of(whole, first, held)
+    w = {k: share[k] for k in ("w1", "w3", "w2")}
+    x = u.reshape(-1, 32).astype(dtype)
+    with jax.default_matmul_precision("highest"):
+        experts, gates0 = lfm2.route(share, bias, x, cfg)
+    state = dict(lfm2.expert_layer_state(8), expert_bias=bias)
+    weight = jnp.cos(jnp.arange(48 * 32, dtype=jnp.float32)).reshape(48, 32)
+
+    def program(w, x, gates):
+        monkeypatch.setattr(lfm2, "route", lambda *a: (experts, gates))
+        y, counters = lfm2.moe_ffn(dict(w, router=share["router"]), state,
+                                   x[None], cfg)
+        return jnp.sum(y[0].astype(jnp.float32) * weight), (y[0], counters)
+
+    def reference(w, x, gates):
+        y = _plain_held(w, x, experts, gates, first)
+        return jnp.sum(y.astype(jnp.float32) * weight), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, (got, counters)), grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1, 2), has_aux=True))(w, x, gates0)
+        (_, want), want_grads = jax.jit(jax.value_and_grad(
+            reference, argnums=(0, 1, 2), has_aux=True))(w, x, gates0)
+        monkeypatch.setattr(lfm2, "_scratch",
+                            lambda shape, dt: jnp.full(shape, jnp.nan, dt))
+        (_, (unfilled, _)), unfilled_grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1, 2), has_aux=True))(w, x, gates0)
+    # float32: another order of sums; bfloat16: a product of a tile's rows
+    # and the same product of all rows differ in an addend's last bit
+    rtol, atol = {"float32": (2e-4, 2e-6), "bfloat16": (2e-2, 1e-2)}[dtype]
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))   # noqa: E731
+    np.testing.assert_allclose(f32(got), f32(want), rtol=rtol, atol=atol)
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        np.testing.assert_allclose(f32(g), f32(r), rtol=rtol,
+                                   atol=atol * float(jnp.max(jnp.abs(r)) + 1))
+    # the gates' gradient: zero where the slot's expert is not held, and
+    # something where it is
+    mine = np.asarray((experts >= first) & (experts < first + held))
+    dgates = np.asarray(grads[2])
+    assert dgates.shape == mine.shape and not dgates[~mine].any()
+    assert mine.any() and np.abs(dgates[mine]).min() > 0
+    np.testing.assert_array_equal(f32(got), f32(unfilled))
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(unfilled_grads)):
+        np.testing.assert_array_equal(f32(g), f32(r))
+    k = cfg.num_experts_per_tok
+    assert float(counters["held"]) == mine.sum()
+    assert float(counters["combined"]) == _combined_by_hand(
+        experts, cfg, first, held)
+    if router == "worst_case" and decoder == "lfm2":
+        assert set(mine.sum(1)) == {k}          # every slot of every token
+    if router == "balanced":
+        assert mine.sum(1).min() < mine.sum(1).max()
 
 
 def test_a_share_outside_the_routers_experts_is_refused():

@@ -575,7 +575,12 @@ def test_the_expert_part_walks_tiles_of_one_expert(one_chip, decoder):
     which XLA runs as its own kernel without the scope's name, is gone),
     and a tile's weight gradient is added into its one expert's float32
     slice: nowhere in the part is a whole ``(experts held, d, f)`` stack
-    added."""
+    added. Since PR 39 nothing in the part addresses memory one index at a
+    time at a size that grows with the tokens: no ``scatter``, no gather
+    but of whole rows (a tile's, a chunk's), the gates out of the sort
+    that orders the rows; a tile's results and its rows' ``dx`` are written
+    where they lie in sorted order, into buffers nothing fills, and two
+    more loops (the chunks in use) sum them by token."""
     make_cfg, mod = {"lfm2": (_lfm2_config, lfm2),
                      "kanana": (_kanana_config, deepseek_v3)}[decoder]
     cfg = make_cfg()
@@ -604,16 +609,38 @@ def test_the_expert_part_walks_tiles_of_one_expert(one_chip, decoder):
     assert (held, d, f) == {"lfm2": (8, 2048, 1536),
                             "kanana": (8, 2048, 768)}[decoder]
     assert "ragged" not in text and "tpu_custom_call" not in text
-    # forward and backward loop, neither with a trip count known in advance
+    # the walk and the sum by token, forward and backward: four loops, none
+    # with a trip count known in advance
     loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 2 and not any("known_trip_count" in line
+    assert len(loops) == 4 and not any("known_trip_count" in line
                                        for line in loops)
+    # no row and no gate moves one index at a time
+    n, k = 4096, cfg.num_experts_per_tok
+    window = lfm2._window_tokens(cfg, 8 * n, tile)
+    assert window == 512                  # in both cells; a chunk is a tile
+    assert " scatter(" not in text
+    gathers = _instructions(text, "gather")
+    assert [shape for shape, _ in gathers] == [f"bf16[{tile},{d}]"] * 5
+    # the gates ride the sort: one of three operands forward, the same
+    # recomputed, and their gradient's way back
+    sorts = [line.split(" sort(")[0] for line in text.splitlines()
+             if " sort(" in line]
+    carried = sorted(len(re.findall(r"\w+\[%d\]" % (n * k), x))
+                     for x in sorts if f"f32[{n * k}]" in x)
+    assert carried == [2, 3, 3]
+    assert not re.search(r"= \w+\[%d\]\S* gather\(" % (n * k), text)
     # every product of the part under its stage: the experts' in the loops,
-    # the router's and (kanana) the shared expert's outside them
+    # the router's and (kanana) the shared expert's outside them, and the
+    # sum by token's one a chunk (ones and zeros times the chunk's rows)
     products = _instructions(text, "convolution")
     stages = [stage_of(name) for _, name in products]
-    assert set(stages) == {"grace/moe_experts", "grace/moe_router"} | (
+    assert set(stages) == {"grace/moe_experts", "grace/moe_router",
+                           "grace/moe_combine", "grace/moe_dispatch"} | (
         {"grace/shared_expert"} if decoder == "kanana" else set())
+    assert sorted((shape, stage) for (shape, _), stage in zip(products, stages)
+                  if stage in ("grace/moe_combine", "grace/moe_dispatch")) == [
+        (f"f32[{window},{d}]", "grace/moe_combine"),
+        (f"f32[{window},{d}]", "grace/moe_dispatch")]
     in_tiles = [shape for (shape, name), stage in zip(products, stages)
                 if stage == "grace/moe_experts"]
     assert len(in_tiles) >= 11 and all("/while/body/" in name for _, name in
@@ -632,6 +659,17 @@ def test_the_expert_part_walks_tiles_of_one_expert(one_chip, decoder):
                _instructions(text, "dynamic-update-slice")]
     assert updates.count(f"f32[{held},{d},{f}]") == 2 and updates.count(
         f"f32[{held},{f},{d}]") == 1
+    # the sorted-order buffers (every slot and a tile of padding) and the
+    # windows' sums: written in place, contiguously, and never filled
+    assert updates.count(f"bf16[{n * k + tile},{d}]") == 2
+    assert updates.count(f"bf16[{n // window},{window},{d}]") == 2
+    made = re.findall(r"= (\w+\[[\d,]*\])[^\n]*? custom-call\(\)[^\n]*"
+                      r"custom_call_target=\"AllocateBuffer\"", text)
+    assert sorted(made) == sorted([f"bf16[{n * k + tile},{d}]"] * 2
+                                  + [f"bf16[{n // window},{window},{d}]"] * 2)
+    assert not any(shape in (f"f32[{n},{d}]", f"bf16[{n * k + tile},{d}]")
+                   and (stage_of(name) or "").startswith("grace/moe")
+                   for shape, name in _instructions(text, "broadcast"))
 
 
 def _lfm2_config():
