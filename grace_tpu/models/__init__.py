@@ -21,12 +21,19 @@ checkpoints with orbax alongside them.
                       a chip holds a share (the expert layer, the head and
                       the loss are ``lfm2``'s); the benchmark's
                       ``kanana-2-30b-a3b-ep16`` configuration
+* ``sdar``          — SDAR block-diffusion decoder (a Qwen3-shaped expert
+                      decoder): the noised and the clean copy of a sequence
+                      side by side under a block mask, position ids, a
+                      softmax router, the masked tokens' weighted loss with
+                      the step's noise drawn on the device (attention, the
+                      expert walk and the head are ``lfm2``'s); the
+                      benchmark's ``sdar-30b-a3b-ep8`` configuration
 * ``vgg``           — VGG-11/13/16/19 (the communication-bound classic of the
                       reference's synthetic-benchmark model list)
 """
 
 from grace_tpu.models import (deepseek_v3, layers, lenet, lfm2, resnet,
-                              resnet_cifar, transformer, vgg)
+                              resnet_cifar, sdar, transformer, vgg)
 
 __all__ = ["deepseek_v3", "layers", "lenet", "lfm2", "resnet", "resnet_cifar",
-           "transformer", "vgg"]
+           "sdar", "transformer", "vgg"]

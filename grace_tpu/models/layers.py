@@ -148,13 +148,19 @@ def rms_apply(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * p["scale"]).astype(x.dtype)
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
+def rotary(x: jax.Array, theta: float,
+           positions: jax.Array | None = None) -> jax.Array:
     """Rotary positions over the whole head, rotate-half: ``x`` is
-    ``(..., T, heads, head_dim)``, position ``t`` along the third axis from
-    the end. Angles and the rotation are float32."""
+    ``(..., T, heads, head_dim)``, along the third axis from the end the
+    entries of ``positions`` ``(T,)`` or, without them, ``0 .. T - 1``.
+    Angles and the rotation are float32."""
     t, d = x.shape[-3], x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None, None] * freq
+    if positions is None:
+        positions = jnp.arange(t, dtype=jnp.float32)
+    elif positions.shape != (t,):
+        raise ValueError(f"{positions.shape} positions for {t} entries")
+    ang = positions.astype(jnp.float32)[:, None, None] * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],

@@ -265,21 +265,23 @@ def short_conv(p, u, cfg: Config):
     return _dot(c * conv, p["out_proj"])
 
 
-def _scores_block(q, k, v, start):
-    """Causal attention of one block of queries, at positions ``start`` on,
-    over the keys up to the block's end. ``q``: ``(n, Q, Hkv, G, D)``;
+def _scores_block(q, k, v, start, mask=pallas_attention.CAUSAL):
+    """Attention under ``mask`` of one block of queries, at positions
+    ``start`` on, over the keys given (the first of the sequence: under the
+    causal mask those up to the block's end). ``q``: ``(n, Q, Hkv, G, D)``;
     ``k``, ``v``: ``(n, K, Hkv, D)``."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("nqhgd,nkhd->nhgqk", q, k).astype(jnp.float32) * scale
     qpos = start + jnp.arange(q.shape[1])
-    mask = qpos[:, None] >= jnp.arange(k.shape[1])[None, :]
-    s = jnp.where(mask, s, -jnp.inf)
+    allowed = mask.allowed(qpos[:, None], jnp.arange(k.shape[1])[None, :])
+    s = jnp.where(allowed, s, -jnp.inf)
     a = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("nhgqk,nkhd->nqhgd", a, v)
 
 
-def _scores_in_blocks(q, k, v, q_block: int):
-    """Causal attention ``q_block`` queries at a time, each block's
+def _scores_in_blocks(q, k, v, q_block: int, mask=pallas_attention.CAUSAL):
+    """Attention under ``mask`` (``ops.pallas_attention``'s: the causal one,
+    or block diffusion's) ``q_block`` queries at a time, each block's
     ``(heads, queries, keys)`` scores made, normalised and multiplied into
     the values by XLA: the plain spelling, and what the kernel is tested
     against. ``q``: ``(n, T, Hq, D)``; ``k``: ``(n, T, Hkv, D)``; ``v``:
@@ -287,32 +289,52 @@ def _scores_in_blocks(q, k, v, q_block: int):
     n, t, hq, hd = q.shape
     hkv = k.shape[2]
     q = q.reshape(n, t, hkv, hq // hkv, hd)
-    block = jax.checkpoint(_scores_block, static_argnums=(3,))
-    out = [block(q[:, s:s + q_block], k[:, :s + q_block], v[:, :s + q_block],
-                 s) for s in range(0, t, q_block)]
+    block = jax.checkpoint(_scores_block, static_argnums=(3, 4))
+    out = []
+    for s in range(0, t, q_block):
+        keys = mask.keys_read(s + q_block, t)
+        out.append(block(q[:, s:s + q_block], k[:, :keys], v[:, :keys], s,
+                         mask))
     return jnp.concatenate(out, axis=1).reshape(n, t, hq, v.shape[-1])
 
 
-def attention(p, u, cfg: Config):
-    """Grouped-query causal self-attention of normalised ``u``. On a TPU, a
-    sequence of whole tiles at a head size the fused kernel takes goes
-    through it (``ops.pallas_attention``: no score tensor in HBM, its own
-    backward pass); everything else through :func:`_scores_in_blocks`."""
+def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
+              positions=None):
+    """Grouped-query self-attention of normalised ``u`` under ``mask``
+    (causal unless told otherwise), rotated by ``positions`` (``0 .. T -
+    1`` unless given). On a TPU, a sequence of whole tiles at a head size
+    the fused kernel takes goes through it (``ops.pallas_attention``: no
+    score tensor in HBM, its own backward pass); everything else through
+    :func:`_scores_in_blocks`."""
     n, t, _ = u.shape
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
+    fused = pallas_attention.engages(t, hd, hd, u.dtype)
+    # The kernel applies no scale. 1/sqrt(64) is a power of two, so q times
+    # it is exact in q's dtype; 1/sqrt(128) is none, and goes into the
+    # float32 weight of the queries' norm, so that q is rounded as often
+    # as the plain spelling's.
+    scale = 1.0 / math.sqrt(hd)
+    exact = math.frexp(scale)[0] == 0.5
+    q_norm = p["q_norm"]
+    if fused and not exact:
+        q_norm = {"scale": q_norm["scale"] * scale}
     q = _dot(u, p["q_proj"]).reshape(n, t, hq, hd)
     k = _dot(u, p["k_proj"]).reshape(n, t, hkv, hd)
     v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
-    q = L.rotary(L.rms_apply(p["q_norm"], q, cfg.norm_eps), cfg.rope_theta)
-    k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta)
-    if pallas_attention.engages(t, hd, hd, q.dtype):
-        # the kernel applies no scale; 1/sqrt(64) is a power of two, so q
-        # times it is exact in q's dtype
-        scale = jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
-        out = pallas_attention.causal_gqa(q * scale, k, v)
+    q = L.rotary(L.rms_apply(q_norm, q, cfg.norm_eps), cfg.rope_theta,
+                 positions)
+    k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta,
+                 positions)
+    if fused:
+        if exact:
+            q = q * jnp.asarray(scale, q.dtype)
+        if mask == pallas_attention.CAUSAL:
+            out = pallas_attention.causal_gqa(q, k, v)
+        else:
+            out = pallas_attention.masked_gqa(q, k, v, mask)
     else:
-        out = _scores_in_blocks(q, k, v, cfg.attn_q_block)
+        out = _scores_in_blocks(q, k, v, cfg.attn_q_block, mask)
     return _dot(out.reshape(n, t, hq * hd), p["o_proj"])
 
 
@@ -324,15 +346,20 @@ def dense_ffn(p, u):
 # the expert layer
 # ---------------------------------------------------------------------------
 
+def _chosen_scores(s, experts, num_experts: int):
+    """The scores ``s`` ``(N, E)`` of the ``experts`` ``(N, k)`` chosen, by
+    a compare and a sum (a gather of ``N * k`` single numbers runs one at a
+    time on the chip)."""
+    chosen = experts[..., None] == jnp.arange(num_experts)
+    return jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
+
+
 def route(p, bias, x, cfg: Config):
     """``(experts, gates)`` of every token of ``x`` ``(N, d)``: ``(N, k)``
     expert ids among all ``num_experts`` and their float32 weights."""
     s = jax.nn.sigmoid(_dot(x, p["router"]).astype(jnp.float32))
     _, experts = lax.top_k(s + bias, cfg.num_experts_per_tok)
-    # the chosen scores by a compare and a sum (a gather of N*k single
-    # numbers runs one at a time on the chip)
-    chosen = experts[..., None] == jnp.arange(cfg.num_experts)
-    g = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
+    g = _chosen_scores(s, experts, cfg.num_experts)
     g = g / (jnp.sum(g, axis=-1, keepdims=True) + cfg.route_eps)
     return experts, g * cfg.routed_scaling_factor
 
@@ -604,8 +631,10 @@ def _held_experts_bwd(sizes, res, dy):
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _route_and_sort(p, state, u, cfg: Config, sizes):
-    """Route the tokens ``u`` ``(N, d)``, sort their assignments by held
+def _route_and_sort(p, state, u, cfg: Config, sizes, router):
+    """Route the tokens ``u`` ``(N, d)`` (by ``router``, :func:`route` or
+    another with its signature, which is handed the state's ``expert_bias``
+    where it has one), sort their assignments by held
     expert and lay tiles over the sorted rows, each held expert's first
     tile at its first row; sort the held rows back by token and lay chunks
     over the windows of tokens: what :func:`held_experts` takes, and the
@@ -617,7 +646,7 @@ def _route_and_sort(p, state, u, cfg: Config, sizes):
     n_tokens, n_slots = u.shape[0], u.shape[0] * k
     n_windows = -(-n_tokens // window)
     with jax.named_scope(STAGE_MOE_ROUTER):
-        experts, gates = route(p, state["expert_bias"], u, cfg)
+        experts, gates = router(p, state.get("expert_bias"), u, cfg)
         drawn = _count(experts.reshape(-1), cfg.num_experts)
     with jax.named_scope(STAGE_MOE_DISPATCH):
         local = experts.reshape(-1) - cfg.first_expert
@@ -642,7 +671,7 @@ def _route_and_sort(p, state, u, cfg: Config, sizes):
         by_token = (jnp.pad(slot // k % window, (0, tile)),
                     jnp.pad(row_of, (0, tile)), chunks, chunks_in_use)
     held_rows = jnp.sum(tiles[2]).astype(jnp.float32)
-    counters = {"expert_bias": state["expert_bias"],
+    counters = {"expert_bias": state.get("expert_bias"),
                 "drawn": drawn.astype(jnp.float32), "held": held_rows,
                 "computed": (in_use * tile).astype(jnp.float32),
                 "combined": (chunks_in_use * tile).astype(jnp.float32),
@@ -653,13 +682,16 @@ def _route_and_sort(p, state, u, cfg: Config, sizes):
             {name: counters[name] for name in state})
 
 
-def moe_ffn(p, state, u, cfg: Config):
+def moe_ffn(p, state, u, cfg: Config, router=None):
     """The held experts' part of the expert layer's result for normalised
-    ``u`` ``(n, T, d)``, and the layer's new state (the counters)."""
+    ``u`` ``(n, T, d)``, and the layer's new state (the counters).
+    ``router``: another decoder's, with :func:`route`'s signature, in place
+    of this module's."""
     x = u.reshape(-1, u.shape[-1])
     tile = _tile_rows(cfg, x.shape[0])
     sizes = (tile, _window_tokens(cfg, x.shape[0], tile))
-    sorted_rows, counters = _route_and_sort(p, state, x, cfg, sizes)
+    sorted_rows, counters = _route_and_sort(p, state, x, cfg, sizes,
+                                            router or route)
     y = held_experts(sizes, {k: p[k] for k in ("w1", "w3", "w2")}, x,
                      *sorted_rows)
     return y.reshape(u.shape), counters
@@ -716,14 +748,20 @@ def hidden_states(params, model_state, ids, cfg: Config,
 
 
 def _head_part(cfg):
+    """Final norm, head and each sequence's summed cross-entropy of ``(x,
+    targets)`` or, with a float32 weight a position, ``(x, targets,
+    weights)``."""
     def part(p, xt):
-        x, targets = xt
+        x, targets, *weights = xt
         with jax.named_scope(STAGE_LM_HEAD):
             u = L.rms_apply(p["final_norm"], x, cfg.norm_eps)
             logits = _dot(u, p["head"]).astype(jnp.float32)
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-            return jnp.sum(nll[..., 0], axis=-1)
+            nll = nll[..., 0]
+            for w in weights:
+                nll = nll * w
+            return jnp.sum(nll, axis=-1)
     return part
 
 

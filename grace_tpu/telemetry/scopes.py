@@ -33,7 +33,8 @@ __all__ = ["trace_stage", "match_stage", "ALL_STAGES",
            "STAGE_PIPELINE", "STAGE_ATTENTION", "STAGE_SHORT_CONV",
            "STAGE_DENSE_FFN", "STAGE_MOE_ROUTER", "STAGE_MOE_DISPATCH",
            "STAGE_MOE_EXPERTS", "STAGE_MOE_COMBINE", "STAGE_LM_HEAD",
-           "STAGE_MLA_LATENT", "STAGE_SHARED_EXPERT", "MODEL_STAGES"]
+           "STAGE_MLA_LATENT", "STAGE_SHARED_EXPERT", "STAGE_DIFFUSION_NOISE",
+           "MODEL_STAGES"]
 
 # Canonical stage names — one vocabulary for the profiler, the report tool,
 # and the docs. Keep in sync with README "Observability".
@@ -107,10 +108,13 @@ STAGE_LM_HEAD = "grace/lm_head"                # final norm, head, loss
 STAGE_MLA_LATENT = "grace/mla_latent"
 # The expert every token passes and every chip of a layer computes whole.
 STAGE_SHARED_EXPERT = "grace/shared_expert"
+# Block-diffusion training's draws of a step (models/sdar.py): each block's
+# noise level, each token's mask, the noised copy and the loss's weights.
+STAGE_DIFFUSION_NOISE = "grace/diffusion_noise"
 MODEL_STAGES = (STAGE_ATTENTION, STAGE_SHORT_CONV, STAGE_DENSE_FFN,
                 STAGE_MOE_ROUTER, STAGE_MOE_DISPATCH, STAGE_MOE_EXPERTS,
                 STAGE_MOE_COMBINE, STAGE_LM_HEAD, STAGE_MLA_LATENT,
-                STAGE_SHARED_EXPERT)
+                STAGE_SHARED_EXPERT, STAGE_DIFFUSION_NOISE)
 
 # The canonical stage vocabulary, longest-prefix-matchable: the profiler,
 # tools/telemetry_report.py, and the static auditor's finding attribution

@@ -1,10 +1,10 @@
-"""The static decisions the six compiled steps depend on, at the cells' own
-shapes.
+"""The static decisions the seven compiled steps depend on, at the cells'
+own shapes.
 
 Every route the transform takes for a leaf follows from static facts: the
 cell's ``grace`` parameters, the leaf's shape, the world size. ``PERF.md``
 states them in prose ("no ResNet-50 leaf takes the row-slices route at W=1",
-"27 of LFM2's 50 leaves do", "32 of kanana's 69"); here they are assertions, on parameter trees
+"27 of LFM2's 50 leaves do", "32 of kanana's 69", "22 of SDAR's 51"); here they are assertions, on parameter trees
 taken with ``jax.eval_shape`` from the benchmark's own builders at the sizes
 in ``benchmarks/configs/*.json`` (read, never edited; no weight is made).
 The expected values are written down, not computed by the code under test:
@@ -48,6 +48,9 @@ CELLS = {
     "kanana-2-30b-a3b-topk1pct-w1": ("kanana-2-30b-a3b-ep16",
                                      "TopKCompressor", "ResidualMemory",
                                      "Allgather"),
+    "sdar-30b-a3b-blockdiff-topk1pct-w1": ("sdar-30b-a3b-ep8",
+                                           "TopKCompressor",
+                                           "ResidualMemory", "Allgather"),
 }
 
 # configuration -> (leaves, parameters, leaves on the row-slices route under
@@ -57,6 +60,7 @@ CONFIGS = {
     "lfm2-24b-a2b-ep8": (50, 486_062_208, 27, 38_884_848),
     "bert-base-squad": (150, 108_793_346, None, 3_369_912),
     "kanana-2-30b-a3b-ep16": (69, 424_960_512, 32, 33_996_704),
+    "sdar-30b-a3b-ep8": (51, 456_346_624, 22, 36_507_584),
 }
 
 # Top-k 1 % chunk, per distinct leaf shape:
@@ -130,6 +134,19 @@ TOPK_LEAVES = {
         ((8, 768, 2048), 4, 12582912, 125829, 101, True),
         ((8, 2048, 768), 8, 12582912, 125829, 101, True),
     ],
+    "sdar-30b-a3b-ep8": [
+        ((128,), 8, 128, 1, 128, False),            # the heads' q and k norms
+        ((2048,), 9, 2048, 20, 103, False),
+        ((2048, 128), 4, 262144, 2621, 101, False),         # the router
+        ((2048, 512), 8, 1048576, 10485, 101, False),       # W_k, W_v
+        ((2048, 4096), 4, 8388608, 83886, 101, True),       # W_q
+        ((4096, 2048), 4, 8388608, 83886, 101, True),       # W_o
+        ((2048, 18992), 1, 38895616, 388956, 101, True),
+        ((18992, 2048), 1, 38895616, 388956, 101, True),
+        # sixteen experts a stack: LFM2's stack of eight at twice the width
+        ((16, 768, 2048), 4, 25165824, 251658, 101, True),
+        ((16, 2048, 768), 8, 25165824, 251658, 101, True),
+    ],
 }
 
 # PowerSGD rank 4 on BERT-base, per distinct leaf shape: (shape, leaves of
@@ -140,6 +157,7 @@ TOPK_LEAVES = {
 CODEC_CELL = {"resnet50-imagenet": "resnet50-topk1pct-w1",
               "lfm2-24b-a2b-ep8": "lfm2-24b-a2b-topk1pct-w1",
               "kanana-2-30b-a3b-ep16": "kanana-2-30b-a3b-topk1pct-w1",
+              "sdar-30b-a3b-ep8": "sdar-30b-a3b-blockdiff-topk1pct-w1",
               "bert-base-squad": "bert-base-powersgd4-w1"}
 
 POWERSGD_LEAVES = [

@@ -26,11 +26,13 @@ from grace_tpu.models import deepseek_v3
 from grace_tpu.models import layers as L
 from grace_tpu.models import lfm2
 from grace_tpu.ops import pallas_attention
-from grace_tpu.ops.pallas_attention import TILE, causal_gqa, engages
+from grace_tpu.ops.pallas_attention import (CAUSAL, TILE, BlockDiffusion,
+                                            causal_gqa, engages, masked_gqa)
 
 T = 2 * TILE
 D = 64
 GQA, MLA = (64, 64), (192, 128)       # queries and keys | values
+SDAR = (128, 128)
 HKV = 2
 
 
@@ -45,10 +47,11 @@ def _inputs(group, dtype, t=T, key=0, dims=GQA):
             normal(ks[2], HKV, dims[1]), normal(ks[3], HKV * group, dims[1]))
 
 
-def _plain(q, k, v):
+def _plain(q, k, v, mask=CAUSAL):
     n, t, hq, d = q.shape
     hkv = k.shape[2]
-    out = lfm2._scores_block(q.reshape(n, t, hkv, hq // hkv, d), k, v, 0)
+    out = lfm2._scores_block(q.reshape(n, t, hkv, hq // hkv, d), k, v, 0,
+                             mask)
     return out.reshape(n, t, hq, v.shape[-1])
 
 
@@ -122,6 +125,92 @@ def test_the_kernel_is_causal(dims):
     assert not np.allclose(np.asarray(out[:, cut:]), np.asarray(out2[:, cut:]))
 
 
+# The doubled sequence of block diffusion at the kernel's least size: one
+# tile of noised positions and one of clean ones, blocks of 4. Of the four
+# tiles the noised diagonal is masked inside to 4 keys a query, noised
+# queries over clean keys and clean over clean are block-causal, and clean
+# queries over noised keys are skipped.
+BLOCK_MASK = BlockDiffusion(TILE, 4)
+
+
+@pytest.mark.parametrize("dims, group", [(SDAR, 4), (GQA, 1)],
+                         ids=["sdar128-gqa4", "mha64"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_kernel_agrees_with_the_plain_spelling_under_the_block_mask(
+        dtype, dims, group):
+    """Output and the gradients of ``q``, ``k`` and ``v`` under the
+    block-diffusion mask, at Qwen3's head size of 128 (``1 / sqrt(128)`` is
+    no power of two: ``q`` is handed over scaled through float32, one
+    rounding more than the plain spelling's scaled scores, inside
+    bfloat16's tolerance) and at 64; the causal cases above are what they
+    were."""
+    q, k, v, w = _inputs(group, dtype, dims=dims)
+    kernel = _weighted(lambda q, k, v: masked_gqa(
+        _scaled(q), k, v, BLOCK_MASK, interpret=True))
+    (_, out), grads = kernel(q, k, v, w)
+    (_, want), want_grads = _weighted(
+        functools.partial(_plain, mask=BLOCK_MASK))(q, k, v, w)
+    assert out.dtype == dtype and out.shape == q.shape[:3] + (dims[1],)
+    tol = TOLERANCE[dtype]
+    assert _gap(out, want) < tol
+    for name, got, ref in zip("qkv", grads, want_grads):
+        assert got.dtype == dtype and got.shape == ref.shape
+        assert _gap(got, ref) < tol, name
+    # and it is another function than the causal one
+    assert _gap(out, _plain(q, k, v)) > 0.1
+
+
+def test_the_kernel_reads_what_the_block_mask_allows_and_no_more():
+    """Changing the keys a query may not read changes nothing it gives:
+    for the noised query at token 5 (block 1) everything but noised 4-7 and
+    clean 0-3; for the clean copy every noised key."""
+    q, k, v, _ = _inputs(4, jnp.float32, dims=SDAR)
+    out = masked_gqa(q, k, v, BLOCK_MASK, interpret=True)
+    readable = np.zeros(T, bool)
+    readable[4:8] = readable[TILE:TILE + 4] = True
+    k2 = jnp.where(readable[None, :, None, None], k, 7.0)
+    v2 = jnp.where(readable[None, :, None, None], v, -3.0)
+    out2 = masked_gqa(q, k2, v2, BLOCK_MASK, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out[:, 4:8]),
+                                  np.asarray(out2[:, 4:8]))
+    assert not np.allclose(np.asarray(out[:, 8:12]), np.asarray(out2[:, 8:12]))
+    noised = np.arange(T) < TILE
+    out3 = masked_gqa(q, jnp.where(noised[None, :, None, None], 7.0, k),
+                      jnp.where(noised[None, :, None, None], -3.0, v),
+                      BLOCK_MASK, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out[:, TILE:]),
+                                  np.asarray(out3[:, TILE:]))
+
+
+@pytest.mark.parametrize("seq_len, block, visited", [
+    (4096, 4, 24), (4096, 1024, 20), (1024, 4, 3), (2048, 4, 8)])
+def test_tiles_without_an_allowed_pair_are_not_visited(seq_len, block,
+                                                       visited):
+    """The kernel's own table of tiles (0: skipped) under the
+    block-diffusion mask over ``2 * seq_len`` positions: 4 + 10 + 10 of the
+    64 tiles of 1,024 a doubled sequence of 8,192 has, where the causal
+    mask over as many positions visits 36; forward and fused backward
+    alike. The mask is evaluated from positions: the kernel carries no
+    mask blocks."""
+    total = 2 * seq_len
+    kernel = pallas_attention._kernel(total, 2, True,
+                                      BlockDiffusion(seq_len, block))
+    for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
+        assert int((np.asarray(info.block_mask) > 0).sum()) == visited
+        assert info.partial_mask_blocks is None and info.q_sequence is not None
+    tiles = total // TILE
+    causal = pallas_attention._kernel(total, 2, True)
+    assert int((np.asarray(causal.fwd_mask_info.block_mask) > 0).sum()) \
+        == tiles * (tiles + 1) // 2
+
+
+def test_a_mask_over_another_length_is_refused():
+    q = jnp.zeros((1, T, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match="positions"):
+        masked_gqa(q, q, q, BlockDiffusion(TILE // 2, 4), interpret=True)
+
+
 @pytest.mark.parametrize("seq_len, dims, dtype, platform, taken", [
     (4096, GQA, jnp.bfloat16, "tpu", True),
     (TILE, GQA, jnp.float32, "tpu", True),
@@ -135,12 +224,17 @@ def test_the_kernel_is_causal(dims):
     (16, (12, 8), jnp.float32, "tpu", False),     # deepseek_v3.tiny()
     (4096, (8, 8), jnp.bfloat16, "tpu", False),
     (4096, (192, 192), jnp.bfloat16, "tpu", False),   # values as wide as keys
-    (4096, (128, 128), jnp.bfloat16, "tpu", False),   # no pair of the two
+    (4096, (128, 128), jnp.bfloat16, "tpu", True),    # Qwen3's heads (PR 41)
     (4096, (64, 128), jnp.bfloat16, "tpu", False),
     (4096, GQA, jnp.float16, "tpu", False),
+    (8192, SDAR, jnp.bfloat16, "tpu", True),          # a doubled sequence
+    (8192, SDAR, jnp.bfloat16, "cpu", False),
+    (4096, (128, 64), jnp.bfloat16, "tpu", False),    # no pair of the three
+    (32, (8, 8), jnp.float32, "tpu", False),          # sdar.tiny()
 ], ids=["lfm2-cell", "one-tile-f32", "kanana-cell", "mla-one-tile-f32", "cpu",
         "here", "part-tile", "mla-part-tile", "tiny", "mla-tiny", "head8",
-        "192-192", "128-128", "64-128", "float16"])
+        "192-192", "128-128", "64-128", "float16", "sdar-cell", "sdar-cpu",
+        "128-64", "sdar-tiny"])
 def test_who_takes_the_kernel(seq_len, dims, dtype, platform, taken):
     assert engages(seq_len, *dims, dtype, platform) is taken
 

@@ -51,8 +51,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
     sys.path.insert(0, REPO)
 
+from benchmarks import harness  # noqa: E402
 from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
+from benchmarks.reference import train as plain_train  # noqa: E402
 from benchmarks.trace_reduce import stage_of  # noqa: E402
 
 N = 25_557_032            # ResNet-50's flat gradient
@@ -748,3 +750,99 @@ def test_row_slices_engagement_count_in_lowered_step(model, params, leaves,
     assert scope_engagements(
         text, "grace/decompress/row_slices/jit(row_blocks_dense)"
     ) == 2 * engaged
+
+
+# ---------------------------------------------------------------------------
+# whole steps of the benchmark's decoder cells, as `harness.Program` builds
+# them, compiled for the described chip (PR 41)
+# ---------------------------------------------------------------------------
+
+def _whole_step(cell_name, topo, kernels_on, monkeypatch):
+    """The cell's step built as ``benchmarks/harness.Program`` builds it
+    (the cell's transform and optimizer, ``make_stateful_train_step``), on
+    abstract state placed on the described chip, compiled: ``(text, bytes
+    the step holds as ``Program.hbm_program_bytes`` counts them)``."""
+    import optax
+    from grace_tpu.train import (init_stateful_train_state,
+                                 make_stateful_train_step)
+    from grace_tpu.transform import partition_specs
+
+    catalog = harness.Catalog()
+    cell = catalog.cell(cell_name)
+    config = catalog.config(cell["config"])
+    builder = catalog.builder(config)
+    _as_on_the_chip(monkeypatch)
+    key = jax.random.key(0)
+    here = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    tx = optax.chain(grace_from_params(dict(cell["grace"])).transform(seed=0),
+                     plain_train.optimizer(cell["optimizer"]))
+    params, mstate = jax.eval_shape(lambda k: builder.init(k, config), key)
+    state = jax.eval_shape(
+        lambda p, m: init_stateful_train_state(p, m, tx, here), params, mstate)
+    state = jax.tree_util.tree_map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, partition_specs(state, "data"))
+    batch = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, P("data"))),
+        jax.eval_shape(lambda k: builder.make_batch(
+            k, config["per_chip_batch"], config), key))
+    step = make_stateful_train_step(builder.program_loss(config), tx, mesh)
+    jax.eval_shape(step, state, batch)
+    compiled = next(iter(step.jit_cache.values())).lower(state,
+                                                         batch).compile()
+    m = compiled.memory_analysis()
+    return compiled.as_text(), (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def test_the_sdar_step_compiles_for_the_described_chip(topo, kernels_on,
+                                                       monkeypatch):
+    """The whole step of ``sdar-30b-a3b-blockdiff-topk1pct-w1`` from the
+    CPU: Mosaic takes the kernel at heads of 128 | 128 under the
+    block-diffusion mask over 8,192 positions, twice a layer (forward and
+    the fused backward, the kept output read in place of a second forward),
+    each under ``grace/attention``; no block of float32 scores is left; the
+    noise is drawn inside the step under its stage; and the step leaves room
+    on the chip for the harness's copy of the start parameters (1.83 GB)
+    under the runtime's 16.91 GB."""
+    text, held = _whole_step("sdar-30b-a3b-blockdiff-topk1pct-w1", topo,
+                             kernels_on, monkeypatch)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == 4 * KEPT[:1] + 4 * KEPT[1:]
+    assert all("grace/attention" in name for name in op_names), op_names
+    assert "bf16[32,8192,128]" in text and "bf16[4,8192,128]" in text
+    assert not re.search(r"f32\[(?:1,)?(?:32|4,8),1024,8192\]", text)
+    assert "grace/diffusion_noise" in text
+    assert held + 456_346_624 * 4 < 16.91e9
+    assert held > 0.75 * 16e9              # three quarters of the chip
+
+
+# (kernel calls, bytes the compiled step holds) of the two causal decoder
+# cells at the parent of PR 41 (commit 4f95434), compiled here for the
+# described chip by the same helper: the mask handed to the kernel as a
+# value, position ids, a router handed to the walk and a weighted head
+# changed neither step (their texts, metadata and the kernels' embedded
+# source locations apart, were compared whole by hand: PERF.md section 6).
+CAUSAL_STEPS = {
+    "lfm2-24b-a2b-topk1pct-w1": (1, 12_865_857_024),
+    "kanana-2-30b-a3b-topk1pct-w1": (5, 14_399_759_872)}
+
+
+# Marked slow (outside tier-1): the two whole steps take 135 s and 90 s to
+# compile alone and, with the SDAR step, 444 s beside three busy workers,
+# which this one file's worker cannot spend inside tier-1's time limit.
+@pytest.mark.slow
+@pytest.mark.parametrize("cell", sorted(CAUSAL_STEPS))
+def test_the_causal_cells_steps_are_what_they_were(topo, kernels_on,
+                                                   monkeypatch, cell):
+    layers, held = CAUSAL_STEPS[cell]
+    text, got = _whole_step(cell, topo, kernels_on, monkeypatch)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == layers * KEPT[:1] + layers * KEPT[1:]
+    assert all("grace/attention" in name for name in op_names)
+    assert got == held
+    assert "grace/diffusion_noise" not in text
