@@ -309,7 +309,7 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
     n, t, _ = u.shape
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    fused = pallas_attention.engages(t, hd, hd, u.dtype)
+    fused = pallas_attention.engages(t, hd, hd, u.dtype, mask=mask)
     # The kernel applies no scale. 1/sqrt(64) is a power of two, so q times
     # it is exact in q's dtype; 1/sqrt(128) is none, and goes into the
     # float32 weight of the queries' norm, so that q is rounded as often

@@ -42,8 +42,14 @@ What is shared with ``models/lfm2.py`` is imported from it, not copied:
 attention's projections, norms and two spellings of the scores (the fused
 kernel where ``ops.pallas_attention.engages`` says so, ``attn_q_block``
 queries at a time everywhere else), the expert layer's walk, the walk over
-sequences, the head part. Memory as there: every part is recomputed in the
-backward pass from its input but for the kernel's output and log-sum-exp.
+sequences, the head part. Under this mask the fused path hands the kernel
+all ``2 L`` queries over the clean copy's ``L`` keys alone, of which every
+query reads a prefix (one comparison a pair), scores a noised query's own
+block of ``block_length`` noised keys beside it and merges the two by
+log-sum-exp (``ops/pallas_attention.py``); the plain path evaluates the
+whole mask pair by pair. Memory as there: every part is recomputed in the
+backward pass from its input but for the merged output and the joint
+log-sum-exp, which the kernel's backward reads.
 
 Model state: ``step`` (the steps taken, the noise's counter), ``masked``
 (positions the last step scored: the masked tokens of all sequences) and,

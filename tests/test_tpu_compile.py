@@ -37,7 +37,7 @@ import grace_tpu.ops
 from grace_tpu import grace_from_params
 from grace_tpu.compressors.topk import static_k
 from grace_tpu.memories import ResidualMemory
-from grace_tpu.models import deepseek_v3, lfm2, resnet
+from grace_tpu.models import deepseek_v3, lfm2, resnet, sdar
 from grace_tpu.ops import pallas_attention, sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
                                         quantize_stochastic, sign_pack)
@@ -54,6 +54,7 @@ if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
 from benchmarks import harness  # noqa: E402
 from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
+from benchmarks.models import sdar_moe  # noqa: E402
 from benchmarks.reference import train as plain_train  # noqa: E402
 from benchmarks.trace_reduce import stage_of  # noqa: E402
 
@@ -390,11 +391,17 @@ SCORE_BLOCK = re.compile(r"f32\[(?:1,)?(?:32|8,4),1024,(?:1024|2048|3072|4096)\]
 # kept for the backward pass, and in one recomputed whole
 KEPT = ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"]
 RECOMPUTED = KEPT + ["splash_mha_fwd_residuals"]
+# under the block-diffusion mask, beside each of the fused kernel's calls the
+# own blocks' kernel of that direction (PR 42): other names, so that the
+# benchmark's readers, which find the fused kernel's calls by their names'
+# beginning, count the fused kernel alone
+OWN_BLOCK = ["block_diffusion_own_block_bwd", "block_diffusion_own_block_fwd"]
 
 
-def _part_text(part, layer_shapes, cfg, one_chip, sequences=1):
+def _part_text(part, layer_shapes, cfg, one_chip, sequences=1,
+               positions=4096):
     """One part of a decoder layer on ``sequences`` sequences (4,096 x
-    hidden, bfloat16), as the step runs it: one sequence after another,
+    hidden unless told, bfloat16), as the step runs it: one sequence after another,
     recomputed from its input but for what the fused kernel names, forward
     and gradient, compiled for the described chip."""
     def loss(p, x):
@@ -404,7 +411,7 @@ def _part_text(part, layer_shapes, cfg, one_chip, sequences=1):
     p = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         layer_shapes)
-    x = jax.ShapeDtypeStruct((sequences, 4096, cfg.hidden_size),
+    x = jax.ShapeDtypeStruct((sequences, positions, cfg.hidden_size),
                              jnp.bfloat16, sharding=one_chip)
     return compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
 
@@ -511,6 +518,76 @@ def test_latent_attention_still_compiles_without_the_kernel(one_chip):
     text = _mla_part_text(one_chip)
     assert "tpu_custom_call" not in text
     assert SCORE_BLOCK.search(text)
+
+
+# ---------------------------------------------------------------------------
+# the same kernel under the block-diffusion mask: a rectangle over the clean
+# keys, a noised query's own block beside it (PR 42)
+# ---------------------------------------------------------------------------
+
+def _sdar_part_text(one_chip, sequences=1):
+    """Attention of the benchmark's SDAR configuration (32 query heads over
+    4 key/value heads of 128) over a doubled sequence of 8,192 positions
+    in blocks of 4."""
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "sdar-30b-a3b-ep8.json")) as f:
+        sizes = json.load(f)
+    cfg = sdar_moe.model_config(sizes)
+    length = sizes["seq_length"]
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+            length, cfg.block_length) == (32, 4, 128, 4096, 4)
+    shapes = jax.eval_shape(lambda k: sdar.init(k, cfg)[0], jax.random.key(0))
+    part = sdar._attention_part(
+        cfg, pallas_attention.BlockDiffusion(length, cfg.block_length),
+        np.tile(np.arange(length), 2))
+    return _part_text(part, shapes["layers"][0], cfg, one_chip, sequences,
+                      positions=2 * length)
+
+
+def test_block_diffusion_attention_compiles_to_one_kernel_a_kind(
+        one_chip, monkeypatch):
+    """Mosaic takes the rectangle, all 8,192 queries over the clean copy's
+    4,096 keys and values, and the part holds the fused kernel once a kind:
+    ``splash_mha_fwd_residuals`` and the fused backward
+    ``splash_mha_dkv_no_residuals`` from the merged output and the joint
+    log-sum-exp (the benchmark's readers find the calls by those names, one
+    a layer and kind). Beside each, the own blocks' kernel of that direction
+    under a name of its own, which Mosaic takes at tiles of 128 noised
+    positions and eight query heads a step; all four under
+    ``grace/attention``. No block of float32 scores and nothing of 8,192 x
+    8,192 is in the text, and the fused backward's partial ``dq`` is a key
+    tile's each: four, where the square had eight."""
+    _as_on_the_chip(monkeypatch)
+    text = _sdar_part_text(one_chip)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == OWN_BLOCK + KEPT
+    assert all("grace/attention" in name for name in op_names), op_names
+    assert "bf16[32,8192,128]" in text and "bf16[4,4096,128]" in text
+    assert "bf16[4,32,8192,128]" in text and "bf16[8,32,8192,128]" not in text
+    assert not re.search(r"f32\[(?:1,)?(?:32|4,8),1024,(?:4096|8192)\]", text)
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+
+
+def test_block_diffusion_attention_still_compiles_without_the_kernel(
+        one_chip):
+    """Where ``engages`` says no (here: a CPU process), the part compiles
+    for the chip from the plain spelling under the whole mask."""
+    text = _sdar_part_text(one_chip)
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[(?:1,)?(?:32|4,8),1024,8192\]", text)
+
+
+def test_the_block_diffusion_part_keeps_the_merged_pair(one_chip,
+                                                        monkeypatch):
+    """Walked over two sequences the part keeps the merged output and the
+    joint log-sum-exp of both, a row a sequence, and runs the kernel twice a
+    sequence as the causal decoders' parts do."""
+    _as_on_the_chip(monkeypatch)
+    text = _sdar_part_text(one_chip, sequences=2)
+    assert _kernel_calls(text)[0] == OWN_BLOCK + KEPT
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert sum("bf16[2,1,32,8192,128]" in line
+               and "f32[2,1,32,8192]" in line for line in loops) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -803,8 +880,10 @@ def test_the_sdar_step_compiles_for_the_described_chip(topo, kernels_on,
                                                        monkeypatch):
     """The whole step of ``sdar-30b-a3b-blockdiff-topk1pct-w1`` from the
     CPU: Mosaic takes the kernel at heads of 128 | 128 under the
-    block-diffusion mask over 8,192 positions, twice a layer (forward and
-    the fused backward, the kept output read in place of a second forward),
+    block-diffusion mask (all 8,192 queries over the clean copy's 4,096
+    keys, PR 42), twice a layer (forward and the fused backward, the kept
+    merged output read in place of a second forward), the own blocks'
+    kernel of that direction beside each call,
     each under ``grace/attention``; no block of float32 scores is left; the
     noise is drawn inside the step under its stage; and the step leaves room
     on the chip for the harness's copy of the start parameters (1.83 GB)
@@ -812,9 +891,11 @@ def test_the_sdar_step_compiles_for_the_described_chip(topo, kernels_on,
     text, held = _whole_step("sdar-30b-a3b-blockdiff-topk1pct-w1", topo,
                              kernels_on, monkeypatch)
     kernels, op_names = _kernel_calls(text)
-    assert kernels == 4 * KEPT[:1] + 4 * KEPT[1:]
+    assert kernels == sorted(4 * (OWN_BLOCK + KEPT))
     assert all("grace/attention" in name for name in op_names), op_names
-    assert "bf16[32,8192,128]" in text and "bf16[4,8192,128]" in text
+    # the kernel's queries are all 8,192 positions, its keys and values
+    # the clean copy's 4,096 (PR 42)
+    assert "bf16[32,8192,128]" in text and "bf16[4,4096,128]" in text
     assert not re.search(r"f32\[(?:1,)?(?:32|4,8),1024,8192\]", text)
     assert "grace/diffusion_noise" in text
     assert held + 456_346_624 * 4 < 16.91e9
