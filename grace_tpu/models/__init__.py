@@ -28,12 +28,21 @@ checkpoints with orbax alongside them.
                       the step's noise drawn on the device (attention, the
                       expert walk and the head are ``lfm2``'s); the
                       benchmark's ``sdar-30b-a3b-ep8`` configuration
+* ``smallthinker``  — SmallThinker causal decoder: windowed rotary layers
+                      beside full-attention layers without positions (a
+                      layer's mask and whether it rotates are the config's
+                      two layouts), a softmax router that reads the layer's
+                      input before attention runs, ReLU-gated experts
+                      (attention, the expert walk and the head are
+                      ``lfm2``'s, the router ``sdar``'s); the benchmark's
+                      ``smallthinker-21b-a3b-ep8`` configuration
 * ``vgg``           — VGG-11/13/16/19 (the communication-bound classic of the
                       reference's synthetic-benchmark model list)
 """
 
 from grace_tpu.models import (deepseek_v3, layers, lenet, lfm2, resnet,
-                              resnet_cifar, sdar, transformer, vgg)
+                              resnet_cifar, sdar, smallthinker, transformer,
+                              vgg)
 
 __all__ = ["deepseek_v3", "layers", "lenet", "lfm2", "resnet", "resnet_cifar",
-           "sdar", "transformer", "vgg"]
+           "sdar", "smallthinker", "transformer", "vgg"]

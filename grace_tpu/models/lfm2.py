@@ -265,15 +265,17 @@ def short_conv(p, u, cfg: Config):
     return _dot(c * conv, p["out_proj"])
 
 
-def _scores_block(q, k, v, start, mask=pallas_attention.CAUSAL):
+def _scores_block(q, k, v, start, mask=pallas_attention.CAUSAL, first=0):
     """Attention under ``mask`` of one block of queries, at positions
-    ``start`` on, over the keys given (the first of the sequence: under the
-    causal mask those up to the block's end). ``q``: ``(n, Q, Hkv, G, D)``;
-    ``k``, ``v``: ``(n, K, Hkv, D)``."""
+    ``start`` on, over the keys given, those at positions ``first`` on
+    (under the causal mask the sequence's first up to the block's end; under
+    a window those the block's first query reads, on). ``q``: ``(n, Q, Hkv,
+    G, D)``; ``k``, ``v``: ``(n, K, Hkv, D)``."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("nqhgd,nkhd->nhgqk", q, k).astype(jnp.float32) * scale
     qpos = start + jnp.arange(q.shape[1])
-    allowed = mask.allowed(qpos[:, None], jnp.arange(k.shape[1])[None, :])
+    allowed = mask.allowed(qpos[:, None],
+                           jnp.arange(first, first + k.shape[1])[None, :])
     s = jnp.where(allowed, s, -jnp.inf)
     a = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("nhgqk,nkhd->nqhgd", a, v)
@@ -281,30 +283,33 @@ def _scores_block(q, k, v, start, mask=pallas_attention.CAUSAL):
 
 def _scores_in_blocks(q, k, v, q_block: int, mask=pallas_attention.CAUSAL):
     """Attention under ``mask`` (``ops.pallas_attention``'s: the causal one,
-    or block diffusion's) ``q_block`` queries at a time, each block's
-    ``(heads, queries, keys)`` scores made, normalised and multiplied into
-    the values by XLA: the plain spelling, and what the kernel is tested
-    against. ``q``: ``(n, T, Hq, D)``; ``k``: ``(n, T, Hkv, D)``; ``v``:
-    ``(n, T, Hkv, Dv)``, and so the result's heads."""
+    a sliding window, or block diffusion's) ``q_block`` queries at a time
+    over the keys the block can read, each block's ``(heads, queries,
+    keys)`` scores made, normalised and multiplied into the values by XLA:
+    the plain spelling, and what the kernel is tested against. ``q``: ``(n,
+    T, Hq, D)``; ``k``: ``(n, T, Hkv, D)``; ``v``: ``(n, T, Hkv, Dv)``, and
+    so the result's heads."""
     n, t, hq, hd = q.shape
     hkv = k.shape[2]
     q = q.reshape(n, t, hkv, hq // hkv, hd)
-    block = jax.checkpoint(_scores_block, static_argnums=(3, 4))
+    block = jax.checkpoint(_scores_block, static_argnums=(3, 4, 5))
     out = []
     for s in range(0, t, q_block):
-        keys = mask.keys_read(s + q_block, t)
-        out.append(block(q[:, s:s + q_block], k[:, :keys], v[:, :keys], s,
-                         mask))
+        first, stop = mask.first_key(s), mask.keys_read(s + q_block, t)
+        out.append(block(q[:, s:s + q_block], k[:, first:stop],
+                         v[:, first:stop], s, mask, first))
     return jnp.concatenate(out, axis=1).reshape(n, t, hq, v.shape[-1])
 
 
 def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
-              positions=None):
+              positions=None, rotate: bool = True):
     """Grouped-query self-attention of normalised ``u`` under ``mask``
     (causal unless told otherwise), rotated by ``positions`` (``0 .. T -
-    1`` unless given). On a TPU, a sequence of whole tiles at a head size
-    the fused kernel takes goes through it (``ops.pallas_attention``: no
-    score tensor in HBM, its own backward pass); everything else through
+    1`` unless given) unless ``rotate`` is false (a layer without
+    positions); the query and key heads are normalised where ``p`` holds
+    ``q_norm`` and ``k_norm``. On a TPU, a sequence of whole tiles at a head
+    size the fused kernel takes goes through it (``ops.pallas_attention``:
+    no score tensor in HBM, its own backward pass); everything else through
     :func:`_scores_in_blocks`."""
     n, t, _ = u.shape
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -312,20 +317,27 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
     fused = pallas_attention.engages(t, hd, hd, u.dtype, mask=mask)
     # The kernel applies no scale. 1/sqrt(64) is a power of two, so q times
     # it is exact in q's dtype; 1/sqrt(128) is none, and goes into the
-    # float32 weight of the queries' norm, so that q is rounded as often
-    # as the plain spelling's.
+    # float32 weight of the queries' norm or, without a norm, into the
+    # float32 weights of the queries' projection as they are cast, so that
+    # q is rounded as often as the plain spelling's.
     scale = 1.0 / math.sqrt(hd)
     exact = math.frexp(scale)[0] == 0.5
-    q_norm = p["q_norm"]
+    q_norm, q_proj = p.get("q_norm"), p["q_proj"]
     if fused and not exact:
-        q_norm = {"scale": q_norm["scale"] * scale}
-    q = _dot(u, p["q_proj"]).reshape(n, t, hq, hd)
+        if q_norm is None:
+            q_proj = q_proj * scale
+        else:
+            q_norm = {"scale": q_norm["scale"] * scale}
+    q = _dot(u, q_proj).reshape(n, t, hq, hd)
     k = _dot(u, p["k_proj"]).reshape(n, t, hkv, hd)
     v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
-    q = L.rotary(L.rms_apply(q_norm, q, cfg.norm_eps), cfg.rope_theta,
-                 positions)
-    k = L.rotary(L.rms_apply(p["k_norm"], k, cfg.norm_eps), cfg.rope_theta,
-                 positions)
+
+    def placed(heads, norm):
+        if norm is not None:
+            heads = L.rms_apply(norm, heads, cfg.norm_eps)
+        return L.rotary(heads, cfg.rope_theta, positions) if rotate else heads
+
+    q, k = placed(q, q_norm), placed(k, p.get("k_norm"))
     if fused:
         if exact:
             q = q * jnp.asarray(scale, q.dtype)
@@ -485,8 +497,11 @@ def _expert(wa, expert):
             for k, a in wa.items()}
 
 
-def _gated(a, b):
-    return jax.nn.silu(a) * b
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(a, b, gate="silu"):
+    return _GATES[gate](a) * b
 
 
 def _dot_rows(a, b):
@@ -537,11 +552,21 @@ def _sum_by_token(rows, by_token, window: int, chunk: int, n_tokens: int):
     return y.reshape(-1, d)[:n_tokens]
 
 
+def walk_sizes(cfg: Config, tokens: int, gate: str = "silu"):
+    """What :func:`held_experts` and :func:`_route_and_sort` take as
+    ``sizes``: the rows of a tile and the tokens of a window of the walk
+    over ``tokens`` tokens' assignments, and the activation of an expert's
+    gate (``silu`` or ``relu``)."""
+    tile = _tile_rows(cfg, tokens)
+    return tile, _window_tokens(cfg, tokens, tile), gate
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def held_experts(sizes, w, x, tokens, gates, tiles, in_use, by_token):
     """Sum over the sorted rows of the held experts' weighted results, by
     token: ``(N, d)``. ``sizes``: the rows of a tile (and of a chunk of
-    :func:`_sum_by_token`) and the tokens of its window; ``tokens``,
+    :func:`_sum_by_token`), the tokens of its window and the activation of
+    an expert's gate (:func:`walk_sizes`); ``tokens``,
     ``gates``: the token and the weight of each sorted row, with a tile of
     padding behind; ``tiles``: per tile its expert, its first sorted row
     and how many of its rows are that expert's; ``in_use``: the tiles that
@@ -560,7 +585,7 @@ def held_experts(sizes, w, x, tokens, gates, tiles, in_use, by_token):
     place and writes the rows' ``dx`` in sorted order, which the same
     function sums by token. The gates came sorted out of the sort
     (:func:`_sort_by_group`), and their gradient goes back through it."""
-    tile, window = sizes
+    tile, window, gate = sizes
     wa = _cast(w, x.dtype)
 
     def visit(t, out):
@@ -569,7 +594,7 @@ def held_experts(sizes, w, x, tokens, gates, tiles, in_use, by_token):
             xt = jnp.take(x, tok, axis=0)
         with jax.named_scope(STAGE_MOE_EXPERTS):
             we = _expert(wa, expert)
-            o = (_gated(xt @ we["w1"], xt @ we["w3"]) @ we["w2"]
+            o = (_gated(xt @ we["w1"], xt @ we["w3"], gate) @ we["w2"]
                  * g[:, None].astype(xt.dtype))
         with jax.named_scope(STAGE_MOE_COMBINE):
             return lax.dynamic_update_slice(out, o, (first, 0))
@@ -586,7 +611,7 @@ def _held_experts_fwd(sizes, w, x, tokens, gates, tiles, in_use, by_token):
 
 
 def _held_experts_bwd(sizes, res, dy):
-    tile, window = sizes
+    tile, window, gate = sizes
     w, x, tokens, gates, tiles, in_use, by_token = res
     wa = _cast(w, x.dtype)
 
@@ -599,7 +624,8 @@ def _held_experts_bwd(sizes, res, dy):
             dout = jnp.take(dy, tok, axis=0)
         with jax.named_scope(STAGE_MOE_EXPERTS):
             we = _expert(wa, expert)
-            h, pull = jax.vjp(_gated, xt @ we["w1"], xt @ we["w3"])
+            h, pull = jax.vjp(functools.partial(_gated, gate=gate),
+                              xt @ we["w1"], xt @ we["w3"])
             dg = jnp.sum(dout.astype(jnp.float32)
                          * (h @ we["w2"]).astype(jnp.float32), axis=-1)
             dout = dout * g[:, None].astype(dout.dtype)
@@ -641,7 +667,7 @@ def _route_and_sort(p, state, u, cfg: Config, sizes, router):
     layer's counters. A chunk is a tile's rows; tiles and chunks are
     bounded by the worst case, every assignment held here and every
     expert's (window's) last tile (chunk) all but empty."""
-    tile, window = sizes
+    tile, window, _ = sizes
     k, held = cfg.num_experts_per_tok, cfg.experts_held
     n_tokens, n_slots = u.shape[0], u.shape[0] * k
     n_windows = -(-n_tokens // window)
@@ -688,8 +714,7 @@ def moe_ffn(p, state, u, cfg: Config, router=None):
     ``router``: another decoder's, with :func:`route`'s signature, in place
     of this module's."""
     x = u.reshape(-1, u.shape[-1])
-    tile = _tile_rows(cfg, x.shape[0])
-    sizes = (tile, _window_tokens(cfg, x.shape[0], tile))
+    sizes = walk_sizes(cfg, x.shape[0])
     sorted_rows, counters = _route_and_sort(p, state, x, cfg, sizes,
                                             router or route)
     y = held_experts(sizes, {k: p[k] for k in ("w1", "w3", "w2")}, x,

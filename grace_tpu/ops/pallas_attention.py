@@ -12,12 +12,17 @@ skipped, in the grid and in the copies from HBM.
 **Masks** (a value handed to :func:`masked_gqa`; each is evaluated from the
 positions inside a tile, so no mask tensor reaches HBM either):
 :data:`CAUSAL`, a query reads the keys at or before it (tiles wholly above
-the diagonal are skipped), and :class:`BlockDiffusion` ``(seq_len,
-block)``, block-diffusion training's mask over a doubled sequence, the
-noised copy's ``seq_len`` positions first and the clean copy's behind them
-(``models/sdar.py``): block-diagonal among the noised positions, strictly
-block-causal from a noised query to the clean keys, block-causal among the
-clean positions, and nothing from a clean query to a noised key.
+the diagonal are skipped); :class:`SlidingWindow` ``(window)``, a query
+reads the ``window`` keys that end at its own position (the tiles above the
+diagonal and those wholly before the band are skipped: at 16,384 positions
+in tiles of 1,024 a window of 4,096 leaves 70 of 256 tiles, where the causal
+mask leaves 136; ``models/smallthinker.py``'s windowed layers); and
+:class:`BlockDiffusion` ``(seq_len, block)``, block-diffusion training's
+mask over a doubled sequence, the noised copy's ``seq_len`` positions first
+and the clean copy's behind them (``models/sdar.py``): block-diagonal among
+the noised positions, strictly block-causal from a noised query to the clean
+keys, block-causal among the clean positions, and nothing from a clean query
+to a noised key.
 
 **What the kernel sees under a block-diffusion mask** (PR 42) is not the
 ``2L x 2L`` square but a rectangle: all ``2L`` queries over the ``L`` clean
@@ -78,7 +83,8 @@ Three pairs of head sizes are taken (:data:`HEAD_DIMS`, queries and keys |
 values): ``(64, 64)``, grouped-query attention as ``models/lfm2.py`` has
 it, ``(192, 128)``, latent attention as ``models/deepseek_v3.py`` has it
 (a 128-wide part without positions beside a 64-wide rotary part), and
-``(128, 128)``, grouped-query attention as ``models/sdar.py`` has it. The
+``(128, 128)``, grouped-query attention as ``models/sdar.py`` and
+``models/smallthinker.py`` have it. The
 tile sizes were chosen by chip runs on a TPU v5e at ``(1, 4096, 32 | 8,
 64)`` bfloat16, where JAX's other kernel, ``flash_attention``, read 1.6
 times this one's time (PERF.md §6, PR 31), and read again at ``(1, 4096,
@@ -90,10 +96,12 @@ is given as ``q`` is what it multiplies into the keys, so ``softmax(q k^T +
 mask) v`` is what comes back. Each caller scales ``q`` where that rounds
 nothing its plain spelling does not round: ``models/lfm2.py`` multiplies
 ``q`` by ``1 / sqrt(64)`` in ``q``'s dtype (a power of two: exact) and, at
-a head size whose root is none (128, ``models/sdar.py``), folds the scale
-into the float32 weight of the queries' norm; ``models/deepseek_v3.py``
-folds ``1 / sqrt(192)`` into the query projection's weights in float32 as
-it casts them, so ``q`` is rounded once.
+a head size whose root is none (128), folds the scale into the float32
+weight of the queries' norm (``models/sdar.py``) or, where the heads have
+no norm (``models/smallthinker.py``), into the query projection's weights
+in float32 as it casts them; ``models/deepseek_v3.py`` folds ``1 /
+sqrt(192)`` into the query projection's weights the same way, so ``q`` is
+rounded once.
 
 **What a recomputed part keeps.** The kernel's backward pass needs two
 things that only its forward can make: the output and the log-sum-exp. The
@@ -164,10 +172,32 @@ class Causal:
         """The queries before ``stop`` read no key at or after this."""
         return min(stop, total)
 
+    def first_key(self, start: int) -> int:
+        """The queries from ``start`` on read no key before this."""
+        return 0
+
     def whole_tiles(self, seq_len: int) -> bool:
         """Whether what the kernel is given of ``seq_len`` positions under
         this mask is whole tiles: here, the positions themselves."""
         return seq_len % TILE == 0
+
+
+@dataclasses.dataclass(frozen=True)
+class SlidingWindow(Causal):
+    """A query reads the ``window`` keys that end at its own position, itself
+    included: query ``i`` reads key ``j`` iff ``0 <= i - j < window``. A
+    window as long as the sequence is the causal mask."""
+    window: int
+
+    def __post_init__(self):
+        if self.window <= 0:
+            raise ValueError(f"a window of {self.window} keys reads nothing")
+
+    def allowed(self, q_ids, kv_ids):
+        return (q_ids >= kv_ids) & (q_ids - kv_ids < self.window)
+
+    def first_key(self, start: int) -> int:
+        return max(0, start - self.window + 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,6 +244,9 @@ class BlockDiffusion:
 
     def keys_read(self, stop: int, total: int) -> int:
         return total
+
+    def first_key(self, start: int) -> int:
+        return 0
 
     def clean_keys_read(self, q_ids):
         """How many clean keys, from the clean copy's first on, the queries
@@ -263,11 +296,14 @@ def _below(read, kv_ids):
 def _computed_mask(splash, mask, seq_len: int):
     """``mask`` as the kernel takes one it evaluates itself: a mask object
     that answers for a slice of it (the tiles to visit) and hands the kernel
-    the function for the positions inside a tile. The causal mask is a
-    square over the sequence; a block-diffusion mask the rectangle of all
-    positions over the clean copy's keys."""
+    the function for the positions inside a tile. The causal mask and a
+    sliding window are squares over the sequence; a block-diffusion mask the
+    rectangle of all positions over the clean copy's keys."""
     if mask == CAUSAL:
         return splash.CausalMask((seq_len, seq_len))
+    if isinstance(mask, SlidingWindow):
+        # (keys before the query's own, keys after it)
+        return splash.LocalMask((seq_len, seq_len), (mask.window - 1, 0), 0)
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask)
 
@@ -521,12 +557,12 @@ def _block_diffusion_kernel(seq_len: int, q_heads: int, interpret: bool,
 
 def masked_gqa(q: jax.Array, k: jax.Array, v: jax.Array, mask=CAUSAL, *,
                interpret: bool = False) -> jax.Array:
-    """Attention under ``mask`` (:data:`CAUSAL` or a :class:`BlockDiffusion`)
-    of ``q`` ``(n, T, Hq, D)`` over ``k`` ``(n, T, Hkv, D)`` and ``v`` ``(n,
-    T, Hkv, Dv)``, query head ``h`` reading key/value head ``h // (Hq //
-    Hkv)``: ``(n, T, Hq, Dv)`` in ``q``'s dtype. ``T`` is a multiple of
-    :data:`TILE` (under a :class:`BlockDiffusion`, each copy is) and ``(D,
-    Dv)`` one of :data:`HEAD_DIMS` (see :func:`engages`). No scale is
+    """Attention under ``mask`` (:data:`CAUSAL`, a :class:`SlidingWindow` or a
+    :class:`BlockDiffusion`) of ``q`` ``(n, T, Hq, D)`` over ``k`` ``(n, T,
+    Hkv, D)`` and ``v`` ``(n, T, Hkv, Dv)``, query head ``h`` reading key/value
+    head ``h // (Hq // Hkv)``: ``(n, T, Hq, Dv)`` in ``q``'s dtype. ``T`` is a
+    multiple of :data:`TILE` (under a :class:`BlockDiffusion`, each copy is)
+    and ``(D, Dv)`` one of :data:`HEAD_DIMS` (see :func:`engages`). No scale is
     applied: the caller's ``q`` carries it (the module's docstring).
     ``interpret`` runs the kernel in Pallas's interpreter, for tests without
     the chip."""

@@ -34,7 +34,7 @@ __all__ = ["trace_stage", "match_stage", "ALL_STAGES",
            "STAGE_DENSE_FFN", "STAGE_MOE_ROUTER", "STAGE_MOE_DISPATCH",
            "STAGE_MOE_EXPERTS", "STAGE_MOE_COMBINE", "STAGE_LM_HEAD",
            "STAGE_MLA_LATENT", "STAGE_SHARED_EXPERT", "STAGE_DIFFUSION_NOISE",
-           "MODEL_STAGES"]
+           "STAGE_WINDOW_ATTENTION", "MODEL_STAGES"]
 
 # Canonical stage names — one vocabulary for the profiler, the report tool,
 # and the docs. Keep in sync with README "Observability".
@@ -111,10 +111,17 @@ STAGE_SHARED_EXPERT = "grace/shared_expert"
 # Block-diffusion training's draws of a step (models/sdar.py): each block's
 # noise level, each token's mask, the noised copy and the loss's weights.
 STAGE_DIFFUSION_NOISE = "grace/diffusion_noise"
+# Attention of a layer that reads a causal window of keys
+# (models/smallthinker.py): its projections, rotation and copies and, through
+# the kernel's ``op_name``, the fused kernel's calls under the window's mask.
+# A layer of the same model that reads the whole prefix stands under
+# STAGE_ATTENTION, so a trace tells the two kinds apart.
+STAGE_WINDOW_ATTENTION = "grace/window_attention"
 MODEL_STAGES = (STAGE_ATTENTION, STAGE_SHORT_CONV, STAGE_DENSE_FFN,
                 STAGE_MOE_ROUTER, STAGE_MOE_DISPATCH, STAGE_MOE_EXPERTS,
                 STAGE_MOE_COMBINE, STAGE_LM_HEAD, STAGE_MLA_LATENT,
-                STAGE_SHARED_EXPERT, STAGE_DIFFUSION_NOISE)
+                STAGE_SHARED_EXPERT, STAGE_DIFFUSION_NOISE,
+                STAGE_WINDOW_ATTENTION)
 
 # The canonical stage vocabulary, longest-prefix-matchable: the profiler,
 # tools/telemetry_report.py, and the static auditor's finding attribution

@@ -51,6 +51,10 @@ CELLS = {
     "sdar-30b-a3b-blockdiff-topk1pct-w1": ("sdar-30b-a3b-ep8",
                                            "TopKCompressor",
                                            "ResidualMemory", "Allgather"),
+    "smallthinker-21b-a3b-swa16k-topk1pct-w1": ("smallthinker-21b-a3b-ep8",
+                                                "TopKCompressor",
+                                                "ResidualMemory",
+                                                "Allgather"),
 }
 
 # configuration -> (leaves, parameters, leaves on the row-slices route under
@@ -61,6 +65,7 @@ CONFIGS = {
     "bert-base-squad": (150, 108_793_346, None, 3_369_912),
     "kanana-2-30b-a3b-ep16": (69, 424_960_512, 32, 33_996_704),
     "sdar-30b-a3b-ep8": (51, 456_346_624, 22, 36_507_584),
+    "smallthinker-21b-a3b-ep8": (43, 370_547_200, 22, 29_643_640),
 }
 
 # Top-k 1 % chunk, per distinct leaf shape:
@@ -147,6 +152,17 @@ TOPK_LEAVES = {
         ((16, 768, 2048), 4, 25165824, 251658, 101, True),
         ((16, 2048, 768), 8, 25165824, 251658, 101, True),
     ],
+    "smallthinker-21b-a3b-ep8": [
+        ((2560,), 9, 2560, 25, 103, False),         # no norm on the heads
+        ((2560, 64), 4, 163840, 1638, 101, False),          # the router
+        ((2560, 512), 8, 1310720, 13107, 101, False),       # W_k, W_v
+        ((2560, 3584), 4, 9175040, 91750, 101, True),       # W_q: 28 heads
+        ((3584, 2560), 4, 9175040, 91750, 101, True),       # W_o
+        ((2560, 18992), 1, 48619520, 486195, 101, True),
+        ((18992, 2560), 1, 48619520, 486195, 101, True),
+        ((8, 768, 2560), 4, 15728640, 157286, 101, True),
+        ((8, 2560, 768), 8, 15728640, 157286, 101, True),
+    ],
 }
 
 # PowerSGD rank 4 on BERT-base, per distinct leaf shape: (shape, leaves of
@@ -158,6 +174,8 @@ CODEC_CELL = {"resnet50-imagenet": "resnet50-topk1pct-w1",
               "lfm2-24b-a2b-ep8": "lfm2-24b-a2b-topk1pct-w1",
               "kanana-2-30b-a3b-ep16": "kanana-2-30b-a3b-topk1pct-w1",
               "sdar-30b-a3b-ep8": "sdar-30b-a3b-blockdiff-topk1pct-w1",
+              "smallthinker-21b-a3b-ep8":
+                  "smallthinker-21b-a3b-swa16k-topk1pct-w1",
               "bert-base-squad": "bert-base-powersgd4-w1"}
 
 POWERSGD_LEAVES = [

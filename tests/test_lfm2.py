@@ -817,9 +817,9 @@ def test_wire_report_reads_the_expert_stacks(trained):
 
 def test_every_part_of_the_step_is_under_its_stage(trained):
     text = trained["text"]
-    # the other decoders' three are not in this step
+    # the other decoders' four are not in this step
     others = (scopes.STAGE_MLA_LATENT, scopes.STAGE_SHARED_EXPERT,
-              scopes.STAGE_DIFFUSION_NOISE)
+              scopes.STAGE_DIFFUSION_NOISE, scopes.STAGE_WINDOW_ATTENTION)
     assert all(stage not in text for stage in others)
     for stage in set(scopes.MODEL_STAGES) - set(others):
         assert stage in text, stage
@@ -833,4 +833,4 @@ def test_every_part_of_the_step_is_under_its_stage(trained):
     assert scopes.match_stage(
         "grace/forward_backward/jvp(grace/short_conv)/dot") \
         == scopes.STAGE_SHORT_CONV
-    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 27
+    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 28

@@ -34,7 +34,8 @@ from grace_tpu.models import lfm2
 from grace_tpu.models import sdar
 from grace_tpu.ops import pallas_attention
 from grace_tpu.ops.pallas_attention import (CAUSAL, TILE, BlockDiffusion,
-                                            causal_gqa, engages, masked_gqa)
+                                            SlidingWindow, causal_gqa,
+                                            engages, masked_gqa)
 
 T = 2 * TILE
 D = 64
@@ -705,3 +706,138 @@ def test_the_kept_residuals_are_the_recomputed_ones_bit_for_bit(
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                           err_msg=jax.tree_util.keystr(path))
     assert float(jnp.max(jnp.abs(kept[1][1]))) > 0
+
+
+# ---------------------------------------------------------------------------
+# the same kernel under a sliding window (PR 43)
+# ---------------------------------------------------------------------------
+
+# a window that ends inside a tile: of the 4 x 4 tiles of 1,024 over 4,096
+# positions it leaves the diagonal's four, the three below it and the two
+# two below (their nearest pair is 1,025 apart)
+WINDOW = SlidingWindow(1500)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_kernel_agrees_with_scores_in_blocks_under_a_window(dtype):
+    """Four tiles of 1,024 positions at heads of 128 | 128, a window of
+    1,500: the interpreted kernel against ``lfm2._scores_in_blocks``, 512
+    queries at a time over the keys they can read, output and the three
+    gradients."""
+    q, k, v, w = _inputs(2, dtype, t=4 * TILE, dims=SDAR)
+    kernel = _weighted(lambda q, k, v: masked_gqa(_scaled(q), k, v, WINDOW,
+                                                  interpret=True))
+    (_, out), grads = kernel(q, k, v, w)
+    (_, want), want_grads = _weighted(functools.partial(
+        lfm2._scores_in_blocks, q_block=512, mask=WINDOW))(q, k, v, w)
+    assert out.dtype == dtype and out.shape == q.shape
+    tol = TOLERANCE[dtype]
+    assert _gap(out, want) < tol
+    for name, got, ref in zip("qkv", grads, want_grads):
+        assert got.dtype == dtype and got.shape == ref.shape
+        assert _gap(got, ref) < tol, name
+    # and it is no causal attention: the last tile's queries read a window
+    causal = causal_gqa(_scaled(q), k, v, interpret=True)
+    assert _gap(out[:, 3 * TILE:], causal[:, 3 * TILE:]) > 0.1
+    np.testing.assert_allclose(np.asarray(out[:, :1500], np.float32),
+                               np.asarray(causal[:, :1500], np.float32),
+                               rtol=2e-2, atol=1e-3)
+
+
+def test_the_kernel_reads_a_window_and_no_more():
+    """A key's value changed: the queries within the window after it move,
+    the queries past it and before it do not, bit for bit."""
+    q, k, v, _ = _inputs(1, jnp.float32, t=4 * TILE, dims=SDAR)
+    fn = jax.jit(lambda v: masked_gqa(_scaled(q), k, v, WINDOW,
+                                      interpret=True))
+    base, moved = fn(v), fn(v.at[:, 1000].add(5.0))
+    changed = np.flatnonzero(np.asarray(
+        jnp.any(base != moved, axis=(0, 2, 3))))
+    assert changed.min() == 1000 and changed.max() == 1000 + 1499
+    assert len(changed) == 1500
+
+
+@pytest.mark.parametrize("seq_len, window, visited", [
+    (16384, 4096, 70), (4096, 1500, 9), (4096, 1024, 7), (4096, 4096, 10),
+    (2048, 1, 2)])
+def test_tiles_outside_the_band_are_not_visited(seq_len, window, visited):
+    """The kernel's own table of tiles (0: skipped) under a window: at the
+    SmallThinker cell's 16,384 positions in tiles of 1,024 a window of 4,096
+    leaves 70 of 256 (five a row of queries from the fifth row on), where
+    the causal mask leaves 136; forward and fused backward alike (the
+    forward's grid is five key tiles wide, not sixteen). The mask is
+    evaluated from positions: the kernel carries no mask blocks."""
+    kernel = pallas_attention._kernel(seq_len, 2, True,
+                                      SlidingWindow(window))
+    tiles = seq_len // TILE
+    for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
+        table = np.asarray(info.block_mask)
+        assert int((table > 0).sum()) == visited
+        assert info.partial_mask_blocks is None
+    if seq_len == 16384:
+        assert np.asarray(kernel.fwd_mask_info.block_mask).shape[-1] == 5
+        assert np.asarray(kernel.dkv_mask_info.block_mask).size == tiles ** 2
+    causal = pallas_attention._kernel(seq_len, 2, True)
+    assert int((np.asarray(causal.fwd_mask_info.block_mask) > 0).sum()) \
+        == tiles * (tiles + 1) // 2
+
+
+@pytest.mark.parametrize("seq_len, platform, taken", [
+    (16384, "tpu", True), (16384, "cpu", False), (TILE, "tpu", True),
+    (TILE + 512, "tpu", False), (32, "tpu", False)],
+    ids=["smallthinker-cell", "cpu", "one-tile", "part-tile", "tiny"])
+def test_who_takes_the_kernel_under_a_window(seq_len, platform, taken):
+    assert engages(seq_len, *SDAR, jnp.bfloat16, platform,
+                   mask=SlidingWindow(4096)) is taken
+
+
+def _headless_inputs(cfg, t):
+    """Attention's weights without norms on the heads, as
+    ``smallthinker.init`` lays them out, scaled so that the softmax is far
+    from uniform."""
+    from grace_tpu.models import smallthinker
+    p = smallthinker.init(jax.random.key(4), cfg)[0]["layers"][1]["attn"]
+    p = jax.tree_util.tree_map(lambda x: x * 8, p)
+    return p, jax.random.normal(jax.random.key(5), (1, t, cfg.hidden_size))
+
+
+@pytest.mark.parametrize("rotate", [True, False], ids=["rotary", "nope"])
+def test_attention_without_head_norms_takes_the_kernel_under_its_mask(
+        monkeypatch, rotate):
+    """``lfm2.attention`` of a SmallThinker-shaped layer (no norm on the
+    heads, heads of 128, so the scale ``1/sqrt(128)`` goes into the query
+    projection's float32 weights) under a window, rotated or not: with
+    ``engages`` answering as on a TPU and the kernel interpreted it agrees
+    with the plain path, values and parameter gradients, and the kernel is
+    handed the window."""
+    from grace_tpu.models import smallthinker
+    cfg = smallthinker.tiny(head_dim=128, num_attention_heads=2,
+                            num_key_value_heads=1, attn_q_block=512)
+    mask = SlidingWindow(700)
+    p, u = _headless_inputs(cfg, 2 * TILE)
+    assert "q_norm" not in p
+
+    def loss(p, u):
+        return jnp.sum(lfm2.attention(p, u, cfg, mask, rotate=rotate) ** 2)
+
+    want, want_grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    calls = []
+
+    def interpreted(q, k, v, mask):
+        calls.append((q.shape, mask))
+        return masked_gqa(q, k, v, mask, interpret=True)
+
+    monkeypatch.setattr(pallas_attention, "engages",
+                        functools.partial(engages, platform="tpu"))
+    monkeypatch.setattr(pallas_attention, "masked_gqa", interpreted)
+    got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    assert calls == [((1, 2 * TILE, 2, 128), mask)]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(grads),
+                                   jax.tree_util.tree_leaves(want_grads)):
+        assert _gap(got_leaf, want_leaf) < 2e-5
+    # positions matter to the rotary layer alone
+    other = jax.jit(lambda p, u: jnp.sum(lfm2.attention(
+        p, u, cfg, mask, rotate=not rotate) ** 2))(p, u)
+    assert abs(float(other) - float(want)) > 1e-3 * abs(float(want))

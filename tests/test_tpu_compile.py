@@ -902,6 +902,36 @@ def test_the_sdar_step_compiles_for_the_described_chip(topo, kernels_on,
     assert held > 0.75 * 16e9              # three quarters of the chip
 
 
+def test_the_smallthinker_step_compiles_for_the_described_chip(
+        topo, kernels_on, monkeypatch):
+    """The whole step of ``smallthinker-21b-a3b-swa16k-topk1pct-w1`` from
+    the CPU: Mosaic takes the kernel at heads of 128 | 128 over sequences of
+    16,384 positions in all four layers, forward and the fused backward
+    (eight calls), the full layer's under ``grace/attention`` and the three
+    windowed layers' under ``grace/window_attention`` (a computed mask: no
+    mask tensor); no block of float32 scores and nothing of 16,384 x 16,384
+    is in the text; the router stands under its stage; and the step leaves
+    room on the chip for the harness's copy of the start parameters (1.48
+    GB) under the runtime's 16.91 GB, and holds over a quarter of the
+    chip."""
+    text, held = _whole_step("smallthinker-21b-a3b-swa16k-topk1pct-w1", topo,
+                             kernels_on, monkeypatch)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == 4 * KEPT[:1] + 4 * KEPT[1:]
+    windowed = [n for n in op_names if "grace/window_attention" in n]
+    full = [n for n in op_names
+            if "grace/attention" in n and "grace/window_attention" not in n]
+    assert (len(windowed), len(full)) == (6, 2), op_names
+    assert "bf16[28,16384,128]" in text and "bf16[4,16384,128]" in text
+    assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
+    assert not re.search(r"f32\[(?:1,)?(?:28|4,7),1024,\d{4,5}\]", text)
+    assert "grace/moe_router" in text
+    assert "grace/diffusion_noise" not in text
+    print("smallthinker step holds", held)
+    assert held + 370_547_200 * 4 < 16.91e9
+    assert held > 0.25 * 16e9
+
+
 # (kernel calls, bytes the compiled step holds) of the two causal decoder
 # cells at the parent of PR 41 (commit 4f95434), compiled here for the
 # described chip by the same helper: the mask handed to the kernel as a
