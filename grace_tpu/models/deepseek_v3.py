@@ -51,10 +51,11 @@ on its path the scale is folded into ``W_q`` in float32 as the weights are
 cast to the activation dtype: ``q`` is rounded once, as the plain
 spelling's is, and its float32 scores are scaled where they are made.
 
-Memory as in ``lfm2.py``: every part is recomputed in the backward pass
-from its input (but for the fused kernel's output and log-sum-exp, which
-are kept), attention and the dense feed-forward ``seq_block`` sequences at
-a time; parameters float32, cast inside a block, so a
+Memory as in ``lfm2.py``: every part of a layer is recomputed in the
+backward pass from its input (but for the fused kernel's output and
+log-sum-exp, which are kept), attention and the dense feed-forward
+``seq_block`` sequences at a time, and the head forms its gradient in the
+walk that makes the logits; parameters float32, cast inside a block, so a
 weight's gradient is summed over the blocks in float32. Model state: per
 expert layer the correction bias and the counters ``drawn``, ``held``,
 ``computed``, ``combined``, ``dropped`` of ``lfm2``.
@@ -79,7 +80,8 @@ from grace_tpu.telemetry.scopes import (STAGE_ATTENTION, STAGE_MLA_LATENT,
                                         STAGE_SHARED_EXPERT)
 
 
-# What lfm2's route, moe_ffn, _dense_part and loss_of_hidden_states read
+# What lfm2's route, moe_ffn, _dense_part and loss_of_hidden_states (which
+# hands head_loss ``norm_eps`` and ``seq_block`` sequences' positions) read
 # from the Config they are given.
 SHARED_FIELDS = ("num_experts", "num_experts_per_tok", "first_expert",
                  "experts_held", "routed_scaling_factor", "route_eps",

@@ -60,14 +60,18 @@ zeros, as many chunks as hold a row (and one a window at least, so that
 every token is written). Forward and backward share that pass: it is one
 linear map, and its transpose is the walk's gather of a row's token.
 
-Memory: every part of a layer (operator, feed-forward, head with loss) is
-recomputed in the backward pass from its input, the dense parts
-``seq_block`` sequences at a time, so the step holds two activations a
-layer and one block's intermediates; and, where attention took the fused
-kernel, the kernel's output and log-sum-exp of every sequence, which its
-backward pass reads in place of a second run of the forward kernel.
-Parameters are cast to the activation dtype inside a block, so a weight's
-gradient is summed over the blocks in float32.
+Memory: every part of a layer (operator, feed-forward) is recomputed in
+the backward pass from its input, the dense parts ``seq_block`` sequences
+at a time, so the step holds two activations a layer and one block's
+intermediates; and, where attention took the fused kernel, the kernel's
+output and log-sum-exp of every sequence, which its backward pass reads in
+place of a second run of the forward kernel. The head with the loss
+(:func:`head_loss`) is the last thing the forward pass does and the first
+the backward pass does, so nothing of it is kept or made again: it forms
+its gradient in the walk that makes the logits, ``seq_block`` sequences'
+float32 logits alive at a time, once a step. Parameters are cast to the
+activation dtype inside a block, so a weight's gradient is summed over the
+blocks in float32.
 
 Model state carries, per expert layer, the expert bias and five counters
 of the last step (float32, so that the step's mean over replicas keeps
@@ -231,7 +235,8 @@ def _over_sequences(fn, p, x, block: int):
     RESIDUAL_NAME``: its output and log-sum-exp, which only the kernel can
     make again; a part without the kernel holds no such name and keeps
     nothing). ``x`` and what ``fn`` returns are trees whose leaves lead with
-    the sequences."""
+    the sequences. For the layers' parts: the head, which nothing stands
+    behind, walks its parts itself (:func:`head_loss`)."""
     fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.
                         save_only_these_names(pallas_attention.RESIDUAL_NAME))
     n = jax.tree_util.tree_leaves(x)[0].shape[0]
@@ -772,22 +777,90 @@ def hidden_states(params, model_state, ids, cfg: Config,
     return x, {"layers": new_state}
 
 
-def _head_part(cfg):
-    """Final norm, head and each sequence's summed cross-entropy of ``(x,
-    targets)`` or, with a float32 weight a position, ``(x, targets,
-    weights)``."""
-    def part(p, xt):
-        x, targets, *weights = xt
-        with jax.named_scope(STAGE_LM_HEAD):
-            u = L.rms_apply(p["final_norm"], x, cfg.norm_eps)
-            logits = _dot(u, p["head"]).astype(jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-            nll = nll[..., 0]
-            for w in weights:
-                nll = nll * w
-            return jnp.sum(nll, axis=-1)
-    return part
+def _head_part(p, x, targets, weights, scale, eps):
+    """Final norm, head and the weighted cross-entropy of one part of
+    positions, summed and times ``scale``."""
+    u = L.rms_apply(p["final_norm"], x, eps)
+    logits = _dot(u, p["head"]).astype(jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * weights) * scale
+
+
+def _head_parts(x, targets, weights, positions: int):
+    """The positions of all sequences, one behind another, in parts of
+    ``positions``: what the head's walk scans."""
+    total = targets.size
+    positions = min(positions, total)
+    if total % positions:
+        raise ValueError(f"{total} positions are not whole parts of "
+                         f"{positions}")
+    return (x.reshape(-1, positions, x.shape[-1]),
+            targets.reshape(-1, positions), weights.reshape(-1, positions))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def head_loss(p, x, targets, weights, scale, positions, eps):
+    """``scale`` times the sum over all positions of ``weights`` times the
+    cross-entropy of the head's logits against ``targets``: final norm
+    (``eps``), head and float32 log-softmax of ``x`` ``(n, T, d)`` under
+    ``p`` = ``{"final_norm", "head"}``, ``positions`` positions at a time,
+    so that one part's float32 logits are what is alive. ``targets`` and
+    ``weights`` ``(n, T)``: a token and a float32 weight a position (a
+    position without a target weighs zero); they take no gradient.
+
+    The head is the last thing the forward pass does and the first the
+    backward pass does, so differentiated it forms its gradient in the walk
+    that makes the logits (:func:`_head_loss_fwd`): each part's logits exist
+    once a step, and a part costs three products (logits, ``dx``, ``d
+    head``) where a part recomputed in the backward pass costs four. Called
+    without differentiation it makes one product a part and the loss."""
+    with jax.named_scope(STAGE_LM_HEAD):
+        def visit(loss, part):
+            return loss + _head_part(p, *part, scale, eps), None
+
+        loss, _ = lax.scan(visit, jnp.zeros((), jnp.float32),
+                           _head_parts(x, targets, weights, positions))
+        return loss
+
+
+def _head_loss_fwd(p, x, targets, weights, scale, positions, eps):
+    """The loss, and as residuals its gradient by ``p`` and ``x``: one walk
+    over the parts, each under ``jax.value_and_grad``, so the rounding is
+    where autodiff puts it (``scale`` enters the float32 ``dlogits`` before
+    their cast to the activations' dtype). The carry holds the loss and the
+    float32 sums of the parts' ``d final_norm`` and ``d head``; the parts'
+    ``dx`` are the walk's outputs, in ``x``'s shape and dtype."""
+    with jax.named_scope(STAGE_LM_HEAD):
+        part_grad = jax.value_and_grad(_head_part, argnums=(0, 1))
+
+        def visit(carry, part):
+            loss, dp = carry
+            part_loss, (dp_part, dx) = part_grad(p, *part, scale, eps)
+            dp = jax.tree_util.tree_map(
+                lambda a, b: a + b.astype(jnp.float32), dp, dp_part)
+            return (loss + part_loss, dp), dx
+
+        (loss, dp), dx = lax.scan(
+            visit, (jnp.zeros((), jnp.float32), jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), p)),
+            _head_parts(x, targets, weights, positions))
+        dp = jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), dp, p)
+        return loss, (dp, dx.reshape(x.shape))
+
+
+def _head_loss_bwd(scale, positions, eps, grads, g):
+    with jax.named_scope(STAGE_LM_HEAD):
+        dp, dx = jax.tree_util.tree_map(lambda a: a * g.astype(a.dtype),
+                                        grads)
+    return dp, dx, None, None
+
+
+head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def _head_params(params):
+    return {"final_norm": params["final_norm"], "head": params["head"]}
 
 
 def next_token_loss(params, model_state, ids, cfg: Config,
@@ -799,12 +872,20 @@ def next_token_loss(params, model_state, ids, cfg: Config,
     return loss_of_hidden_states(params, x, ids, cfg), new_state
 
 
+def next_token_targets(ids):
+    """``(targets, weights)`` of the next-token loss over whole sequences:
+    position ``t``'s target is token ``t + 1``; a sequence's last position
+    has none (it is handed the first token) and weighs zero."""
+    n, t = ids.shape
+    weights = jnp.broadcast_to(
+        (jnp.arange(t) < t - 1).astype(jnp.float32), (n, t))
+    return jnp.roll(ids, -1, axis=1), weights
+
+
 def loss_of_hidden_states(params, x, ids, cfg):
     """The next-token loss of the last layer's output ``x`` ``(n, T, d)``:
-    final norm, head and cross-entropy, ``seq_block`` sequences at a time."""
+    final norm, head and cross-entropy, ``seq_block`` sequences at a time,
+    over all ``T`` positions of each (whole tiles; the last weighs zero)."""
     n, t = ids.shape
-    sums = _over_sequences(
-        _head_part(cfg),
-        {"final_norm": params["final_norm"], "head": params["head"]},
-        (x[:, :-1], ids[:, 1:]), cfg.seq_block)
-    return jnp.sum(sums) / (n * (t - 1))
+    return head_loss(_head_params(params), x, *next_token_targets(ids),
+                     1.0 / (n * (t - 1)), cfg.seq_block * t, cfg.norm_eps)

@@ -42,14 +42,16 @@ What is shared with ``models/lfm2.py`` is imported from it, not copied:
 attention's projections, norms and two spellings of the scores (the fused
 kernel where ``ops.pallas_attention.engages`` says so, ``attn_q_block``
 queries at a time everywhere else), the expert layer's walk, the walk over
-sequences, the head part. Under this mask the fused path hands the kernel
-all ``2 L`` queries over the clean copy's ``L`` keys alone, of which every
-query reads a prefix (one comparison a pair), scores a noised query's own
-block of ``block_length`` noised keys beside it and merges the two by
+sequences, the head with the loss (``head_loss``, handed the noise's
+weights and the divisor ``n L``). Under this mask the fused path hands the
+kernel all ``2 L`` queries over the clean copy's ``L`` keys alone, of which
+every query reads a prefix (one comparison a pair), scores a noised query's
+own block of ``block_length`` noised keys beside it and merges the two by
 log-sum-exp (``ops/pallas_attention.py``); the plain path evaluates the
-whole mask pair by pair. Memory as there: every part is recomputed in the
-backward pass from its input but for the merged output and the joint
-log-sum-exp, which the kernel's backward reads.
+whole mask pair by pair. Memory as there: every part of a layer is
+recomputed in the backward pass from its input but for the merged output
+and the joint log-sum-exp, which the kernel's backward reads; the head
+makes its logits once.
 
 Model state: ``step`` (the steps taken, the noise's counter), ``masked``
 (positions the last step scored: the masked tokens of all sequences) and,
@@ -70,9 +72,9 @@ import numpy as np
 from jax import lax
 
 from grace_tpu.models import layers as L
-from grace_tpu.models.lfm2 import (_chosen_scores, _dot, _head_part,
+from grace_tpu.models.lfm2 import (_chosen_scores, _dot, _head_params,
                                    _over_sequences, attention,
-                                   expert_layer_state, moe_ffn)
+                                   expert_layer_state, head_loss, moe_ffn)
 from grace_tpu.ops import pallas_attention
 from grace_tpu.telemetry.scopes import (STAGE_ATTENTION,
                                         STAGE_DIFFUSION_NOISE)
@@ -293,10 +295,9 @@ def block_diffusion_loss(params, model_state, batch, cfg: Config,
     mask = pallas_attention.BlockDiffusion(length, cfg.block_length)
     x, layer_states = hidden_states(params, model_state["layers"], both, cfg,
                                     mask, positions, dtype)
-    sums = _over_sequences(
-        _head_part(cfg),
-        {"final_norm": params["final_norm"], "head": params["head"]},
-        (x[:, :length], ids, weights), cfg.seq_block)
-    return jnp.sum(sums) / (n * length), {
+    loss = head_loss(_head_params(params), x[:, :length], ids, weights,
+                     1.0 / (n * length), cfg.seq_block * length,
+                     cfg.norm_eps)
+    return loss, {
         "step": model_state["step"] + 1.0, "masked": scored,
         "layers": layer_states}

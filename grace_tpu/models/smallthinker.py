@@ -39,13 +39,15 @@ scores (the fused kernel where ``ops.pallas_attention.engages`` says so,
 under ``CAUSAL`` or ``SlidingWindow(sliding_window_size)`` as the layer
 has it; ``attn_q_block`` queries at a time over the keys they can read
 everywhere else), the softmax router, the expert walk, the walk over
-sequences, the head part. Memory as there: every part is recomputed in the
-backward pass from its input but for the kernel's output and log-sum-exp;
-what the router's part hands the experts' part (the sorted assignments: a
-few numbers a token and expert chosen) is kept between them. The head walks
-a sequence in parts of ``head_positions`` positions, so that one part's
-float32 logits are what is alive (a whole sequence of 16,384 positions over
-18,992 rows would be 1.24 GB, and its gradient as much).
+sequences, the head with the loss. Memory as there: every part of a layer
+is recomputed in the backward pass from its input but for the kernel's
+output and log-sum-exp; what the router's part hands the experts' part (the
+sorted assignments: a few numbers a token and expert chosen) is kept
+between them. The head walks a sequence in parts of ``head_positions``
+positions and forms its gradient in that walk (``lfm2.head_loss``), so that
+one part's float32 logits are what is alive, once a step (a whole sequence
+of 16,384 positions over 18,992 rows would be 1.24 GB, and its gradient as
+much).
 
 Model state: per layer the expert layer's counters of ``lfm2`` without a
 bias (``drawn``, ``held``, ``computed``, ``combined``, ``dropped``), float32.
@@ -61,9 +63,10 @@ import jax
 import jax.numpy as jnp
 
 from grace_tpu.models import layers as L
-from grace_tpu.models.lfm2 import (_head_part, _over_sequences,
+from grace_tpu.models.lfm2 import (_head_params, _over_sequences,
                                    _route_and_sort, attention,
-                                   expert_layer_state, held_experts,
+                                   expert_layer_state, head_loss,
+                                   held_experts, next_token_targets,
                                    walk_sizes)
 from grace_tpu.models.sdar import route
 from grace_tpu.ops import pallas_attention
@@ -255,13 +258,6 @@ def next_token_loss(params, model_state, ids, cfg: Config,
     part = min(t, cfg.head_positions)
     if t % part:
         raise ValueError(f"{t} positions are not whole parts of {part}")
-    targets = jnp.concatenate([ids[:, 1:], ids[:, :1]], axis=1)
-    weights = jnp.broadcast_to(
-        (jnp.arange(t) < t - 1).astype(jnp.float32), (n, t))
-    parts = tuple(a.reshape(n * t // part, part, *a.shape[2:])
-                  for a in (x, targets, weights))
-    sums = _over_sequences(
-        _head_part(cfg),
-        {"final_norm": params["final_norm"], "head": params["head"]},
-        parts, cfg.seq_block)
-    return jnp.sum(sums) / (n * (t - 1)), new_state
+    loss = head_loss(_head_params(params), x, *next_token_targets(ids),
+                     1.0 / (n * (t - 1)), cfg.seq_block * part, cfg.norm_eps)
+    return loss, new_state

@@ -696,11 +696,6 @@ def _part_case(which):
     cfg = lfm2.tiny()
     params, _ = lfm2.init(jax.random.key(3), cfg)
     x = jax.random.normal(jax.random.key(4), (4, 16, cfg.hidden_size))
-    if which == "head":
-        ids = jax.random.randint(jax.random.key(5), (4, 16), 0,
-                                 cfg.vocab_size)
-        return (lambda p, x: lfm2.loss_of_hidden_states(p, x, ids, cfg),
-                params, x)
     kind = {"short_conv": "conv", "plain_attention": "full_attention"}.get(
         which)
     part, layer = ((lfm2._dense_part(cfg), 0) if kind is None else
@@ -710,7 +705,7 @@ def _part_case(which):
             params["layers"][layer], x)
 
 
-@pytest.mark.parametrize("which", ["short_conv", "dense_ffn", "head",
+@pytest.mark.parametrize("which", ["short_conv", "dense_ffn",
                                    "plain_attention"])
 def test_a_part_without_the_kernel_keeps_nothing(keep_nothing, which):
     """``_over_sequences`` keeps what the fused attention kernel names. A

@@ -933,14 +933,15 @@ def test_the_smallthinker_step_compiles_for_the_described_chip(
 
 
 # (kernel calls, bytes the compiled step holds) of the two causal decoder
-# cells at the parent of PR 41 (commit 4f95434), compiled here for the
-# described chip by the same helper: the mask handed to the kernel as a
-# value, position ids, a router handed to the walk and a weighted head
-# changed neither step (their texts, metadata and the kernels' embedded
-# source locations apart, were compared whole by hand: PERF.md section 6).
+# cells, compiled here for the described chip by the same helper. The bytes
+# are PR 44's: the head that forms its gradient in the walk that makes the
+# logits (``lfm2.head_loss``) took 292,352 bytes from the LFM2 step and
+# 131,323,904 from kanana's (12,865,857,024 and 14,399,759,872 from PR 39
+# to PR 43, through the mask handed to the kernel as a value, position ids,
+# a router handed to the walk and a weighted head: PERF.md section 6).
 CAUSAL_STEPS = {
-    "lfm2-24b-a2b-topk1pct-w1": (1, 12_865_857_024),
-    "kanana-2-30b-a3b-topk1pct-w1": (5, 14_399_759_872)}
+    "lfm2-24b-a2b-topk1pct-w1": (1, 12_865_564_672),
+    "kanana-2-30b-a3b-topk1pct-w1": (5, 14_268_435_968)}
 
 
 # Marked slow (outside tier-1): the two whole steps take 135 s and 90 s to
