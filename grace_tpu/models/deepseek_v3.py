@@ -45,7 +45,9 @@ the walk over sequences, the head with the loss. The shared expert is
 computed whole by every chip of a layer and added to the routed part. Two
 spellings of the scores, as there: the fused kernel where ``ops.pallas_attention.engages`` says so (the
 platform, the sequence length, the head sizes ``(nope + rope, v_head_dim)``
-and the dtype decide), ``attn_q_block`` queries at a time everywhere else.
+and the dtype decide), ``attn_q_block`` queries at a time everywhere else;
+head-major ``(n, H, T, .)`` from the query and ``kv_b`` products to the
+output projection's, as there.
 The kernel applies no scale and ``1 / sqrt(192)`` is no power of two, so
 on its path the scale is folded into ``W_q`` in float32 as the weights are
 cast to the activation dtype: ``q`` is rounded once, as the plain
@@ -71,8 +73,8 @@ import jax
 import jax.numpy as jnp
 
 from grace_tpu.models import layers as L
-from grace_tpu.models.lfm2 import (_dense_part, _dot, _over_sequences,
-                                   _scores_in_blocks, dense_ffn,
+from grace_tpu.models.lfm2 import (_dense_part, _dot, _from_heads, _heads_of,
+                                   _over_sequences, _plain_scores, dense_ffn,
                                    expert_layer_state, loss_of_hidden_states,
                                    moe_ffn)
 from grace_tpu.ops import pallas_attention
@@ -203,7 +205,11 @@ def init_state(cfg: Config) -> L.ModelState:
 # ---------------------------------------------------------------------------
 
 def mla(p, u, cfg: Config):
-    """Multi-head latent attention of normalised ``u`` ``(n, T, d)``."""
+    """Multi-head latent attention of normalised ``u`` ``(n, T, d)``,
+    head-major from product to product as ``lfm2.attention`` is: the query
+    and ``kv_b`` projections write ``(n, H, T, .)``, the one rotary key is
+    broadcast over the head axis where it leads, ``v`` is a slice of the
+    head-major ``kv``, and ``o_proj`` reads the output where it lies."""
     n, t, _ = u.shape
     h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -211,23 +217,23 @@ def mla(p, u, cfg: Config):
     w_q = p["q_proj"]
     if fused:       # the kernel applies no scale: see the module's docstring
         w_q = w_q * (1.0 / math.sqrt(cfg.qk_head_dim))
-    q = _dot(u, w_q).reshape(n, t, h, cfg.qk_head_dim)
+    q = _heads_of(u, w_q, h)
     c, k_pe = jnp.split(_dot(u, p["kv_a_proj"]), [cfg.kv_lora_rank], axis=-1)
-    kv = _dot(L.rms_apply(p["kv_a_norm"], c, cfg.norm_eps),
-              p["kv_b_proj"]).reshape(n, t, h, nope + dv)
+    kv = _heads_of(L.rms_apply(p["kv_a_norm"], c, cfg.norm_eps),
+                   p["kv_b_proj"], h)
     q = jnp.concatenate(
-        [q[..., :nope], L.rotary_pairs(q[..., nope:], cfg.rope_theta)],
-        axis=-1)
-    k_pe = L.rotary_pairs(k_pe[:, :, None, :], cfg.rope_theta)
+        [q[..., :nope],
+         L.rotary_pairs(q[..., nope:], cfg.rope_theta, axis=-2)], axis=-1)
+    k_pe = L.rotary_pairs(k_pe[:, None], cfg.rope_theta, axis=-2)
     k = jnp.concatenate(
-        [kv[..., :nope], jnp.broadcast_to(k_pe, (n, t, h, rope))], axis=-1)
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (n, h, t, rope))], axis=-1)
     v = kv[..., nope:]
     with jax.named_scope(STAGE_ATTENTION):
         if fused:
             out = pallas_attention.causal_gqa(q, k, v)
         else:
-            out = _scores_in_blocks(q, k, v, cfg.attn_q_block)
-    return _dot(out.reshape(n, t, h * dv), p["o_proj"])
+            out = _plain_scores(q, k, v, cfg.attn_q_block)
+    return _from_heads(out, p["o_proj"])
 
 
 # ---------------------------------------------------------------------------

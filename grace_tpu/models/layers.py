@@ -148,38 +148,50 @@ def rms_apply(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * p["scale"]).astype(x.dtype)
 
 
-def rotary(x: jax.Array, theta: float,
-           positions: jax.Array | None = None) -> jax.Array:
-    """Rotary positions over the whole head, rotate-half: ``x`` is
-    ``(..., T, heads, head_dim)``, along the third axis from the end the
-    entries of ``positions`` ``(T,)`` or, without them, ``0 .. T - 1``.
-    Angles and the rotation are float32."""
-    t, d = x.shape[-3], x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+def _angles(t: int, freq: jax.Array, axis: int,
+            positions: jax.Array | None = None) -> jax.Array:
+    """Float32 angles ``positions * freq`` (``0 .. t - 1`` without
+    positions), shaped to broadcast against an array whose ``axis`` (from
+    the end) holds the ``t`` entries and whose last holds ``freq``'s."""
     if positions is None:
         positions = jnp.arange(t, dtype=jnp.float32)
     elif positions.shape != (t,):
         raise ValueError(f"{positions.shape} positions for {t} entries")
-    ang = positions.astype(jnp.float32)[:, None, None] * freq
+    return (positions.astype(jnp.float32).reshape((t,) + (1,) * (-axis - 1))
+            * freq)
+
+
+def rotary(x: jax.Array, theta: float, positions: jax.Array | None = None,
+           axis: int = -3) -> jax.Array:
+    """Rotary positions over the whole head, rotate-half: ``x`` is
+    ``(..., T, heads, head_dim)`` or, with ``axis=-2``, head-major ``(...,
+    heads, T, head_dim)``; along ``axis`` (counted from the end) lie the
+    entries of ``positions`` ``(T,)`` or, without them, ``0 .. T - 1``.
+    Angles and the rotation are float32, the same numbers in either layout."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = _angles(x.shape[axis], freq, axis, positions)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
 
-def rotary_pairs(x: jax.Array, theta: float) -> jax.Array:
+def rotary_pairs(x: jax.Array, theta: float, axis: int = -3) -> jax.Array:
     """Rotary positions over neighbouring pairs (``rope_interleave``):
     entries ``2i`` and ``2i + 1`` of the last axis turn by the angle
-    :func:`rotary` gives entries ``i`` and ``i + d/2``. Same layout of
-    ``x``. An entry's partner (``-x[2i + 1]`` for ``2i``, ``x[2i]`` for ``2i
+    :func:`rotary` gives entries ``i`` and ``i + d/2``. Same layouts of
+    ``x``: the positions along ``axis``, ``-3`` for ``(..., T, heads, d)``,
+    ``-2`` for head-major ``(..., heads, T, d)``. An entry's partner
+    (``-x[2i + 1]`` for ``2i``, ``x[2i]`` for ``2i
     + 1``) is reached by a product with a signed permutation matrix, which
     is exact in any dtype (one term a sum) and shuffles no lanes: two
     shifts along the 64-wide last axis and a select read 20 ms a layer more
     at 8 x 4,096 tokens and 32 heads on a TPU v5e (PERF.md section 6,
     PR 32)."""
-    t, d = x.shape[-3], x.shape[-1]
+    d = x.shape[-1]
     freq = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None, None] * freq
+    ang = _angles(x.shape[axis], freq, axis)
     swap = np.zeros((d, d), np.float32)
     swap[np.arange(1, d, 2), np.arange(0, d, 2)] = -1.0
     swap[np.arange(0, d, 2), np.arange(1, d, 2)] = 1.0

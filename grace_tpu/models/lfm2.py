@@ -20,7 +20,12 @@ Equations (``u = RMSNorm(x)`` with a learned weight; no bias anywhere):
   tile in VMEM with an online softmax and the kernel's own backward pass,
   and no ``(heads, queries, keys)`` tensor reaches HBM; everywhere else
   ``attn_q_block`` queries are scored at a time against the keys up to
-  the block's end, each block recomputed in the backward pass.
+  the block's end, each block recomputed in the backward pass. ``q``,
+  ``k``, ``v`` and the output are head-major ``(n, H, T, D)`` from the
+  projections' products to the output projection's, as the kernel reads and
+  writes them: the weights are viewed ``(d, H, D)`` and ``(H, D, d)`` (their
+  shapes as parameters do not change), norms and rotation run on that
+  layout, and no axis is moved on the kernel's path (PERF.md §6, PR 45).
 * dense feed-forward: ``W_2 (silu(W_1 h) * W_3 h)``.
 * expert feed-forward: ``s = sigmoid(W_r h)``; the ``num_experts_per_tok``
   experts are the top of ``s + b`` (``b`` the expert bias, model state, not
@@ -306,17 +311,46 @@ def _scores_in_blocks(q, k, v, q_block: int, mask=pallas_attention.CAUSAL):
     return jnp.concatenate(out, axis=1).reshape(n, t, hq, v.shape[-1])
 
 
+def _heads_of(u, w, heads: int):
+    """``u`` ``(n, T, d)`` through ``w`` ``(d, heads * D)``, head-major:
+    ``(n, heads, T, D)``, the product's own output (the weight is viewed
+    ``(d, heads, D)``, which moves nothing), so that nothing stands between
+    it and the kernel that reads heads first."""
+    w = w.astype(u.dtype).reshape(w.shape[0], heads, -1)
+    return jnp.einsum("ntd,dhk->nhtk", u, w)
+
+
+def _from_heads(out, w):
+    """Head-major ``out`` ``(n, H, T, Dv)`` through ``w`` ``(H * Dv, d)``,
+    read where the kernel left it: ``(n, T, d)``."""
+    h, dv = out.shape[1], out.shape[3]
+    return jnp.einsum("nhtk,hkd->ntd", out,
+                      w.astype(out.dtype).reshape(h, dv, -1))
+
+
+def _plain_scores(q, k, v, q_block: int, mask=pallas_attention.CAUSAL):
+    """:func:`_scores_in_blocks` of head-major ``q``, ``k``, ``v``, head-major:
+    the plain route keeps its ``(n, T, H, D)`` spelling, and the axes are
+    swapped for it here, off the kernel's path."""
+    out = _scores_in_blocks(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)),
+                            q_block, mask)
+    return jnp.swapaxes(out, 1, 2)
+
+
 def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
               positions=None, rotate: bool = True):
     """Grouped-query self-attention of normalised ``u`` under ``mask``
     (causal unless told otherwise), rotated by ``positions`` (``0 .. T -
     1`` unless given) unless ``rotate`` is false (a layer without
     positions); the query and key heads are normalised where ``p`` holds
-    ``q_norm`` and ``k_norm``. On a TPU, a sequence of whole tiles at a head
-    size the fused kernel takes goes through it (``ops.pallas_attention``:
-    no score tensor in HBM, its own backward pass); everything else through
-    :func:`_scores_in_blocks`."""
-    n, t, _ = u.shape
+    ``q_norm`` and ``k_norm``. Head-major from product to product: the
+    three projections write ``(n, H, T, D)``, norms and rotation are applied
+    there, and ``o_proj`` reads the output where it lies, so that no axis is
+    moved between a product and the kernel. On a TPU, a sequence of whole
+    tiles at a head size the fused kernel takes goes through it
+    (``ops.pallas_attention``: no score tensor in HBM, its own backward
+    pass); everything else through :func:`_scores_in_blocks`."""
+    t = u.shape[1]
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
     fused = pallas_attention.engages(t, hd, hd, u.dtype, mask=mask)
@@ -333,14 +367,16 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
             q_proj = q_proj * scale
         else:
             q_norm = {"scale": q_norm["scale"] * scale}
-    q = _dot(u, q_proj).reshape(n, t, hq, hd)
-    k = _dot(u, p["k_proj"]).reshape(n, t, hkv, hd)
-    v = _dot(u, p["v_proj"]).reshape(n, t, hkv, hd)
+    q = _heads_of(u, q_proj, hq)
+    k = _heads_of(u, p["k_proj"], hkv)
+    v = _heads_of(u, p["v_proj"], hkv)
 
     def placed(heads, norm):
         if norm is not None:
             heads = L.rms_apply(norm, heads, cfg.norm_eps)
-        return L.rotary(heads, cfg.rope_theta, positions) if rotate else heads
+        if not rotate:
+            return heads
+        return L.rotary(heads, cfg.rope_theta, positions, axis=-2)
 
     q, k = placed(q, q_norm), placed(k, p.get("k_norm"))
     if fused:
@@ -351,8 +387,8 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
         else:
             out = pallas_attention.masked_gqa(q, k, v, mask)
     else:
-        out = _scores_in_blocks(q, k, v, cfg.attn_q_block, mask)
-    return _dot(out.reshape(n, t, hq * hd), p["o_proj"])
+        out = _plain_scores(q, k, v, cfg.attn_q_block, mask)
+    return _from_heads(out, p["o_proj"])
 
 
 def dense_ffn(p, u):

@@ -79,6 +79,16 @@ float32; the forward multiplies float32 probabilities into the values, the
 backward casts the probabilities and the score gradients to the
 gradient's dtype for its products.
 
+**Layout.** Every kernel here reads and writes heads first, and so does
+:func:`masked_gqa`: ``q`` ``(n, Hq, T, D)``, ``k`` ``(n, Hkv, T, D)``, ``v``
+``(n, Hkv, T, Dv)`` -> ``(n, Hq, T, Dv)``. The wrapper moves no axis (until
+PR 45 it took ``(n, T, H, D)`` and swapped three operands in and the result
+out, each a copy of a tiled array in HBM, forward, recomputed and backward).
+The callers' projections write head-major themselves (``einsum("ntd,dhk->
+nhtk")`` over the weight viewed ``(d, H, D)``) and their output projection
+reads the result where the kernel left it (``einsum("nhtk,hkd->ntd")``), so
+what stands between a product and the kernel is norms and rotations alone.
+
 Three pairs of head sizes are taken (:data:`HEAD_DIMS`, queries and keys |
 values): ``(64, 64)``, grouped-query attention as ``models/lfm2.py`` has
 it, ``(192, 128)``, latent attention as ``models/deepseek_v3.py`` has it
@@ -516,6 +526,12 @@ def _block_diffusion_kernel(seq_len: int, q_heads: int, interpret: bool,
                mask_value=settings["mask_value"])
 
     def forward(q, k, v):
+        # The fused kernel is handed a sequence (``vmap``), the own blocks'
+        # kernel the batch: two shapes of one ``q``. Without the barrier XLA
+        # carries the reshape up through the rotation and rotates ``q`` twice,
+        # once a shape (0.3 ms a sequence and layer at 8,192 positions and 32
+        # heads on a TPU v5e: PERF.md section 6, PR 45).
+        q, k, v = lax.optimization_barrier((q, k, v))
         out, (lse,) = jax.vmap(lambda q, k, v: sk._splash_attention_forward(
             clean.fwd_mask_info, q, k, v, segment_ids=None, sinks=None,
             save_residuals=True, residual_checkpoint_name=None, **settings))(
@@ -533,7 +549,8 @@ def _block_diffusion_kernel(seq_len: int, q_heads: int, interpret: bool,
         return out, (q, k, v, out, lse)
 
     def attend_bwd(res, do):
-        q, k, v, out, lse = res
+        # as in `forward`: the recomputed `q` goes to two kernels
+        q, k, v, out, lse = lax.optimization_barrier(res)
 
         def clean_keys(q, k, v, out, lse, do):
             # The joint softmax's gradients over the clean keys: the fused
@@ -558,34 +575,34 @@ def _block_diffusion_kernel(seq_len: int, q_heads: int, interpret: bool,
 def masked_gqa(q: jax.Array, k: jax.Array, v: jax.Array, mask=CAUSAL, *,
                interpret: bool = False) -> jax.Array:
     """Attention under ``mask`` (:data:`CAUSAL`, a :class:`SlidingWindow` or a
-    :class:`BlockDiffusion`) of ``q`` ``(n, T, Hq, D)`` over ``k`` ``(n, T,
-    Hkv, D)`` and ``v`` ``(n, T, Hkv, Dv)``, query head ``h`` reading key/value
-    head ``h // (Hq // Hkv)``: ``(n, T, Hq, Dv)`` in ``q``'s dtype. ``T`` is a
+    :class:`BlockDiffusion`), head-major as the kernel reads and writes: ``q``
+    ``(n, Hq, T, D)`` over ``k`` ``(n, Hkv, T, D)`` and ``v`` ``(n, Hkv, T,
+    Dv)``, query head ``h`` reading key/value head ``h // (Hq // Hkv)``: ``(n,
+    Hq, T, Dv)`` in ``q``'s dtype. No axis is moved on the way in or out: a
+    caller makes its projections head-major (``einsum("ntd,dhk->nhtk")``)
+    and reads the result where it lies. ``T`` is a
     multiple of :data:`TILE` (under a :class:`BlockDiffusion`, each copy is)
     and ``(D, Dv)`` one of :data:`HEAD_DIMS` (see :func:`engages`). No scale is
     applied: the caller's ``q`` carries it (the module's docstring).
     ``interpret`` runs the kernel in Pallas's interpreter, for tests without
     the chip."""
-    n, t, hq, d = q.shape
+    n, hq, t, d = q.shape
     dv = v.shape[3]
-    if (k.shape[:3] != v.shape[:3] or k.shape[:2] != (n, t)
+    if (k.shape[:3] != v.shape[:3] or (k.shape[0], k.shape[2]) != (n, t)
             or k.shape[3] != d):
         raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape} are not "
-                         "(n, T, Hq, D), (n, T, Hkv, D), (n, T, Hkv, Dv)")
-    if hq % k.shape[2]:
+                         "(n, Hq, T, D), (n, Hkv, T, D), (n, Hkv, T, Dv)")
+    if hq % k.shape[1]:
         raise ValueError("query heads must divide over key/value heads")
     if not _takes(t, d, dv, q.dtype, mask):
         raise ValueError(
             f"the kernel takes sequences of whole tiles of {TILE}, head "
             f"sizes (queries and keys, values) {HEAD_DIMS}, bfloat16 or "
             f"float32; got T={t}, D={d}, Dv={dv}, {q.dtype}, {mask}")
-    heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
     # one key population or two: separate paths, chosen by the mask's type
     if isinstance(mask, BlockDiffusion):
-        kernel = _block_diffusion_kernel(t, hq, interpret, mask)
-    else:
-        kernel = jax.vmap(_kernel(t, hq, interpret, mask))
-    return heads_first(kernel(heads_first(q), heads_first(k), heads_first(v)))
+        return _block_diffusion_kernel(t, hq, interpret, mask)(q, k, v)
+    return jax.vmap(_kernel(t, hq, interpret, mask))(q, k, v)
 
 
 def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -47,20 +47,31 @@ HKV = 2
 def _inputs(group, dtype, t=T, key=0, dims=GQA):
     ks = jax.random.split(jax.random.key(key), 4)
 
-    def normal(k, heads, d):
-        return jax.random.normal(k, (1, t, heads, d), jnp.float32
+    def normal(k, heads, d):       # head-major, as the kernel's wrapper takes
+        return jax.random.normal(k, (1, heads, t, d), jnp.float32
                                  ).astype(dtype)
 
     return (normal(ks[0], HKV * group, dims[0]), normal(ks[1], HKV, dims[0]),
             normal(ks[2], HKV, dims[1]), normal(ks[3], HKV * group, dims[1]))
 
 
+def _tokens_first(fn):
+    """A plain spelling over ``(n, T, H, D)`` as a function of head-major
+    operands that answers head-major, as the kernel's wrapper does."""
+    def swapped(q, k, v, **kw):
+        return jnp.swapaxes(
+            fn(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), **kw), 1, 2)
+    return swapped
+
+
+@_tokens_first
 def _plain(q, k, v, mask=CAUSAL):
     n, t, hq, d = q.shape
     hkv = k.shape[2]
     out = lfm2._scores_block(q.reshape(n, t, hkv, hq // hkv, d), k, v, 0,
                              mask)
     return out.reshape(n, t, hq, v.shape[-1])
+
 
 
 def _scaled(q):
@@ -125,12 +136,13 @@ def test_the_kernel_is_causal(dims):
     q, k, v, _ = _inputs(4 if dims is GQA else 1, jnp.float32, dims=dims)
     out = causal_gqa(q, k, v, interpret=True)
     cut = TILE + 37           # inside the second tile's diagonal block
-    k2 = k.at[:, cut:].set(7.0)
-    v2 = v.at[:, cut:].set(-3.0)
+    k2 = k.at[:, :, cut:].set(7.0)
+    v2 = v.at[:, :, cut:].set(-3.0)
     out2 = causal_gqa(q, k2, v2, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[:, :cut]),
-                                  np.asarray(out2[:, :cut]))
-    assert not np.allclose(np.asarray(out[:, cut:]), np.asarray(out2[:, cut:]))
+    np.testing.assert_array_equal(np.asarray(out[:, :, :cut]),
+                                  np.asarray(out2[:, :, :cut]))
+    assert not np.allclose(np.asarray(out[:, :, cut:]),
+                           np.asarray(out2[:, :, cut:]))
 
 
 # The doubled sequence of block diffusion at the kernel's least size: one
@@ -177,18 +189,19 @@ def test_the_kernel_reads_what_the_block_mask_allows_and_no_more():
     out = masked_gqa(q, k, v, BLOCK_MASK, interpret=True)
     readable = np.zeros(T, bool)
     readable[4:8] = readable[TILE:TILE + 4] = True
-    k2 = jnp.where(readable[None, :, None, None], k, 7.0)
-    v2 = jnp.where(readable[None, :, None, None], v, -3.0)
+    k2 = jnp.where(readable[None, None, :, None], k, 7.0)
+    v2 = jnp.where(readable[None, None, :, None], v, -3.0)
     out2 = masked_gqa(q, k2, v2, BLOCK_MASK, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[:, 4:8]),
-                                  np.asarray(out2[:, 4:8]))
-    assert not np.allclose(np.asarray(out[:, 8:12]), np.asarray(out2[:, 8:12]))
+    np.testing.assert_array_equal(np.asarray(out[:, :, 4:8]),
+                                  np.asarray(out2[:, :, 4:8]))
+    assert not np.allclose(np.asarray(out[:, :, 8:12]),
+                           np.asarray(out2[:, :, 8:12]))
     noised = np.arange(T) < TILE
-    out3 = masked_gqa(q, jnp.where(noised[None, :, None, None], 7.0, k),
-                      jnp.where(noised[None, :, None, None], -3.0, v),
+    out3 = masked_gqa(q, jnp.where(noised[None, None, :, None], 7.0, k),
+                      jnp.where(noised[None, None, :, None], -3.0, v),
                       BLOCK_MASK, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[:, TILE:]),
-                                  np.asarray(out3[:, TILE:]))
+    np.testing.assert_array_equal(np.asarray(out[:, :, TILE:]),
+                                  np.asarray(out3[:, :, TILE:]))
 
 
 @pytest.mark.parametrize("mask, dtype, group", [
@@ -212,7 +225,7 @@ def test_the_rectangle_and_the_own_block_agree_with_scores_in_blocks(
         _scaled(q), k, v, mask, interpret=True))
     (_, out), grads = kernel(q, k, v, w)
     (_, want), want_grads = _weighted(functools.partial(
-        lfm2._scores_in_blocks, q_block=256, mask=mask))(q, k, v, w)
+        lfm2._plain_scores, q_block=256, mask=mask))(q, k, v, w)
     tol = TOLERANCE[dtype]
     assert out.dtype == dtype and out.shape == want.shape
     for name, got, ref in zip(["out", "dq", "dk", "dv"], (out,) + grads,
@@ -220,7 +233,7 @@ def test_the_rectangle_and_the_own_block_agree_with_scores_in_blocks(
         assert got.dtype == dtype and got.shape == ref.shape
         assert np.isfinite(np.asarray(got, np.float32)).all(), name
         for half in (slice(0, TILE), slice(TILE, T)):
-            assert _gap(got[:, half], ref[:, half]) < tol, (name, half)
+            assert _gap(got[:, :, half], ref[:, :, half]) < tol, (name, half)
 
 
 def test_a_noised_query_of_block_0_gets_its_own_blocks_answer_exactly():
@@ -230,18 +243,20 @@ def test_a_noised_query_of_block_0_gets_its_own_blocks_answer_exactly():
     whatever the clean keys and values hold."""
     q, k, v, _ = _inputs(4, jnp.float32, dims=SDAR, key=12)
     out = masked_gqa(q, k, v, BLOCK_MASK, interpret=True)
-    clean = (np.arange(T) >= TILE)[None, :, None, None]
+    clean = (np.arange(T) >= TILE)[None, None, :, None]
     out2 = masked_gqa(q, jnp.where(clean, 50.0, k),
                       jnp.where(clean, -3e30, v), BLOCK_MASK, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[:, :4]),
-                                  np.asarray(out2[:, :4]))
-    assert not np.allclose(np.asarray(out[:, 4:8]), np.asarray(out2[:, 4:8]))
-    group = q.shape[2] // k.shape[2]
-    k4, v4 = (jnp.repeat(x[:, :4], group, axis=2) for x in (k, v))
+    np.testing.assert_array_equal(np.asarray(out[:, :, :4]),
+                                  np.asarray(out2[:, :, :4]))
+    assert not np.allclose(np.asarray(out[:, :, 4:8]),
+                           np.asarray(out2[:, :, 4:8]))
+    group = q.shape[1] // k.shape[1]
+    k4, v4 = (jnp.repeat(x[:, :, :4], group, axis=1) for x in (k, v))
     own = jnp.einsum(
-        "nhqk,nkhd->nqhd",
-        jax.nn.softmax(jnp.einsum("nqhd,nkhd->nhqk", q[:, :4], k4), -1), v4)
-    np.testing.assert_allclose(np.asarray(out[:, :4]), np.asarray(own),
+        "nhqk,nhkd->nhqd",
+        jax.nn.softmax(jnp.einsum("nhqd,nhkd->nhqk", q[:, :, :4], k4), -1),
+        v4)
+    np.testing.assert_allclose(np.asarray(out[:, :, :4]), np.asarray(own),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -343,7 +358,7 @@ def test_the_clean_keys_a_query_reads_are_the_masks():
 
 
 def test_a_mask_over_another_length_is_refused():
-    q = jnp.zeros((1, T, 2, 128), jnp.float32)
+    q = jnp.zeros((1, 2, T, 128), jnp.float32)
     with pytest.raises(ValueError, match="positions"):
         masked_gqa(q, q, q, BlockDiffusion(TILE // 2, 4), interpret=True)
 
@@ -422,23 +437,32 @@ def test_half_a_tile_a_copy_takes_the_plain_path(monkeypatch):
 
 
 @pytest.mark.parametrize("shape, dv", [
-    ((1, TILE + 128, 4, 64), 64), ((1, T, 4, 8), 8), ((1, T, 4, 192), 192),
-    ((1, T, 4, 64), 128)],
+    ((1, 4, TILE + 128, 64), 64), ((1, 4, T, 8), 8), ((1, 4, T, 192), 192),
+    ((1, 4, T, 64), 128)],
     ids=["part-tile", "head8", "192-192", "64-128"])
 def test_a_refused_shape_raises_in_the_kernel(shape, dv):
     q = jnp.zeros(shape, jnp.float32)
-    k = jnp.zeros(shape[:2] + (2, shape[3]), jnp.float32)
-    v = jnp.zeros(shape[:2] + (2, dv), jnp.float32)
+    k = jnp.zeros((shape[0], 2) + shape[2:], jnp.float32)
+    v = jnp.zeros((shape[0], 2, shape[2], dv), jnp.float32)
     with pytest.raises(ValueError, match="whole tiles.*queries and keys, "
                                          "values"):
         causal_gqa(q, k, v, interpret=True)
 
 
 def test_keys_of_another_size_than_the_queries_are_refused():
-    q = jnp.zeros((1, TILE, 2, 192), jnp.float32)
-    kv = jnp.zeros((1, TILE, 2, 128), jnp.float32)
-    with pytest.raises(ValueError, match=r"\(n, T, Hkv, Dv\)"):
+    q = jnp.zeros((1, 2, TILE, 192), jnp.float32)
+    kv = jnp.zeros((1, 2, TILE, 128), jnp.float32)
+    with pytest.raises(ValueError, match=r"\(n, Hkv, T, Dv\)"):
         causal_gqa(q, kv, kv, interpret=True)
+
+
+def test_operands_that_lead_with_the_positions_are_refused():
+    """The wrapper moves no axis: ``(n, T, H, D)`` operands, the layout it
+    took until PR 45, are no sequence of whole tiles (two positions of
+    ``T`` heads) and are refused, not swapped."""
+    q = jnp.zeros((1, TILE, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="whole tiles"):
+        causal_gqa(q, q, q, interpret=True)
 
 
 def _attention_inputs(cfg, t, key=5):
@@ -504,7 +528,7 @@ def test_attention_takes_the_kernel_where_it_engages(monkeypatch):
                         functools.partial(engages, platform="tpu"))
     monkeypatch.setattr(pallas_attention, "causal_gqa", interpreted)
     got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
-    assert calls == [(2, TILE, cfg.num_attention_heads, 64)]
+    assert calls == [(2, cfg.num_attention_heads, TILE, 64)]
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(grads),
                                    jax.tree_util.tree_leaves(want_grads)):
@@ -566,8 +590,8 @@ def test_latent_attention_takes_the_kernel_where_it_engages(monkeypatch):
                         functools.partial(engages, platform="tpu"))
     monkeypatch.setattr(pallas_attention, "causal_gqa", interpreted)
     got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
-    assert calls == [((1, TILE, 2, 192), (1, TILE, 2, 192),
-                      (1, TILE, 2, 128))]
+    assert calls == [((1, 2, TILE, 192), (1, 2, TILE, 192),
+                      (1, 2, TILE, 128))]
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for (path, got_leaf), want_leaf in zip(
             jax.tree_util.tree_flatten_with_path(grads)[0],
@@ -730,7 +754,7 @@ def test_kernel_agrees_with_scores_in_blocks_under_a_window(dtype):
                                                   interpret=True))
     (_, out), grads = kernel(q, k, v, w)
     (_, want), want_grads = _weighted(functools.partial(
-        lfm2._scores_in_blocks, q_block=512, mask=WINDOW))(q, k, v, w)
+        lfm2._plain_scores, q_block=512, mask=WINDOW))(q, k, v, w)
     assert out.dtype == dtype and out.shape == q.shape
     tol = TOLERANCE[dtype]
     assert _gap(out, want) < tol
@@ -739,9 +763,9 @@ def test_kernel_agrees_with_scores_in_blocks_under_a_window(dtype):
         assert _gap(got, ref) < tol, name
     # and it is no causal attention: the last tile's queries read a window
     causal = causal_gqa(_scaled(q), k, v, interpret=True)
-    assert _gap(out[:, 3 * TILE:], causal[:, 3 * TILE:]) > 0.1
-    np.testing.assert_allclose(np.asarray(out[:, :1500], np.float32),
-                               np.asarray(causal[:, :1500], np.float32),
+    assert _gap(out[:, :, 3 * TILE:], causal[:, :, 3 * TILE:]) > 0.1
+    np.testing.assert_allclose(np.asarray(out[:, :, :1500], np.float32),
+                               np.asarray(causal[:, :, :1500], np.float32),
                                rtol=2e-2, atol=1e-3)
 
 
@@ -751,9 +775,9 @@ def test_the_kernel_reads_a_window_and_no_more():
     q, k, v, _ = _inputs(1, jnp.float32, t=4 * TILE, dims=SDAR)
     fn = jax.jit(lambda v: masked_gqa(_scaled(q), k, v, WINDOW,
                                       interpret=True))
-    base, moved = fn(v), fn(v.at[:, 1000].add(5.0))
+    base, moved = fn(v), fn(v.at[:, :, 1000].add(5.0))
     changed = np.flatnonzero(np.asarray(
-        jnp.any(base != moved, axis=(0, 2, 3))))
+        jnp.any(base != moved, axis=(0, 1, 3))))
     assert changed.min() == 1000 and changed.max() == 1000 + 1499
     assert len(changed) == 1500
 
@@ -832,7 +856,7 @@ def test_attention_without_head_norms_takes_the_kernel_under_its_mask(
                         functools.partial(engages, platform="tpu"))
     monkeypatch.setattr(pallas_attention, "masked_gqa", interpreted)
     got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
-    assert calls == [((1, 2 * TILE, 2, 128), mask)]
+    assert calls == [((1, 2, 2 * TILE, 128), mask)]
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(grads),
                                    jax.tree_util.tree_leaves(want_grads)):
@@ -841,3 +865,89 @@ def test_attention_without_head_norms_takes_the_kernel_under_its_mask(
     other = jax.jit(lambda p, u: jnp.sum(lfm2.attention(
         p, u, cfg, mask, rotate=not rotate) ** 2))(p, u)
     assert abs(float(other) - float(want)) > 1e-3 * abs(float(want))
+
+
+# ---------------------------------------------------------------------------
+# head-major from product to product (PR 45)
+# ---------------------------------------------------------------------------
+
+def _causal_layer():
+    cfg = dataclasses.replace(lfm2.tiny(), head_dim=64, attn_q_block=256)
+    p, u = _attention_inputs(cfg, TILE)
+    return functools.partial(lfm2.attention, cfg=cfg), p, u, (4, 2, TILE, 64)
+
+
+def _headless_layer(mask, rotate):
+    from grace_tpu.models import smallthinker
+    cfg = smallthinker.tiny(head_dim=128, num_attention_heads=2,
+                            num_key_value_heads=1, attn_q_block=512)
+    p, u = _headless_inputs(cfg, 2 * TILE)
+    return (functools.partial(lfm2.attention, cfg=cfg, mask=mask,
+                              rotate=rotate), p, u, (2, 1, 2 * TILE, 128))
+
+
+def _block_diffusion_layer():
+    cfg = sdar.tiny(head_dim=128, attn_q_block=256)
+    p = sdar.init(jax.random.key(8), cfg)[0]["layers"][0]["attn"]
+    p = jax.tree_util.tree_map(lambda x: x * 8 if x.ndim == 2 else x, p)
+    u = jax.random.normal(jax.random.key(9), (1, T, cfg.hidden_size))
+    return (functools.partial(lfm2.attention, cfg=cfg, mask=BLOCK_MASK,
+                              positions=np.tile(np.arange(TILE), 2)), p, u,
+            (cfg.num_attention_heads, cfg.num_key_value_heads, T, 128))
+
+
+def _latent_layer():
+    cfg, p, u = _mla_case(TILE, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                          v_head_dim=128, attn_q_block=256)
+    return functools.partial(deepseek_v3.mla, cfg=cfg), p, u, (2, 2, TILE, 192)
+
+
+LAYERS = {
+    "causal": _causal_layer,
+    "window": functools.partial(_headless_layer, SlidingWindow(700), True),
+    "no-position": functools.partial(_headless_layer, CAUSAL, False),
+    "block-diffusion": _block_diffusion_layer,
+    "latent": _latent_layer,
+}
+
+
+@pytest.mark.parametrize("layer", sorted(LAYERS))
+def test_a_layer_on_the_kernel_route_equals_its_plain_route(monkeypatch,
+                                                            layer):
+    """``lfm2.attention`` (causal with head norms, windowed and without
+    positions as SmallThinker's layers are, under the block-diffusion mask
+    by position ids as SDAR's) and ``deepseek_v3.mla``, with ``engages``
+    answering as on a TPU and the kernel interpreted, against the same layer
+    on the plain route: value, parameter gradients and the input's gradient
+    in float32. The kernel's wrapper is handed head-major operands, ``(n, H,
+    T, D)``, as the projections wrote them: no axis is moved between a
+    product and the kernel."""
+    attend, p, u, (hq, hkv, t, d) = LAYERS[layer]()
+
+    def loss(p, u):
+        return jnp.sum(attend(p, u) ** 2)
+
+    def grad():     # traced anew on either route
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+
+    want, want_grads = grad()(p, u)
+    handed = []
+
+    def interpreted(q, k, v, mask=CAUSAL):
+        handed.append((q.shape, k.shape[:3], v.shape[:3]))
+        return masked_gqa(q, k, v, mask, interpret=True)
+
+    monkeypatch.setattr(pallas_attention, "engages",
+                        functools.partial(engages, platform="tpu"))
+    monkeypatch.setattr(pallas_attention, "causal_gqa", interpreted)
+    monkeypatch.setattr(pallas_attention, "masked_gqa", interpreted)
+    got, grads = grad()(p, u)
+    n = u.shape[0]
+    assert handed == [((n, hq, t, d), (n, hkv, t), (n, hkv, t))]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for (path, got_leaf), want_leaf in zip(
+            jax.tree_util.tree_flatten_with_path(grads)[0],
+            jax.tree_util.tree_leaves(want_grads)):
+        assert _gap(got_leaf, want_leaf) < TOLERANCE[jnp.float32], \
+            jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(want_leaf))) > 0

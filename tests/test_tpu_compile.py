@@ -37,7 +37,8 @@ import grace_tpu.ops
 from grace_tpu import grace_from_params
 from grace_tpu.compressors.topk import static_k
 from grace_tpu.memories import ResidualMemory
-from grace_tpu.models import deepseek_v3, lfm2, resnet, sdar
+from grace_tpu.models import (deepseek_v3, lfm2, resnet, sdar,
+                              smallthinker)
 from grace_tpu.ops import pallas_attention, sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
                                         quantize_stochastic, sign_pack)
@@ -55,6 +56,7 @@ from benchmarks import harness  # noqa: E402
 from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
 from benchmarks.models import sdar_moe  # noqa: E402
+from benchmarks.models import smallthinker_moe  # noqa: E402
 from benchmarks.reference import train as plain_train  # noqa: E402
 from benchmarks.trace_reduce import stage_of  # noqa: E402
 
@@ -633,6 +635,151 @@ def test_the_name_alone_changes_nothing(one_chip, monkeypatch, keep_nothing,
 
 
 # ---------------------------------------------------------------------------
+# attention is head-major from product to product (PR 45): the relayouts that
+# stand under the attention stage of a compiled part
+# ---------------------------------------------------------------------------
+
+def _smallthinker_part_text(one_chip, layer):
+    """Attention of layer ``layer`` of the benchmark's SmallThinker
+    configuration (28 query heads over 4 key/value heads of 128, 16,384
+    positions; layer 0 reads the whole prefix without positions, layer 1 a
+    window of 4,096, rotated)."""
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "smallthinker-21b-a3b-ep8.json")) as f:
+        sizes = json.load(f)
+    cfg = smallthinker_moe.model_config(sizes)
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+            sizes["seq_length"]) == (28, 4, 128, 16384)
+    assert (cfg.rope_layout[layer], cfg.sliding_window_layout[layer]) \
+        == (layer, layer)
+    shapes = jax.eval_shape(lambda k: smallthinker.init(k, cfg)[0],
+                            jax.random.key(0))
+    return _part_text(smallthinker._attention_part(cfg, layer),
+                      shapes["layers"][layer], cfg, one_chip,
+                      positions=sizes["seq_length"])
+
+
+def _layout_changes(text):
+    """``dtype[dims]`` of every layout-changing instruction a compiled part
+    runs under an attention stage: a ``copy``, a ``transpose``, or a
+    ``fusion`` whose root (through bitcasts) is one. Instructions inside
+    fusions are the fusion's, not counted again."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        header = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if header:
+            name = header.group(1)
+            bodies[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            found = re.match(r"\s+(ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                             r"([\w\-]+)\(%?([\w.\-]*)", line)
+            if found:
+                bodies[name].append(found.groups() + (line,))
+
+    def root(body):
+        by_name = {i[1]: i for i in bodies[body]}
+        at = next(i for i in bodies[body] if i[0])
+        while at[3] == "bitcast" and at[4] in by_name:
+            at = by_name[at[4]]
+        return at[3]
+
+    calls = {i[1]: re.search(r"calls=%([\w.\-]+)", i[5]).group(1)
+             for body in bodies.values() for i in body if i[3] == "fusion"}
+    found = []
+    for body, instructions in bodies.items():
+        if body in calls.values():
+            continue
+        for _, name, shape, opcode, _, line in instructions:
+            if opcode == "fusion":
+                opcode = root(calls[name])
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            if opcode in ("copy", "transpose") and op_name and stage_of(
+                    op_name.group(1)) in ATTENTION_STAGES:
+                found.append(shape)
+    return sorted(found)
+
+
+ATTENTION_STAGES = ("grace/attention", "grace/window_attention",
+                    "grace/mla_latent")
+
+
+def _weight_gradient_operands(positions, q, kv, dv=None):
+    """The relayouts a head-major part keeps: ``dq``, ``dk``, ``dv`` and the
+    kept output, bfloat16, made ``T``-minor for the four weight-gradient
+    products (``q``, ``kv``: heads and head size of the query and the key
+    and value projections' outputs; ``dv``: the output's head size)."""
+    (hq, dq), (hkv, dkv) = q, kv
+    return sorted([f"bf16[{hq},{dq},1,{positions}]",
+                   f"bf16[{hq},{dv or dq},{positions}]"]
+                  + [f"bf16[{hkv},{dkv},1,{positions}]"] * (2 if hkv != hq
+                                                            else 1))
+
+
+# decoder: (the part's compiled text, positions of a sequence, the elements
+# of a sequence's values: what counts as activation-sized, how many
+# layout-changing instructions stand under the attention stage, which of
+# them are activation-sized)
+HEAD_MAJOR_PARTS = {
+    "lfm2": (_attention_part_text, 4096, 4096 * 8 * 64, 4,
+             _weight_gradient_operands(4096, (32, 64), (8, 64))),
+    # one product makes keys and values, 128 + 128 wide, of all 32 heads
+    "kanana": (_mla_part_text, 4096, 4096 * 32 * 128, 12,
+               _weight_gradient_operands(4096, (32, 192), (32, 256), 128)),
+    "sdar": (_sdar_part_text, 8192, 8192 * 4 * 128, 10,
+             _weight_gradient_operands(8192, (32, 128), (4, 128))),
+    "smallthinker-full": (
+        functools.partial(_smallthinker_part_text, layer=0), 16384,
+        16384 * 4 * 128, 8,
+        _weight_gradient_operands(16384, (28, 128), (4, 128))),
+    "smallthinker-window": (
+        functools.partial(_smallthinker_part_text, layer=1), 16384,
+        16384 * 4 * 128, 8,
+        _weight_gradient_operands(16384, (28, 128), (4, 128))),
+}
+
+
+@pytest.mark.parametrize("decoder", sorted(HEAD_MAJOR_PARTS))
+def test_the_relayouts_under_the_attention_stage(one_chip, monkeypatch,
+                                                 decoder):
+    """One attention part of each decoder at its cell's shape, forward,
+    recomputation and backward, compiled for the described chip with
+    ``engages`` answering as on it: the layout-changing instructions under
+    the attention stage (a count made here, not a time), and which of them
+    are activation-sized (the positions among their dimensions, and at
+    least a sequence's values in elements).
+
+    Attention is head-major from product to product (PR 45): no
+    activation-sized relayout is left in the forward pass or the
+    recomputation, none is float32, and the backward pass holds the four
+    (three under latent attention, whose keys and values are one product)
+    that XLA makes for the weight-gradient products, whose contraction over
+    the positions stands between the heads and the head size of a
+    head-major operand: ``dq``, ``dk``, ``dv`` and the kept output,
+    ``T``-minor, bfloat16. The rest are weight-sized casts and the shared
+    rotary key's.
+
+    The parent's parts (PR 44, ``(n, T, H, D)`` between the projections and
+    a wrapper that swapped axes; counted by this function on its compiled
+    texts): LFM2 9 in all, 9 of them activation-sized (four float32, the
+    head norms' backward; forward, recomputation and backward); kanana 13
+    and 4; SDAR 15 and 9 (four float32); SmallThinker 11 and 5 in either
+    kind of layer."""
+    part_text, positions, values, count, activations = \
+        HEAD_MAJOR_PARTS[decoder]
+    _as_on_the_chip(monkeypatch)
+    found = _layout_changes(part_text(one_chip))
+
+    def activation_sized(shape):
+        dims = [int(d) for d in re.findall(r"\d+", shape.split("[")[1])]
+        return positions in dims and np.prod(dims) >= values
+
+    assert [s for s in found if activation_sized(s)] == activations
+    assert len(found) == count, found
+
+
+# ---------------------------------------------------------------------------
 # the expert part of both decoders: expert-aligned tiles (PR 37)
 # ---------------------------------------------------------------------------
 
@@ -934,14 +1081,15 @@ def test_the_smallthinker_step_compiles_for_the_described_chip(
 
 # (kernel calls, bytes the compiled step holds) of the two causal decoder
 # cells, compiled here for the described chip by the same helper. The bytes
-# are PR 44's: the head that forms its gradient in the walk that makes the
-# logits (``lfm2.head_loss``) took 292,352 bytes from the LFM2 step and
-# 131,323,904 from kanana's (12,865,857,024 and 14,399,759,872 from PR 39
-# to PR 43, through the mask handed to the kernel as a value, position ids,
-# a router handed to the walk and a weighted head: PERF.md section 6).
+# are PR 45's: head-major attention took 1,354,752 bytes from the LFM2 step
+# and added 163,537,408 to kanana's, whose live bytes at the peak rose by
+# five 8 MB weight prefetches and the heap's packing by the rest (PERF.md
+# section 6, PR 45). PR 44's were 12,865,564,672 and 14,268,435,968 (the
+# head that forms its gradient in the walk that makes the logits), PR 39's
+# to PR 43's 12,865,857,024 and 14,399,759,872.
 CAUSAL_STEPS = {
-    "lfm2-24b-a2b-topk1pct-w1": (1, 12_865_564_672),
-    "kanana-2-30b-a3b-topk1pct-w1": (5, 14_268_435_968)}
+    "lfm2-24b-a2b-topk1pct-w1": (1, 12_864_209_920),
+    "kanana-2-30b-a3b-topk1pct-w1": (5, 14_431_973_376)}
 
 
 # Marked slow (outside tier-1): the two whole steps take 135 s and 90 s to
