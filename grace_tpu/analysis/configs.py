@@ -431,27 +431,21 @@ AUDIT_CONFIGS: List[Dict[str, Any]] = [
           "adapt": {"window": 5, "ladder": [{"compress_ratio": 0.2}]}},
          passes=_NO_WIRE, mode="train",
          guard={"fallback_after": 3, "fallback_steps": 8}, consensus=True),
-    # -- graft-retune variants (ISSUE 18): the two configs the online
-    #    re-tuner promotes between. The PowerSGD rank ladder is the
-    #    rung-invariant layout's standing proof: every rung's Q/P state is
-    #    padded to the ladder max rank so ONE lax.switch dispatches all
-    #    rungs over one state shape — a rank move is a mask flip, never a
-    #    reshape, which is what makes mid-run promotion (and the adapt
-    #    controller's tighten/loosen) a pure index change the auditor can
-    #    trace. This entry is also what the retune PREPARE gate audits
-    #    before staging a powersgd+ladder candidate.
+    # -- a PowerSGD rank ladder under adapt: the rung-invariant layout's
+    #    standing proof. Every rung's Q/P state is padded to the ladder
+    #    max rank so ONE lax.switch dispatches all rungs over one state
+    #    shape — a rank move is a mask flip, never a reshape, which is
+    #    what makes the adapt controller's tighten/loosen a pure index
+    #    change the auditor can trace.
     _cfg("adapt-powersgd-rankladder",
          {"compressor": "powersgd", "compress_rank": 4,
           "memory": "powersgd", "communicator": "allreduce",
           "escape": "fp16", "telemetry": True,
           "adapt": {"window": 5, "ladder": [{"compress_rank": 1}]}},
          passes=_NO_WIRE),
-    # The retune drill's incumbent under the full resilience stack: the
-    # shared-scale homomorphic codec inside the guarded train step with
-    # the consensus audit fingerprinting its replicated state — the exact
-    # config the controller checkpoints as last-known-good and demotes
-    # back to, so its audited trace is the standing proof the demotion
-    # target itself lints clean.
+    # homoqsgd flat under guard and consensus: the shared-scale
+    # homomorphic codec inside the guarded train step with the consensus
+    # audit fingerprinting its replicated state.
     _cfg("retune-incumbent-homoqsgd",
          {"compressor": "homoqsgd", "quantum_num": 7, "memory": "residual",
           "communicator": "allreduce", "fusion": "flat", "escape": "fp16",

@@ -33,7 +33,7 @@ Orthogonalization uses ``jnp.linalg.qr`` — a fused XLA op on the MXU —
 instead of the reference's column-by-column @torch.jit.script Gram-Schmidt
 (powersgd.py:7-18), which would serialize r matvecs.
 
-Rung-invariant state layout (graft-retune): an adapt ladder across
+Rung-invariant state layout (graft-adapt): an adapt ladder across
 PowerSGD *ranks* must thread one comp-state structure through every
 ``lax.switch`` branch, but a rank-r rung natively stores a ``(m, r)`` Q —
 structurally different per rung. ``state_rank`` decouples the stored

@@ -31,12 +31,6 @@ it (SURVEY.md has no counterpart — the reference assumes a fault-free run):
   static codec and the dense escape, tightening within one window of an
   error spike (before the guard would trip) and loosening with
   hysteresis when gradients go quiet.
-* :mod:`~grace_tpu.resilience.retune` — graft-retune fault-tolerant
-  online re-tuning: config promotion as a two-phase transaction
-  (lint-audited, state-migrated, footprint-validated PREPARE;
-  consensus-gated COMMIT) with a probation window that demotes
-  bit-exactly on any guard trip or consensus escalation, every leg
-  under the elastic drain watchdog's bounded-timeout discipline.
 """
 
 from __future__ import annotations
@@ -61,8 +55,6 @@ from grace_tpu.resilience.elastic import (ElasticController, ResizePlan,
 from grace_tpu.resilience.guard import (GUARD_ROLLBACK_EXCLUDED,
                                         GUARD_SCAN_EXCLUDED_TYPES,
                                         GuardState, guard_transform)
-from grace_tpu.resilience.retune import (RetuneController, StagedPromotion,
-                                         state_digest)
 
 __all__ = ["GUARD_ROLLBACK_EXCLUDED", "GUARD_SCAN_EXCLUDED_TYPES",
            "GuardState", "guard_transform", "guarded_chain",
@@ -73,8 +65,7 @@ __all__ = ["GUARD_ROLLBACK_EXCLUDED", "GUARD_SCAN_EXCLUDED_TYPES",
            "reshard_grace_state", "validate_resharded", "rejoin_barrier",
            "implant_stale_replica", "replica_variants",
            "AdaptConfig", "AdaptState", "AdaptMonitor", "adapt_report",
-           "normalize_adapt",
-           "RetuneController", "StagedPromotion", "state_digest"]
+           "normalize_adapt"]
 
 
 def guarded_chain(grace, *txs: optax.GradientTransformation,

@@ -10,7 +10,7 @@ around step 140?" means hand-joining five record shapes by eye.
 
 :class:`Timeline` is that join. It classifies every record into a **kind**
 (``telemetry`` / ``watch`` / ``anomaly`` / ``guard`` / ``consensus`` /
-``perf`` / ``lint`` / ``elastic`` / ``adapt`` / ``retune`` / ``other``),
+``perf`` / ``lint`` / ``elastic`` / ``adapt`` / ``other``),
 orders the whole run by ``(step, file
 position)`` — file position breaks ties so causality within a step is
 preserved exactly as the run emitted it — and exposes a small query API
@@ -29,7 +29,7 @@ from typing import Any, Dict, Iterable, List, Mapping, Optional
 __all__ = ["KINDS", "classify", "TimelineEvent", "Timeline"]
 
 KINDS = ("telemetry", "watch", "anomaly", "guard", "consensus", "perf",
-         "lint", "elastic", "adapt", "retune", "other")
+         "lint", "elastic", "adapt", "other")
 
 
 def classify(record: Mapping[str, Any]) -> str:
@@ -61,8 +61,6 @@ def classify(record: Mapping[str, Any]) -> str:
         return "elastic"
     if event.startswith("adapt"):
         return "adapt"
-    if event.startswith("retune"):
-        return "retune"
     return "other"
 
 
@@ -207,8 +205,7 @@ class Timeline:
             if isinstance(score, (int, float)):
                 max_score[k] = max(max_score.get(k, 0.0), float(score))
         firsts = {}
-        for kind in ("anomaly", "guard", "consensus", "lint", "adapt",
-                     "retune"):
+        for kind in ("anomaly", "guard", "consensus", "lint", "adapt"):
             ev = self.first(kind)
             if ev is not None:
                 firsts[f"first_{kind}_step"] = ev.step

@@ -379,117 +379,6 @@ def carry_replicated(old_tree, fresh_tree, convert=None):
                                   is_leaf=_is_grace)
 
 
-def _migrate_leaf(old, fresh):
-    """One leaf of the cross-config state migration map. Returns
-    ``(leaf, verdict)``:
-
-    * ``carried`` — same shape+dtype: the old leaf moves bit-exactly
-      (a PowerSGD Q whose padded layout did not change, a residual whose
-      gradient-space shape is config-independent).
-    * ``overlap`` — same dtype and same dims except the LAST axis: the
-      shared leading columns carry (``min(k_old, k_new)``), the rest keep
-      the fresh init. This is the PowerSGD rank-change rule: Q columns
-      are per-direction power-iteration state, so the directions both
-      layouts track warm-start and only genuinely new columns start from
-      the fresh draw.
-    * ``fresh`` — anything else (different codec family, different
-      matricization): no meaningful warm state exists; zero/fresh-init is
-      the PR-3 rationale's demand.
-    """
-    if old is None or fresh is None:
-        return fresh, "carried" if (old is None and fresh is None) else "fresh"
-    if not (hasattr(old, "shape") and hasattr(fresh, "shape")):
-        return fresh, "fresh"
-    if old.dtype != fresh.dtype:
-        return fresh, "fresh"
-    if old.shape == fresh.shape:
-        return old, "carried"
-    if (old.ndim == fresh.ndim and old.ndim >= 1
-            and old.shape[:-1] == fresh.shape[:-1]):
-        k = min(old.shape[-1], fresh.shape[-1])
-        return fresh.at[..., :k].set(old[..., :k]), "overlap"
-    return fresh, "fresh"
-
-
-def migrate_state_tree(old, fresh):
-    """Leafwise migration of one varying-state pytree (a GraceState
-    ``mem`` or ``comp`` field) from an OLD config's layout onto a FRESH
-    init under the new config. Structures that do not match at the pytree
-    level migrate nothing (the new codec family keeps its fresh state).
-    Returns ``(tree, {"carried": n, "overlap": n, "fresh": n,
-    "structure_match": bool})``."""
-    old_td = jax.tree_util.tree_structure(old)
-    fresh_td = jax.tree_util.tree_structure(fresh)
-    stats = {"carried": 0, "overlap": 0, "fresh": 0,
-             "structure_match": old_td == fresh_td}
-    if not stats["structure_match"]:
-        stats["fresh"] = len(jax.tree_util.tree_leaves(fresh))
-        return fresh, stats
-
-    def leaf(o, f):
-        out, verdict = _migrate_leaf(o, f)
-        stats[verdict] += 1
-        return out
-
-    return jax.tree_util.tree_map(leaf, old, fresh), stats
-
-
-def migrate_grace_state(old_tree, fresh_tree, convert=None):
-    """Cross-CONFIG GraceState migration — the retune promotion's state
-    surgery, same shape as :func:`carry_replicated` (elastic's
-    cross-WORLD hook) but at a fixed world with a possibly different
-    codec/ladder:
-
-    * replicated fields ``count``/``rng_key``/``fallback``/``audit``
-      carry bit-exactly (step counter and consensus history continue
-      across the cutover);
-    * ``adapt`` takes the FRESH policy state — the ladder changed, so
-      the windowed statistics and operating rung learned under the old
-      config are meaningless (the elastic ``_reinit_adapt`` rationale);
-    * ``mem``/``comp`` migrate leafwise through :func:`migrate_state_tree`
-      — error-feedback residuals are gradient-shaped and codec-agnostic
-      (carried when shapes agree), compressor state carries whole or by
-      column overlap (PowerSGD warm start across promotions), else fresh;
-    * ``telem``/``watch`` take the fresh rings — per-rung wire plans and
-      window statistics are priced against the NEW config; splicing old
-      rows under new pricing would fabricate evidence;
-    * non-GraceState leaves (optimizer moments, guard counters) carry
-      from ``old_tree`` — replicated by the ``partition_specs`` contract.
-
-    Returns ``(state, stats)`` with per-field migration counts for the
-    PREPARE audit record.
-    """
-    conv = convert if convert is not None else (lambda x: x)
-    stats = {"mem": {"carried": 0, "overlap": 0, "fresh": 0},
-             "comp": {"carried": 0, "overlap": 0, "fresh": 0},
-             "mem_structure_match": True, "comp_structure_match": True}
-
-    def graft(old, fresh):
-        if _is_grace(old):
-            if not _is_grace(fresh):
-                raise ValueError(
-                    "migrate_grace_state: old tree has a GraceState where "
-                    f"the fresh tree has {type(fresh).__name__} — the two "
-                    "states were built from different optimizer chains.")
-            mem, ms = migrate_state_tree(old.mem, fresh.mem)
-            comp, cs = migrate_state_tree(old.comp, fresh.comp)
-            for k in ("carried", "overlap", "fresh"):
-                stats["mem"][k] += ms[k]
-                stats["comp"][k] += cs[k]
-            stats["mem_structure_match"] &= ms["structure_match"]
-            stats["comp_structure_match"] &= cs["structure_match"]
-            rep = {name: jax.tree_util.tree_map(conv, getattr(old, name))
-                   for name in GRACE_REPLICATED_FIELDS if name != "adapt"}
-            return fresh._replace(mem=jax.tree_util.tree_map(conv, mem),
-                                  comp=jax.tree_util.tree_map(conv, comp),
-                                  **rep)
-        return conv(old)
-
-    out = jax.tree_util.tree_map(graft, old_tree, fresh_tree,
-                                 is_leaf=_is_grace)
-    return out, stats
-
-
 def leaf_path_str(path) -> str:
     """The ``"/"``-joined spelling of a ``tree_flatten_with_path`` key path
     — the string codec routes match against (and the same spelling the

@@ -35,15 +35,11 @@ from grace_tpu.tuning.candidates import (Candidate, candidate_legal,
                                          variant_audit_entries)
 from grace_tpu.tuning.cost import TuneTopology, price_candidate, \
     projection_constants
-from grace_tpu.tuning.measure import (MeasureTimeout, bounded_call,
-                                      build_model_step, measure_shortlist,
+from grace_tpu.tuning.measure import (build_model_step, measure_shortlist,
                                       model_structs, overlap_sandwich)
-from grace_tpu.tuning.online import ONLINE_MEASURE_TIMEOUT_S, online_funnel
 from grace_tpu.tuning.prune import numeric_verdict, static_prune
 
-__all__ = ["Candidate", "MeasureTimeout", "ONLINE_MEASURE_TIMEOUT_S",
-           "TuneTopology", "bounded_call",
-           "candidate_legal", "online_funnel",
+__all__ = ["Candidate", "TuneTopology", "candidate_legal",
            "enumerate_candidates", "measure_shortlist", "model_structs",
            "numeric_verdict", "overlap_sandwich", "price_candidate",
            "projection_constants", "run_tune", "static_prune",
@@ -59,8 +55,6 @@ def run_tune(topologies: Sequence[Union[str, TuneTopology]], *,
              model: str = "toy", shortlist_n: int = 3,
              static_only: bool = False, audit_world: int = 8,
              timed_steps: int = 8, repeats: int = 2, seed: int = 0,
-             measure_timeout_s: Optional[float] = None,
-             measure_retries: int = 2,
              mesh=None, trace_dir: Optional[str] = None,
              argv: str = "") -> Dict[str, Any]:
     """The whole tuning loop; returns the ``TUNE_LAST.json`` document.
@@ -123,9 +117,7 @@ def run_tune(topologies: Sequence[Union[str, TuneTopology]], *,
             mesh = data_parallel_mesh(jax.devices())
         measured = measure_shortlist(
             shortlist, target, mesh, model=model,
-            timed_steps=timed_steps, repeats=repeats, seed=seed,
-            measure_timeout_s=measure_timeout_s,
-            measure_retries=measure_retries)
+            timed_steps=timed_steps, repeats=repeats, seed=seed)
         doc["measured"] = measured
         winner_name = measured["winner"]
         if winner_name is None:

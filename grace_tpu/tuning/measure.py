@@ -27,74 +27,14 @@ from __future__ import annotations
 
 import statistics
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 from grace_tpu.tuning.candidates import Candidate
 from grace_tpu.tuning.cost import (TuneTopology, dense_bytes, n_elements,
                                    price_candidate)
 
-__all__ = ["MeasureTimeout", "bounded_call", "build_model_step",
-           "measure_shortlist", "overlap_sandwich"]
+__all__ = ["build_model_step", "measure_shortlist", "overlap_sandwich"]
 
-
-class MeasureTimeout(RuntimeError):
-    """A timed measurement leg exceeded its bounded wait (after every
-    retry). Carries ``attempts`` and the final ``timeout_s``."""
-
-    def __init__(self, msg: str, *, attempts: int, timeout_s: float):
-        super().__init__(msg)
-        self.attempts = attempts
-        self.timeout_s = timeout_s
-
-
-def bounded_call(fn, timeout_s: Optional[float], *, retries: int = 0,
-                 label: str = "measurement"):
-    """Run ``fn()`` under a watchdog with retry + doubling backoff — the
-    elastic drain watchdog's discipline applied to a measurement leg.
-
-    ``fn`` runs on a daemon worker thread; the caller waits at most
-    ``timeout_s`` seconds, then retries with the timeout DOUBLED (the
-    backoff: a slow-but-alive leg gets geometrically more room, so only a
-    genuinely hung one exhausts the budget) up to ``retries`` times, then
-    raises :class:`MeasureTimeout`. The hung thread itself cannot be
-    killed from Python — it is abandoned (daemon) and the caller proceeds,
-    which is the whole point: a wedged candidate must never wedge the
-    tuner. ``timeout_s=None`` runs ``fn`` inline with no bound (the
-    historical behavior). Exceptions from ``fn`` propagate unchanged and
-    are never retried — a deterministic failure does not become flaky
-    success by repetition."""
-    if timeout_s is None:
-        return fn()
-    import threading
-
-    wait = float(timeout_s)
-    for attempt in range(retries + 1):
-        out: List[Any] = []
-        err: List[BaseException] = []
-        done = threading.Event()
-
-        def run():
-            try:
-                out.append(fn())
-            except BaseException as e:      # noqa: BLE001
-                err.append(e)
-            finally:
-                done.set()
-
-        t = threading.Thread(target=run, daemon=True,
-                             name=f"grace-measure-{label}-{attempt}")
-        t.start()
-        if done.wait(wait):
-            if err:
-                raise err[0]
-            return out[0]
-        if attempt < retries:
-            wait *= 2
-    raise MeasureTimeout(
-        f"{label} exceeded the bounded wait after {retries + 1} "
-        f"attempt(s) (final timeout {wait:.1f}s) — abandoning the hung "
-        "leg and proceeding",
-        attempts=retries + 1, timeout_s=wait)
 
 DENSE_ANCHOR = Candidate(
     name="dense", source="generated",
@@ -188,9 +128,7 @@ def _timed_step_s(step, state, batch, *, timed_steps: int,
 
 def measure_shortlist(shortlisted: List[Candidate], spec: TuneTopology,
                       mesh, *, model: str = "toy", timed_steps: int = 8,
-                      repeats: int = 2, seed: int = 0,
-                      measure_timeout_s: Optional[float] = None,
-                      measure_retries: int = 2
+                      repeats: int = 2, seed: int = 0
                       ) -> Dict[str, Any]:
     """Time every shortlisted candidate against an interleaved dense
     baseline; rank by the target-topology projection with each candidate's
@@ -200,12 +138,6 @@ def measure_shortlist(shortlisted: List[Candidate], spec: TuneTopology,
     name minimizing ``projected_step_ms`` at the target topology (measured
     compute + per-link wire), the EQuARX-style decision: compute measured
     where we are, wire priced where we're going.
-
-    With ``measure_timeout_s`` set, each candidate's whole measurement leg
-    (build + every timed sample) runs under :func:`bounded_call`: a hung
-    candidate is retried ``measure_retries`` times with doubling backoff,
-    then recorded in ``skipped`` with ``verdict='measure_timeout'`` and
-    the funnel moves on — one wedged config must never stall the tuner.
     """
     import jax
 
@@ -247,16 +179,7 @@ def measure_shortlist(shortlisted: List[Candidate], spec: TuneTopology,
             return live, samples, bsamples
 
         try:
-            live, samples, bsamples = bounded_call(
-                _measure, measure_timeout_s,
-                retries=measure_retries, label=cand.name)
-        except MeasureTimeout as e:
-            skipped.append({"candidate": cand.name,
-                            "verdict": "measure_timeout",
-                            "reason": str(e),
-                            "attempts": e.attempts,
-                            "timeout_s": e.timeout_s})
-            continue
+            live, samples, bsamples = _measure()
         except Exception as e:                           # noqa: BLE001
             skipped.append({"candidate": cand.name,
                             "verdict": "error",
@@ -286,8 +209,6 @@ def measure_shortlist(shortlisted: List[Candidate], spec: TuneTopology,
         if rows else None
     return {"rows": rows, "winner": winner, "skipped": skipped,
             "model": model, "timed_steps": timed_steps, "repeats": repeats,
-            "measure_timeout_s": measure_timeout_s,
-            "measure_retries": measure_retries,
             "measured_world": len(mesh.devices.flatten())}
 
 

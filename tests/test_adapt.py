@@ -44,10 +44,11 @@ from grace_tpu.resilience.adapt import (AdaptConfig, AdaptMonitor,
                                         AdaptState, adapt_advance,
                                         adapt_init, adapt_report,
                                         adapt_signal_bytes, normalize_adapt)
+from grace_tpu.resilience.consensus import _grace_nodes
 from grace_tpu.telemetry import TelemetryReader
 from grace_tpu.train import init_train_state, make_train_step
-from grace_tpu.transform import (GRACE_REPLICATED_FIELDS, GraceState,
-                                 grace_transform, partition_specs)
+from grace_tpu.transform import (GRACE_REPLICATED_FIELDS, grace_transform,
+                                 partition_specs)
 
 W = 8
 
@@ -441,10 +442,7 @@ def test_elastic_reshard_reinitializes_adapt(mesh):
                     "escalations": 0, "hold": 0, "quiet": 0,
                     "last_change_step": -1}
     # ...while the replicated clock carried bit-exactly.
-    graces = [n for n in jax.tree_util.tree_leaves(
-        resharded.opt_state,
-        is_leaf=lambda n: isinstance(n, GraceState))
-        if isinstance(n, GraceState)]
+    graces = _grace_nodes(resharded.opt_state)
     assert int(np.asarray(graces[0].count).reshape(-1)[0]) == 5
 
 
@@ -463,6 +461,53 @@ def test_mismatched_rung_state_structure_raises():
     grc = dataclasses.replace(grc, adapt=bad)
     with pytest.raises(ValueError, match="identical mem/comp state"):
         trace_update(grc, world=W, name="bad-ladder")
+
+
+def _mlp_params(rng):
+    return {
+        "w1": jnp.asarray(rng.normal(scale=0.3, size=(32, 16)),
+                          jnp.float32),
+        "b1": jnp.zeros((16,), jnp.float32),
+        "w2": jnp.asarray(rng.normal(scale=0.3, size=(16, 8)), jnp.float32),
+        "b2": jnp.zeros((8,), jnp.float32),
+    }
+
+
+def _mlp_loss(p, b):
+    x, y = b
+    h = jnp.tanh(x @ p["w1"] + p["b1"])
+    logits = h @ p["w2"] + p["b2"]
+    return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+
+def _mlp_batch(rng, n=16):
+    return (jnp.asarray(rng.normal(size=(n, 32)).astype(np.float32)),
+            jnp.asarray(rng.integers(0, 8, size=(n,)).astype(np.int32)))
+
+
+def test_powersgd_ladder_states_padded_to_max_rank(mesh, rng):
+    """The rung-invariant comp-state layout: a PowerSGD ladder pads every
+    per-direction leaf to the LADDER's max rank so one ``lax.switch``
+    dispatches all rungs over one state shape."""
+    grc = grace_from_params({"compressor": "powersgd", "compress_rank": 2,
+                             "memory": "powersgd",
+                             "communicator": "allreduce",
+                             "escape": "fp16", "telemetry": 16,
+                             "adapt": {"window": 5,
+                                       "ladder": [{"compress_rank": 4}]}})
+    tx = optax.chain(grc.transform(seed=0), optax.sgd(0.05))
+    state = init_train_state(_mlp_params(rng), tx, mesh)
+    ranks = {leaf.shape[-1]
+             for g in _grace_nodes(state.opt_state)
+             for leaf in jax.tree_util.tree_leaves(g.comp)
+             if hasattr(leaf, "ndim") and leaf.ndim >= 2}
+    assert ranks == {4}, (
+        f"comp-state last-axis ranks {ranks}: every rung must share the "
+        "ladder max (4) so rank moves are mask flips, not reshapes")
+    step = make_train_step(_mlp_loss, tx, mesh, donate=False)
+    for _ in range(3):
+        state, loss = step(state, _mlp_batch(rng))
+    assert np.isfinite(float(loss))
 
 
 # ---------------------------------------------------------------------------
@@ -746,7 +791,7 @@ def test_adaptive_matches_static_convergence_floor(mesh):
     reaches the hand-picked static config's final loss on a real
     trajectory (here bitwise-equal would also hold — the quiet ladder
     never leaves the top rung — but the floor comparison is the stated
-    contract and survives threshold retunes)."""
+    contract and survives a change of thresholds)."""
     loss_fn, batch = _ls_problem(seed=3)
 
     def final_loss(extra):

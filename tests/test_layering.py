@@ -11,11 +11,21 @@ No unit imports a script from the checkout root, under any name.
 stepped once in a process of its own, which must end without the static
 auditor, the tuner, the second trace reducer or the framework bridges in
 ``sys.modules``: nothing a cell measures can depend on them.
+
+(iii) What the documents name: every path under ``grace_tpu/``, ``tools/``,
+``tests/`` or ``benchmarks/`` and every dotted ``grace_tpu.<unit>`` name in
+the README and its sister documents must exist, so that a deletion's sweep
+of the documents can be checked.
+
+(iv) What the root's records claim: each ``*_LAST.json`` at the checkout
+root must still have the tool that writes it.
 """
 
 import ast
+import functools
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -64,8 +74,8 @@ ALLOWED = {
                "transform", "utils"},
     # the jitted shard_map step
     "train": {"core", "parallel", "telemetry", "transform"},
-    # guard, consensus, adapt; and the host-side controllers that drive
-    # train steps (elastic, retune)
+    # guard, consensus, adapt; and the host-side controller that drives
+    # train steps (elastic)
     "resilience": {"comm", "core", "parallel", "telemetry", "transform",
                    "train"},
     "profiling": {"telemetry", "transform", "utils"},
@@ -93,9 +103,8 @@ KNOWN_UPWARD = {
     ("helper", "resilience"):
         "debt (e): helper.py -> resilience.adapt (the ladder's rungs)",
     ("resilience", "analysis"):
-        "debt (e)/(f): elastic.py, retune.py -> analysis",
+        "debt (e)/(f): elastic.py -> analysis",
     ("resilience", "profiling"): "debt (e)/(f): elastic.py -> profiling",
-    ("resilience", "tuning"): "debt (a): retune.py -> tuning.online",
     ("analysis", "tuning"):
         "D7: analysis/configs.py -> tuning's generated variants",
 }
@@ -251,3 +260,101 @@ def test_a_cells_step_loads_only_the_hot_path(cell):
              if m.startswith(OFF_THE_HOT_PATH)
              or m.split(".")[0] in ROOT_MODULES]
     assert not extra, extra
+
+
+# ---------------------------------------------------------------------------
+# (iii) the documents name only what exists
+# ---------------------------------------------------------------------------
+
+DOCUMENTS = ("README.md", "IMPLEMENTING.md", "TRAINING.md",
+             "OBSERVABILITY.md", "INSTALLING.md", "examples/README.md")
+
+# `examples/` is left out: the reference repository has one of its own and
+# the documents cite it.
+DOC_PATH = re.compile(
+    r"(?<![\w/.-])(?:grace_tpu|tools|tests|benchmarks)/[\w./-]*")
+DOC_DOTTED = re.compile(r"(?<![\w.])grace_tpu(?:\.[A-Za-z_]\w*)+")
+
+
+def bound_names(init_py):
+    """The names a package's ``__init__.py`` binds at its top level."""
+    with open(init_py) as f:
+        tree = ast.parse(f.read(), init_py)
+    names = set()
+    for node in tree.body:
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            names.update((a.asname or a.name).split(".")[0]
+                         for a in node.names)
+        elif isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+        elif isinstance(node, ast.Assign):
+            names.update(t.id for t in node.targets
+                         if isinstance(t, ast.Name))
+    return names
+
+
+def dotted_name_resolves(dotted):
+    """Walk ``grace_tpu.a.b.c`` down the packages. It ends at a module
+    (what follows is an attribute, and is not resolved) or at a name a
+    package's ``__init__.py`` binds."""
+    where = REPO
+    for part in dotted.split("."):
+        if os.path.isdir(os.path.join(where, part)):
+            where = os.path.join(where, part)
+        elif os.path.isfile(os.path.join(where, part + ".py")):
+            return True
+        else:
+            return part in bound_names(os.path.join(where, "__init__.py"))
+    return True
+
+
+@pytest.mark.parametrize("document", DOCUMENTS)
+def test_a_document_names_only_what_exists(document):
+    with open(os.path.join(REPO, document)) as f:
+        text = f.read()
+    # a sentence's full stop is not the path's
+    paths = {p.rstrip(".") for p in DOC_PATH.findall(text)}
+    gone = sorted(p for p in paths
+                  if not os.path.exists(os.path.join(REPO, p)))
+    gone += sorted(n for n in set(DOC_DOTTED.findall(text))
+                   if not dotted_name_resolves(n))
+    assert not gone, f"{document} names what is not there: {gone}"
+
+
+# ---------------------------------------------------------------------------
+# (iv) a record at the root has the tool that writes it
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def written_names():
+    """Every string a file under ``tools/`` or ``grace_tpu/`` holds outside
+    its docstrings: an output's name is one of them, a mention in prose is
+    not."""
+    found = set()
+    for top in ("tools", "grace_tpu"):
+        for d, _, fs in os.walk(os.path.join(REPO, top)):
+            for f in fs:
+                if not f.endswith(".py"):
+                    continue
+                with open(os.path.join(d, f)) as src:
+                    tree = ast.parse(src.read(), f)
+                docstrings = {
+                    id(node.body[0].value) for node in ast.walk(tree)
+                    if isinstance(node, (ast.Module, ast.FunctionDef,
+                                         ast.ClassDef))
+                    and node.body and isinstance(node.body[0], ast.Expr)}
+                found.update(
+                    node.value for node in ast.walk(tree)
+                    if isinstance(node, ast.Constant)
+                    and isinstance(node.value, str)
+                    and id(node) not in docstrings)
+    return frozenset(found)
+
+
+@pytest.mark.parametrize("record", sorted(
+    f for f in os.listdir(REPO) if f.endswith("_LAST.json")))
+def test_a_root_record_has_the_tool_that_writes_it(record):
+    assert record in written_names(), (
+        f"{record}: no file under tools/ or grace_tpu/ names it as an "
+        "output. A record whose producer has gone is a claim nobody can "
+        "make again: delete it with the producer")

@@ -95,32 +95,6 @@ convergence-floor verdict, per-world footprint checks) lands in
 ``--elastic-out`` (ELASTIC_LAST.json);
 ``elastic_*`` events additionally stream into the telemetry JSONL.
 
-Retune scenario (ISSUE 18): ``--retune`` drills fault-tolerant online
-re-tuning (``grace_tpu.resilience.retune``) end to end. The run warms up
-on the 4-bit homomorphic codec (homoqsgd ``quantum_num=7``) while the
-:class:`RetuneController` learns its healthy compression-error baseline
-from live telemetry rows, then a FLEET-WIDE finite drift
-(``ChaosCompressor(drift_scale=..., rank=None)`` — every rank, so the
-windowed mean moves; the guard must stay silent) forces a sustained-drift
-verdict (``retune_drift``). The controller then promotes a PowerSGD
-rank-4 config carrying a rank-1 adapt ladder as a two-phase transaction:
-PREPARE (lint-audit the candidate, migrate ``GraceState`` leafwise with
-the rung-invariant overlap rule, footprint-check the migrated tree,
-checkpoint the incumbent as last-known-good) then COMMIT (consensus-gated
-cutover behind the rejoin barrier; replicas must end bit-identical). The
-probation window clears quietly, a second promotion migrates BACK to
-homoqsgd4 (the cross-family migration in both directions), and finally a
-SABOTAGED third promotion — the promoted codec wrapped in
-``ChaosCompressor(nan_prob=1.0)`` — must trip the guard during probation
-and trigger an automatic demotion that restores the pre-promotion
-checkpoint BIT-EXACTLY (``state_digest`` witness) within the probation
-window. Every transition leg is bounded by the drain watchdog discipline
-(``--drain-timeout``). Evidence (drift/promote/demote steps, migration
-stats, replica-variant counts, the event-ordering verdict, the bit-exact
-restore witness) lands in ``--retune-out`` (RETUNE_LAST.json);
-``retune_*`` events stream into the telemetry JSONL (timeline kind
-``retune``).
-
 Region scenario (ISSUE 16): ``--region`` runs the cross-region failure
 lifecycle on the 8-device mesh laid out as 2 regions × 2 slices × 2 ranks
 (``Topology(slice_size=2, region_size=4)``, three-level hier exchange).
@@ -149,7 +123,6 @@ Usage::
     python tools/chaos_smoke.py --elastic                    # kill + rejoin
     python tools/chaos_smoke.py --elastic --hier --slice-size 4  # slice kill
     python tools/chaos_smoke.py --region                     # region kill
-    python tools/chaos_smoke.py --retune                     # config retune
 """
 
 from __future__ import annotations
@@ -298,29 +271,6 @@ def main(argv=None) -> int:
                          "a region wide so all three tiers are exercised)")
     ap.add_argument("--region-out", default="REGION_LAST.json",
                     help="evidence JSON path for --region ('' disables)")
-    ap.add_argument("--retune", action="store_true",
-                    help="online re-tuning drill (ISSUE 18): warm up on "
-                         "homoqsgd4, inject fleet-wide drift until the "
-                         "RetuneController flags it, promote to a powersgd "
-                         "rank ladder as a two-phase transaction (guard "
-                         "silent, replicas bit-identical), clear probation, "
-                         "promote back, then sabotage a third promotion "
-                         "(ChaosCompressor NaNs the promoted codec) and "
-                         "require automatic bit-exact demotion within the "
-                         "probation window")
-    ap.add_argument("--retune-window", type=int, default=6,
-                    help="controller drift window in telemetry rows "
-                         "(with --retune)")
-    ap.add_argument("--retune-probation", type=int, default=18,
-                    help="probation steps after each promotion "
-                         "(with --retune)")
-    ap.add_argument("--retune-funnel", action="store_true",
-                    help="with --retune: after drift fires, re-run the "
-                         "tuner's bounded static+measured funnel against "
-                         "the live mesh (RetuneController.propose) and "
-                         "record its verdict in the evidence doc")
-    ap.add_argument("--retune-out", default="RETUNE_LAST.json",
-                    help="evidence JSON path for --retune ('' disables)")
     ap.add_argument("--drain-timeout", type=float, default=60.0,
                     help="ElasticController drain watchdog seconds "
                          "(--region; 0 disables the watchdog)")
@@ -381,8 +331,6 @@ def main(argv=None) -> int:
 
     if args.adapt:
         return _adapt_main(args)
-    if args.retune:
-        return _retune_main(args)
     if args.elastic:
         return _elastic_main(args)
     if args.region:
@@ -1207,412 +1155,6 @@ def _adapt_main(args) -> int:
         print("[chaos_smoke] FAIL: the first adapt event does not "
               "precede the first guard event — tighten-before-guard is "
               "the scenario's claim", file=sys.stderr)
-        return 1
-    print("[chaos_smoke] OK")
-    return 0
-
-
-def _retune_main(args) -> int:
-    """The --retune lifecycle: baseline → fleet drift → retune_drift →
-    promote (two-phase) → probation clears → promote back → sabotaged
-    promotion → automatic bit-exact demotion. Returns 0 only when every
-    acceptance fact holds (see module docstring)."""
-    import dataclasses
-    import tempfile
-
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from grace_tpu import grace_from_params
-    from grace_tpu.checkpoint import Checkpointer
-    from grace_tpu.parallel import data_parallel_mesh
-    from grace_tpu.resilience import (ChaosCompressor, ConsensusConfig,
-                                      RetuneController, guarded_chain,
-                                      replica_variants)
-    from grace_tpu.telemetry import JSONLSink, MultiSink, TelemetryReader
-    from grace_tpu.telemetry.timeline import Timeline
-    from grace_tpu.train import init_train_state, make_train_step
-    from grace_tpu.utils.logging import GuardMonitor, run_provenance
-    from grace_tpu.utils.metrics import guard_report
-
-    mesh = data_parallel_mesh()
-    world = mesh.devices.size
-    window = args.retune_window
-    probation = args.retune_probation
-    # Flush cadence must not exceed the drift window, or the controller
-    # only ever sees rows (and can only fire) at flush boundaries.
-    tev = max(1, min(args.telemetry_every, window))
-    telem = max(2 * tev, 16)
-
-    consensus = ConsensusConfig(audit_every=args.audit_every)
-    # The incumbent: the 4-bit homomorphic family (shared-scale payload
-    # algebra). The candidate: PowerSGD rank 4 carrying a rank-1 adapt
-    # ladder — the stateful-codec migration the rung-invariant layout
-    # exists for (Q/P padded to max rank, one lax.switch).
-    old_params = {"compressor": "homoqsgd", "quantum_num": 7,
-                  "memory": "residual", "communicator": "allreduce",
-                  "fusion": "flat", "escape": "fp16",
-                  "telemetry": telem, "consensus": consensus}
-    new_params = {"compressor": "powersgd", "compress_rank": 4,
-                  "memory": "powersgd", "communicator": "allreduce",
-                  "escape": "fp16", "telemetry": telem,
-                  "consensus": consensus,
-                  "adapt": {"window": window,
-                            "ladder": [{"compress_rank": 1}]}}
-
-    # Chaos is toggled OUTSIDE the controller: the same build closure
-    # serves every transition, and the sabotage variant flips "nan" only
-    # between PREPARE and COMMIT of the doomed promotion — the demotion's
-    # rebuild of the incumbent sees a clean flag, exactly like a config
-    # push whose payload (not the push machinery) is poisoned.
-    chaos = {"drift": False, "nan": False}
-
-    def build(p):
-        grc = grace_from_params(p)
-        wraps = []
-        if chaos["drift"]:
-            # rank=None faults EVERY rank: the drift must move the
-            # fleet-mean compression error (a single-rank drift is
-            # graft-watch's scenario; sustained fleet drift is retune's).
-            wraps.append(lambda c: ChaosCompressor(
-                inner=c, drift_scale=args.drift_scale, rank=None,
-                seed=args.seed + 3))
-        if chaos["nan"]:
-            wraps.append(lambda c: ChaosCompressor(
-                inner=c, nan_prob=1.0, rank=args.rank,
-                seed=args.seed + 5))
-        for wrap in wraps:
-            grc = dataclasses.replace(grc, compressor=wrap(grc.compressor))
-            if grc.adapt is not None:
-                grc = dataclasses.replace(grc, adapt=dataclasses.replace(
-                    grc.adapt,
-                    ladder=tuple(wrap(c) for c in grc.adapt.ladder)))
-        tx = guarded_chain(grc, optax.sgd(args.lr),
-                           fallback_after=args.fallback_after,
-                           fallback_steps=args.fallback_steps)
-        return grc, tx
-
-    # Small dense MLP (the _adapt_main scale): this scenario recompiles
-    # the step five times across two codec families.
-    feat, hid, classes = 32, 16, 8
-    rng = np.random.default_rng(args.seed)
-    params = {
-        "w1": jnp.asarray(rng.normal(scale=0.3, size=(feat, hid)),
-                          jnp.float32),
-        "b1": jnp.zeros((hid,), jnp.float32),
-        "w2": jnp.asarray(rng.normal(scale=0.3, size=(hid, classes)),
-                          jnp.float32),
-        "b2": jnp.zeros((classes,), jnp.float32),
-    }
-
-    def loss_fn(p, b):
-        x, y = b
-        h = jnp.tanh(x @ p["w1"] + p["b1"])
-        logits = h @ p["w2"] + p["b2"]
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, y).mean()
-
-    batch = max(args.batch, world) // world * world
-    images = rng.normal(size=(4 * batch, feat)).astype(np.float32)
-    labels = rng.integers(0, classes, size=(4 * batch,)).astype(np.int32)
-
-    def at(i):
-        lo = (i * batch) % (len(images) - batch + 1)
-        return (jnp.asarray(images[lo:lo + batch]),
-                jnp.asarray(labels[lo:lo + batch]))
-
-    if not args.telemetry_out:
-        print("[chaos_smoke] --retune requires --telemetry-out: the "
-              "acceptance artifact IS the retune event ordering",
-              file=sys.stderr)
-        return 1
-    prov = run_provenance(
-        data="synthetic", tool="chaos_smoke",
-        argv=" ".join(sys.argv[1:]), steps=args.steps,
-        retune=True, retune_window=window, retune_probation=probation)
-
-    class _Tape:
-        """Sink that mirrors the record stream into a list — the
-        probation watch is fed the same records the artifact gets."""
-
-        def __init__(self):
-            self.records = []
-
-        def write(self, rec):
-            self.records.append(dict(rec))
-
-        def close(self):
-            pass
-
-    tape = _Tape()
-    sink = MultiSink(JSONLSink(args.telemetry_out, provenance=prov), tape)
-    reader = TelemetryReader(sink, every=tev)
-    monitor = GuardMonitor(sink=sink)
-
-    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="grace-retune-")
-    ckpt = Checkpointer(ckpt_dir, max_to_keep=2)
-    ctl = RetuneController(
-        build=build, params=old_params, consensus=consensus,
-        checkpointer=ckpt, sink=sink,
-        window=window, drift_factor=1.4, drift_windows=2,
-        probation_steps=probation,
-        leg_timeout_s=args.drain_timeout or None, leg_retries=1,
-        audit_world=world)
-
-    t0 = time.perf_counter()
-    grc, tx = build(old_params)
-    state = init_train_state(params, tx, mesh)
-    step_fn = make_train_step(loss_fn, tx, mesh, donate=False)
-
-    def run_steps(state, step_fn, lo, hi, observe=False):
-        """Advance [lo, hi); returns (state, loss, first drift step or
-        None, probation trigger or None). Each step feeds the guard
-        monitor, drains telemetry rows into the controller's drift watch
-        (when asked), and — during probation — shows the controller the
-        fresh trigger-event records from this step's tape window."""
-        loss, drift_step, trigger = float("nan"), None, None
-        for i in range(lo, hi):
-            state, loss = step_fn(state, at(i))
-            n0 = len(tape.records)
-            monitor.update(i, guard_report(state))
-            rows = reader.update(i, state)
-            if observe:
-                for row in rows:
-                    if ctl.observe(row["step"],
-                                   row.get("compression_error")):
-                        drift_step = (drift_step if drift_step is not None
-                                      else int(row["step"]))
-                if drift_step is not None:
-                    return state, float(loss), drift_step, None, i
-            if ctl.phase == "probation":
-                trigger = ctl.watch(i, tape.records[n0:])
-                if trigger:
-                    return state, float(loss), drift_step, trigger, i
-        return state, float(loss), drift_step, None, hi - 1
-
-    # ---- healthy drill: baseline → fleet drift → retune_drift ----------
-    warmup = 3 * window + 2
-    state, loss, _, _, _ = run_steps(state, step_fn, 0, warmup,
-                                     observe=True)
-    chaos["drift"] = True
-    _, tx_d = build(old_params)
-    step_d = make_train_step(loss_fn, tx_d, mesh, donate=False)
-    drift_cap = warmup + 8 * window
-    state, loss, drift_step, _, last = run_steps(state, step_d, warmup,
-                                                 drift_cap, observe=True)
-    chaos["drift"] = False
-    guard_rep = guard_report(state)
-    print(f"[chaos_smoke] retune drift: fleet drift_scale "
-          f"{args.drift_scale} from step {warmup} | retune_drift at step "
-          f"{drift_step} | guard skips {guard_rep['notfinite_count']}")
-    if drift_step is None:
-        print("[chaos_smoke] FAIL: sustained fleet-wide drift never "
-              "produced a retune_drift verdict — the controller is not "
-              "reading the telemetry it was built for", file=sys.stderr)
-        return 1
-    if guard_rep["notfinite_count"] != 0:
-        print("[chaos_smoke] FAIL: guard tripped during the drift phase "
-              "— the fault is finite and guard-invisible; the smoke "
-              "itself is broken", file=sys.stderr)
-        return 1
-
-    # ---- optional bounded funnel against the live mesh -----------------
-    funnel_doc = None
-    if args.retune_funnel:
-        funnel_doc = ctl.propose(
-            last + 1, mesh, str(world), model="toy", shortlist_n=2,
-            timed_steps=2, repeats=1, seed=args.seed, audit_world=world)
-        print(f"[chaos_smoke] retune funnel: winner "
-              f"{funnel_doc['winner'] if funnel_doc else None}")
-
-    def promote(i, state, cand, label):
-        """One PREPARE+COMMIT transaction; None on abort."""
-        staged = ctl.prepare(i, state, mesh, cand)
-        if staged is None:
-            print(f"[chaos_smoke] FAIL: PREPARE aborted for {label}: "
-                  f"{ctl.events[-1]}", file=sys.stderr)
-            return None
-        out = ctl.commit(i, mesh)
-        if out is None:
-            print(f"[chaos_smoke] FAIL: COMMIT timed out for {label} — "
-                  f"incumbent retained: {ctl.events[-1]}", file=sys.stderr)
-            return None
-        state, (_, tx), ev = out
-        mig = staged.migration
-        print(f"[chaos_smoke] retune promote ({label}) at step {i}: "
-              f"mem {mig['mem']} comp {mig['comp']} | footprint "
-              f"{staged.footprint_matches} | checkpointed "
-              f"{staged.checkpointed} | probation until "
-              f"{ev['probation_until']}")
-        return state, tx, ev, mig
-
-    # ---- promotion 1: homoqsgd4 → powersgd rank ladder ------------------
-    i0 = last + 1
-    out = promote(i0, state, new_params, "homoqsgd4 -> powersgd ladder")
-    if out is None:
-        return 1
-    state, tx2, ev_fwd, mig_fwd = out
-    step2 = make_train_step(loss_fn, tx2, mesh, donate=False)
-    variants_fwd = replica_variants(state.params)
-    state, loss, _, trig, _ = run_steps(state, step2, i0,
-                                        i0 + probation + 1)
-    if trig is not None:
-        print(f"[chaos_smoke] FAIL: healthy probation tripped "
-              f"({trig}) after the forward promotion", file=sys.stderr)
-        return 1
-    if ctl.phase != "idle":
-        print("[chaos_smoke] FAIL: probation never cleared after the "
-              "forward promotion", file=sys.stderr)
-        return 1
-
-    # ---- promotion 2: back to homoqsgd4 (reverse migration) -------------
-    i0 += probation + 1
-    out = promote(i0, state, old_params, "powersgd ladder -> homoqsgd4")
-    if out is None:
-        return 1
-    state, tx3, ev_back, mig_back = out
-    step3 = make_train_step(loss_fn, tx3, mesh, donate=False)
-    state, loss, _, trig, _ = run_steps(state, step3, i0,
-                                        i0 + probation + 1)
-    if trig is not None or ctl.phase != "idle":
-        print(f"[chaos_smoke] FAIL: back-promotion probation did not "
-              f"clear quietly (trigger={trig}, phase={ctl.phase})",
-              file=sys.stderr)
-        return 1
-    guard_rep = guard_report(state)
-    healthy_guard_events = [r for r in tape.records
-                            if str(r.get("event", "")).startswith("guard")]
-    print(f"[chaos_smoke] retune healthy drill done: loss {loss:.4f} | "
-          f"replica variants after forward commit {variants_fwd} | guard "
-          f"events {len(healthy_guard_events)}")
-    if healthy_guard_events:
-        print("[chaos_smoke] FAIL: the guard fired during the healthy "
-              "drill — promotion must be guard-invisible", file=sys.stderr)
-        return 1
-    if variants_fwd != 1:
-        print(f"[chaos_smoke] FAIL: {variants_fwd} replica variants "
-              "after the consensus-gated cutover", file=sys.stderr)
-        return 1
-
-    # ---- sabotage: promoted config is poisoned → demote -----------------
-    i0 += probation + 1
-    chaos["nan"] = True
-    out = promote(i0, state, new_params, "sabotaged powersgd ladder")
-    chaos["nan"] = False
-    if out is None:
-        return 1
-    state, tx_sab, ev_sab, _ = out
-    step_sab = make_train_step(loss_fn, tx_sab, mesh, donate=False)
-    sab_state, loss, _, trig, trig_step = run_steps(
-        state, step_sab, i0, i0 + probation + 1)
-    if trig is None:
-        print("[chaos_smoke] FAIL: the poisoned promotion survived its "
-              "probation window — the NaN injection never reached the "
-              "guard", file=sys.stderr)
-        return 1
-    within = trig_step < ev_sab["probation_until"]
-    state, (_, tx4), ev_dem = ctl.demote(trig_step, sab_state, mesh,
-                                         trigger=trig)
-    step4 = make_train_step(loss_fn, tx4, mesh, donate=False)
-    state, loss, _, _, _ = run_steps(state, step4, trig_step,
-                                     trig_step + 4)
-    reader.flush(state)
-    reader.close()
-    ckpt.close()
-    dt = time.perf_counter() - t0
-    print(f"[chaos_smoke] retune sabotage: trigger {trig} at step "
-          f"{trig_step} (probation until {ev_sab['probation_until']}) | "
-          f"demote restored={ev_dem['restored']} "
-          f"bit_exact={ev_dem['bit_exact']} | post-demote loss "
-          f"{loss:.4f} | {dt:.1f}s total")
-
-    # Ordering is judged from the ARTIFACT, not loop bookkeeping: the
-    # transaction's event sequence in the unified timeline must read
-    # drift < prepare < promote < probation_clear, and the sabotage
-    # demotion must land before its probation horizon.
-    tl = Timeline.from_jsonl(args.telemetry_out)
-    firsts = {}
-    for e in tl.kinds("retune"):
-        name = str(e.record.get("event"))
-        if name not in firsts and e.step is not None:
-            firsts[name] = e.step
-    order = ["retune_drift", "retune_prepare", "retune_promote",
-             "retune_probation_clear"]
-    ordering_ok = (all(n in firsts for n in order) and
-                   all(firsts[a] <= firsts[b] for a, b in
-                       zip(order, order[1:])) and
-                   "retune_demote" in firsts)
-    print(f"[chaos_smoke] retune ordering: "
-          + " <= ".join(f"{n.split('retune_')[1]}@{firsts.get(n)}"
-                        for n in order)
-          + f", demote@{firsts.get('retune_demote')} -> "
-          + ("OK" if ordering_ok else "VIOLATED"))
-
-    if args.retune_out:
-        doc = {
-            "tool": "chaos_smoke",
-            "captured_at": time.strftime("%Y-%m-%dT%H:%M:%S"),
-            "argv": " ".join(sys.argv[1:]),
-            "world": world,
-            "window": window,
-            "probation_steps": probation,
-            "incumbent": "homoqsgd quantum_num=7 (4-bit shared-scale)",
-            "candidate": "powersgd rank 4 + rank-1 adapt ladder",
-            "drift": {"scale": args.drift_scale, "from_step": warmup,
-                      "verdict_step": drift_step},
-            "funnel": (None if funnel_doc is None else {
-                "winner": funnel_doc.get("winner"),
-                "measured": [
-                    {"candidate": r["candidate"],
-                     "measured_step_ms": r["measured_step_ms"],
-                     "projected_step_ms": r["projected_step_ms"]}
-                    for r in (funnel_doc.get("measured") or {})
-                    .get("rows", [])],
-                "skipped": (funnel_doc.get("measured") or {})
-                .get("skipped", [])}),
-            "forward_promotion": {
-                "step": ev_fwd["step"],
-                "migration": mig_fwd,
-                "replica_variants": variants_fwd,
-                "probation_until": ev_fwd["probation_until"]},
-            "back_promotion": {
-                "step": ev_back["step"],
-                "migration": mig_back,
-                "probation_until": ev_back["probation_until"]},
-            "sabotage": {
-                "promote_step": ev_sab["step"],
-                "trigger": trig,
-                "trigger_step": trig_step,
-                "probation_until": ev_sab["probation_until"],
-                "within_probation": bool(within),
-                "restored": bool(ev_dem["restored"]),
-                "bit_exact": bool(ev_dem["bit_exact"])},
-            "guard_events_during_healthy_drill":
-                len(healthy_guard_events),
-            "ordering_ok": bool(ordering_ok),
-            "first_steps": firsts,
-            "final_loss": float(loss),
-        }
-        _write_evidence_doc(doc, args.retune_out, world=world,
-                            label="retune evidence")
-
-    if not np.isfinite(loss):
-        print("[chaos_smoke] FAIL: final loss non-finite after the "
-              "demotion — the rollback did not restore a trainable "
-              "state", file=sys.stderr)
-        return 1
-    if not within:
-        print("[chaos_smoke] FAIL: the demotion landed outside the "
-              "probation window", file=sys.stderr)
-        return 1
-    if not (ev_dem["restored"] and ev_dem["bit_exact"]):
-        print("[chaos_smoke] FAIL: demotion did not restore the "
-              "last-known-good checkpoint bit-exactly", file=sys.stderr)
-        return 1
-    if not ordering_ok:
-        print("[chaos_smoke] FAIL: the artifact's retune event ordering "
-              "violates the transaction sequence", file=sys.stderr)
         return 1
     print("[chaos_smoke] OK")
     return 0

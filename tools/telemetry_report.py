@@ -189,20 +189,14 @@ def render(provenance, records, events,
     lint = [e for e in events if e.get("event") == "lint_finding"]
     adapt = [e for e in events
              if str(e.get("event", "")).startswith("adapt")]
-    retune = [e for e in events
-              if str(e.get("event", "")).startswith("retune")]
     other = [e for e in events
              if e not in perf and e not in watch and e not in lint
-             and e not in adapt and e not in retune]
+             and e not in adapt]
     if adapt or any("adapt_rung" in r and float(r["adapt_rung"]) >= 0
                     for r in records):
         out.append("")
         out.append("== adapt (graft-adapt rung transitions) ==")
         out.extend(_render_adapt(adapt, records))
-    if retune:
-        out.append("")
-        out.append("== retune (graft-retune config transactions) ==")
-        out.extend(_render_retune(retune))
     if watch:
         out.append("")
         out.append("== watch (graft-watch summaries + anomalies) ==")
@@ -260,41 +254,6 @@ def _render_adapt(adapt: List[dict], records: List[dict]) -> List[str]:
                    f"rung {e.get('from_rung', '?')} -> {e.get('rung', '?')}")
     if not adapt and not rungs:
         out.append("  (controller armed but no rows recorded)")
-    return out
-
-
-def _render_retune(retune: List[dict]) -> List[str]:
-    """graft-retune transaction trail: one line per event, plus a tally
-    of promotions/demotions/timeouts — a demotion inside a probation
-    window is the rollback working, not a failure, and the report says
-    which config survived."""
-    out = []
-    promotes = [e for e in retune if e.get("event") == "retune_promote"]
-    demotes = [e for e in retune if e.get("event") == "retune_demote"]
-    timeouts = [e for e in retune if e.get("event") == "retune_timeout"]
-    aborts = [e for e in retune if e.get("event") == "retune_abort"]
-    out.append(f"  transactions: {len(promotes)} promotion(s), "
-               f"{len(demotes)} demotion(s), {len(aborts)} abort(s), "
-               f"{len(timeouts)} bounded-leg timeout(s)")
-    for e in retune:
-        name = str(e.get("event", "?"))
-        extras = {k: v for k, v in e.items() if k not in ("event", "step")}
-        brief = ", ".join(f"{k}={v}" for k, v in sorted(extras.items())
-                          if isinstance(v, (int, float, bool, str))
-                          and k not in ("reason",))
-        out.append(f"    step {e.get('step', '?'):>6}: {name}"
-                   + (f"  ({brief})" if brief else ""))
-        if e.get("reason"):
-            msg = str(e["reason"])
-            out.append(f"            {msg[:150]}"
-                       + ("…" if len(msg) > 150 else ""))
-    closers = [e for e in retune
-               if e.get("event") in ("retune_promote", "retune_demote")]
-    if closers:
-        last = closers[-1]
-        survivor = (last.get("new") if last["event"] == "retune_promote"
-                    else last.get("config"))
-        out.append(f"  surviving config: {survivor}")
     return out
 
 
@@ -525,15 +484,11 @@ def build_doc(provenance, records, events,
                           if e.get("event") == "lint_finding"],
         "adapt_events": [e for e in events
                          if str(e.get("event", "")).startswith("adapt")],
-        "retune_events": [e for e in events
-                          if str(e.get("event", "")).startswith("retune")],
         "guard_events": [e for e in events
                          if e.get("event") not in ("watch", "watch_anomaly",
                                                    "lint_finding")
                          and not str(e.get("event", "")).startswith("perf_")
-                         and not str(e.get("event", "")).startswith("adapt")
-                         and not str(e.get("event", "")).startswith(
-                             "retune")],
+                         and not str(e.get("event", "")).startswith("adapt")],
     }
     return doc
 
