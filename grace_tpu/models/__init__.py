@@ -36,13 +36,22 @@ checkpoints with orbax alongside them.
                       (attention, the expert walk and the head are
                       ``lfm2``'s, the router ``sdar``'s); the benchmark's
                       ``smallthinker-21b-a3b-ep8`` configuration
+* ``qwen3_next``    — Qwen3-Next causal decoder: gated delta-rule layers
+                      (the recurrence in chunks: products within a chunk,
+                      one scan over the chunks that carries the float32
+                      state), three in four, beside softmax attention with
+                      an output gate at heads of 256 whose first 64 numbers
+                      are rotated; 512 experts ten a token beside a gated
+                      shared expert (attention, the expert walk and the
+                      head are ``lfm2``'s, the router ``sdar``'s); the
+                      benchmark's ``qwen3-next-80b-a3b-ep32`` configuration
 * ``vgg``           — VGG-11/13/16/19 (the communication-bound classic of the
                       reference's synthetic-benchmark model list)
 """
 
-from grace_tpu.models import (deepseek_v3, layers, lenet, lfm2, resnet,
-                              resnet_cifar, sdar, smallthinker, transformer,
-                              vgg)
+from grace_tpu.models import (deepseek_v3, layers, lenet, lfm2, qwen3_next,
+                              resnet, resnet_cifar, sdar, smallthinker,
+                              transformer, vgg)
 
-__all__ = ["deepseek_v3", "layers", "lenet", "lfm2", "resnet", "resnet_cifar",
-           "sdar", "smallthinker", "transformer", "vgg"]
+__all__ = ["deepseek_v3", "layers", "lenet", "lfm2", "qwen3_next", "resnet",
+           "resnet_cifar", "sdar", "smallthinker", "transformer", "vgg"]

@@ -162,13 +162,23 @@ def _angles(t: int, freq: jax.Array, axis: int,
 
 
 def rotary(x: jax.Array, theta: float, positions: jax.Array | None = None,
-           axis: int = -3) -> jax.Array:
-    """Rotary positions over the whole head, rotate-half: ``x`` is
+           axis: int = -3, rotary_dim: int | None = None) -> jax.Array:
+    """Rotary positions over the whole head or, given ``rotary_dim``, over
+    its first ``rotary_dim`` numbers alone (``partial_rotary_factor``: the
+    pairs are ``(j, j + rotary_dim / 2)``, the frequencies those of a head
+    of ``rotary_dim``, and what lies behind passes through as it is),
+    rotate-half: ``x`` is
     ``(..., T, heads, head_dim)`` or, with ``axis=-2``, head-major ``(...,
     heads, T, head_dim)``; along ``axis`` (counted from the end) lie the
     entries of ``positions`` ``(T,)`` or, without them, ``0 .. T - 1``.
     Angles and the rotation are float32, the same numbers in either layout."""
     d = x.shape[-1]
+    if rotary_dim is not None and rotary_dim != d:
+        if not 0 < rotary_dim < d or rotary_dim % 2:
+            raise ValueError(f"{rotary_dim} of a head's {d} numbers are not "
+                             "whole pairs of it")
+        turned = rotary(x[..., :rotary_dim], theta, positions, axis)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = _angles(x.shape[axis], freq, axis, positions)
     cos, sin = jnp.cos(ang), jnp.sin(ang)

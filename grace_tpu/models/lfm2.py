@@ -338,12 +338,17 @@ def _plain_scores(q, k, v, q_block: int, mask=pallas_attention.CAUSAL):
 
 
 def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
-              positions=None, rotate: bool = True):
+              positions=None, rotate: bool = True, rotary_dim=None,
+              gated: bool = False):
     """Grouped-query self-attention of normalised ``u`` under ``mask``
     (causal unless told otherwise), rotated by ``positions`` (``0 .. T -
     1`` unless given) unless ``rotate`` is false (a layer without
-    positions); the query and key heads are normalised where ``p`` holds
-    ``q_norm`` and ``k_norm``. Head-major from product to product: the
+    positions), over the whole head or its first ``rotary_dim`` numbers;
+    the query and key heads are normalised where ``p`` holds ``q_norm``
+    and ``k_norm``. ``gated``: the query projection is twice as wide and
+    carries, a head, its queries and behind them an output gate, and the
+    heads' output is multiplied by the gate's sigmoid (float32) before
+    ``o_proj`` reads it. Head-major from product to product: the
     three projections write ``(n, H, T, D)``, norms and rotation are applied
     there, and ``o_proj`` reads the output where it lies, so that no axis is
     moved between a product and the kernel. On a TPU, a sequence of whole
@@ -368,6 +373,8 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
         else:
             q_norm = {"scale": q_norm["scale"] * scale}
     q = _heads_of(u, q_proj, hq)
+    if gated:
+        q, gate = jnp.split(q, 2, axis=-1)
     k = _heads_of(u, p["k_proj"], hkv)
     v = _heads_of(u, p["v_proj"], hkv)
 
@@ -376,7 +383,8 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
             heads = L.rms_apply(norm, heads, cfg.norm_eps)
         if not rotate:
             return heads
-        return L.rotary(heads, cfg.rope_theta, positions, axis=-2)
+        return L.rotary(heads, cfg.rope_theta, positions, axis=-2,
+                        rotary_dim=rotary_dim)
 
     q, k = placed(q, q_norm), placed(k, p.get("k_norm"))
     if fused:
@@ -388,6 +396,8 @@ def attention(p, u, cfg: Config, mask=pallas_attention.CAUSAL,
             out = pallas_attention.masked_gqa(q, k, v, mask)
     else:
         out = _plain_scores(q, k, v, cfg.attn_q_block, mask)
+    if gated:
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     return _from_heads(out, p["o_proj"])
 
 

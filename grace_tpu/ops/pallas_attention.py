@@ -89,12 +89,14 @@ nhtk")`` over the weight viewed ``(d, H, D)``) and their output projection
 reads the result where the kernel left it (``einsum("nhtk,hkd->ntd")``), so
 what stands between a product and the kernel is norms and rotations alone.
 
-Three pairs of head sizes are taken (:data:`HEAD_DIMS`, queries and keys |
+Four pairs of head sizes are taken (:data:`HEAD_DIMS`, queries and keys |
 values): ``(64, 64)``, grouped-query attention as ``models/lfm2.py`` has
 it, ``(192, 128)``, latent attention as ``models/deepseek_v3.py`` has it
-(a 128-wide part without positions beside a 64-wide rotary part), and
+(a 128-wide part without positions beside a 64-wide rotary part),
 ``(128, 128)``, grouped-query attention as ``models/sdar.py`` and
-``models/smallthinker.py`` have it. The
+``models/smallthinker.py`` have it, and ``(256, 256)``, as
+``models/qwen3_next.py``'s full layers have it (two tiles of 128 lanes a
+head; the same tiles of positions). The
 tile sizes were chosen by chip runs on a TPU v5e at ``(1, 4096, 32 | 8,
 64)`` bfloat16, where JAX's other kernel, ``flash_attention``, read 1.6
 times this one's time (PERF.md §6, PR 31), and read again at ``(1, 4096,
@@ -105,7 +107,8 @@ are (a tile and a half of 128): no zero padding to 256.
 is given as ``q`` is what it multiplies into the keys, so ``softmax(q k^T +
 mask) v`` is what comes back. Each caller scales ``q`` where that rounds
 nothing its plain spelling does not round: ``models/lfm2.py`` multiplies
-``q`` by ``1 / sqrt(64)`` in ``q``'s dtype (a power of two: exact) and, at
+``q`` by ``1 / sqrt(64)`` (and, for ``models/qwen3_next.py``'s heads, ``1
+/ sqrt(256)``) in ``q``'s dtype (a power of two: exact) and, at
 a head size whose root is none (128), folds the scale into the float32
 weight of the queries' norm (``models/sdar.py``) or, where the heads have
 no norm (``models/smallthinker.py``), into the query projection's weights
@@ -155,7 +158,7 @@ BLOCK_KV_COMPUTE = 512
 # A sequence is whole tiles of both kinds.
 TILE = math.lcm(BLOCK_Q, BLOCK_KV)
 # (queries and keys, values)
-HEAD_DIMS = ((64, 64), (192, 128), (128, 128))
+HEAD_DIMS = ((64, 64), (192, 128), (128, 128), (256, 256))
 DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
 # What the forward rule calls its output and its log-sum-exp: a
 # `jax.checkpoint` policy that saves this name spares the backward pass the

@@ -34,7 +34,8 @@ __all__ = ["trace_stage", "match_stage", "ALL_STAGES",
            "STAGE_DENSE_FFN", "STAGE_MOE_ROUTER", "STAGE_MOE_DISPATCH",
            "STAGE_MOE_EXPERTS", "STAGE_MOE_COMBINE", "STAGE_LM_HEAD",
            "STAGE_MLA_LATENT", "STAGE_SHARED_EXPERT", "STAGE_DIFFUSION_NOISE",
-           "STAGE_WINDOW_ATTENTION", "MODEL_STAGES"]
+           "STAGE_WINDOW_ATTENTION", "STAGE_GATED_DELTA", "STAGE_DELTA_RULE",
+           "MODEL_STAGES"]
 
 # Canonical stage names — one vocabulary for the profiler, the report tool,
 # and the docs. Keep in sync with README "Observability".
@@ -117,11 +118,22 @@ STAGE_DIFFUSION_NOISE = "grace/diffusion_noise"
 # A layer of the same model that reads the whole prefix stands under
 # STAGE_ATTENTION, so a trace tells the two kinds apart.
 STAGE_WINDOW_ATTENTION = "grace/window_attention"
+# A gated delta-rule layer (models/qwen3_next.py), in two stages. The
+# operator around the rule: its projections, the causal depthwise
+# convolution with its SiLU, the heads' l2 norms, the gates' ``beta`` and
+# ``g``, the norm gated by ``silu(z)`` and the output product. And, nested
+# inside, the rule itself alone, forward, recomputed and backward: the
+# recurrence ``S <- exp(g) S + k (beta (v - S^T k))^T``, ``o = S^T q`` in its
+# chunked form (the products within a chunk, the unit-lower-triangular
+# inverse, the scan over chunks that carries the state), so that a trace
+# says what the scan costs apart from the products around it.
+STAGE_GATED_DELTA = "grace/gated_delta"
+STAGE_DELTA_RULE = "grace/delta_rule"
 MODEL_STAGES = (STAGE_ATTENTION, STAGE_SHORT_CONV, STAGE_DENSE_FFN,
                 STAGE_MOE_ROUTER, STAGE_MOE_DISPATCH, STAGE_MOE_EXPERTS,
                 STAGE_MOE_COMBINE, STAGE_LM_HEAD, STAGE_MLA_LATENT,
                 STAGE_SHARED_EXPERT, STAGE_DIFFUSION_NOISE,
-                STAGE_WINDOW_ATTENTION)
+                STAGE_WINDOW_ATTENTION, STAGE_GATED_DELTA, STAGE_DELTA_RULE)
 
 # The canonical stage vocabulary, longest-prefix-matchable: the profiler,
 # tools/telemetry_report.py, and the static auditor's finding attribution

@@ -1,10 +1,10 @@
-"""The static decisions the seven compiled steps depend on, at the cells'
-own shapes.
+"""The static decisions the nine cells' compiled steps depend on, at the
+cells' own shapes.
 
 Every route the transform takes for a leaf follows from static facts: the
 cell's ``grace`` parameters, the leaf's shape, the world size. ``PERF.md``
 states them in prose ("no ResNet-50 leaf takes the row-slices route at W=1",
-"27 of LFM2's 50 leaves do", "32 of kanana's 69", "22 of SDAR's 51"); here they are assertions, on parameter trees
+"27 of LFM2's 50 leaves do", "32 of kanana's 69", "22 of SDAR's 51", "22 of Qwen3-Next's 70"); here they are assertions, on parameter trees
 taken with ``jax.eval_shape`` from the benchmark's own builders at the sizes
 in ``benchmarks/configs/*.json`` (read, never edited; no weight is made).
 The expected values are written down, not computed by the code under test:
@@ -55,6 +55,9 @@ CELLS = {
                                                 "TopKCompressor",
                                                 "ResidualMemory",
                                                 "Allgather"),
+    "qwen3-next-80b-a3b-gdn16k-topk1pct-w1": ("qwen3-next-80b-a3b-ep32",
+                                              "TopKCompressor",
+                                              "ResidualMemory", "Allgather"),
 }
 
 # configuration -> (leaves, parameters, leaves on the row-slices route under
@@ -66,6 +69,7 @@ CONFIGS = {
     "kanana-2-30b-a3b-ep16": (69, 424_960_512, 32, 33_996_704),
     "sdar-30b-a3b-ep8": (51, 456_346_624, 22, 36_507_584),
     "smallthinker-21b-a3b-ep8": (43, 370_547_200, 22, 29_643_640),
+    "qwen3-next-80b-a3b-ep32": (70, 424_340_544, 22, 33_947_040),
 }
 
 # Top-k 1 % chunk, per distinct leaf shape:
@@ -163,6 +167,27 @@ TOPK_LEAVES = {
         ((8, 768, 2560), 4, 15728640, 157286, 101, True),
         ((8, 2560, 768), 8, 15728640, 157286, 101, True),
     ],
+    "qwen3-next-80b-a3b-ep32": [
+        # a value head's A_log and dt_bias: one entry of 32 kept a step
+        ((32,), 6, 32, 1, 32, False),
+        ((128,), 3, 128, 1, 128, False),            # the gated norm
+        ((256,), 2, 256, 2, 128, False),            # the full layer's q, k norms
+        ((2048,), 9, 2048, 20, 103, False),
+        ((4, 8192), 3, 32768, 327, 101, False),             # the convolution
+        ((2048, 1), 4, 2048, 20, 103, False),       # the shared expert's gate
+        ((2048, 64), 3, 131072, 1310, 101, False),          # W_ba
+        # the router, W_k, W_v and the shared expert's w1, w3
+        ((2048, 512), 14, 1048576, 10485, 101, False),
+        ((512, 2048), 4, 1048576, 10485, 101, False),       # its w2
+        ((2048, 8192), 1, 16777216, 167772, 101, True),     # W_q with its gate
+        ((2048, 12288), 3, 25165824, 251658, 101, True),    # W_qkvz
+        ((4096, 2048), 4, 8388608, 83886, 101, True),       # W_out, W_o
+        ((2048, 18992), 1, 38895616, 388956, 101, True),
+        ((18992, 2048), 1, 38895616, 388956, 101, True),
+        # sixteen experts a stack of width 512: 2**24 elements, W_q's
+        ((16, 512, 2048), 4, 16777216, 167772, 101, True),
+        ((16, 2048, 512), 8, 16777216, 167772, 101, True),
+    ],
 }
 
 # PowerSGD rank 4 on BERT-base, per distinct leaf shape: (shape, leaves of
@@ -176,6 +201,8 @@ CODEC_CELL = {"resnet50-imagenet": "resnet50-topk1pct-w1",
               "sdar-30b-a3b-ep8": "sdar-30b-a3b-blockdiff-topk1pct-w1",
               "smallthinker-21b-a3b-ep8":
                   "smallthinker-21b-a3b-swa16k-topk1pct-w1",
+              "qwen3-next-80b-a3b-ep32":
+                  "qwen3-next-80b-a3b-gdn16k-topk1pct-w1",
               "bert-base-squad": "bert-base-powersgd4-w1"}
 
 POWERSGD_LEAVES = [

@@ -632,4 +632,4 @@ def test_every_part_of_the_step_is_under_its_stage(trained):
     assert scopes.match_stage(
         "grace/forward_backward/jvp(grace/shared_expert)/dot") \
         == scopes.STAGE_SHARED_EXPERT
-    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 28
+    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 30

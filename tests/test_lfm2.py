@@ -812,9 +812,10 @@ def test_wire_report_reads_the_expert_stacks(trained):
 
 def test_every_part_of_the_step_is_under_its_stage(trained):
     text = trained["text"]
-    # the other decoders' four are not in this step
+    # the other decoders' six are not in this step
     others = (scopes.STAGE_MLA_LATENT, scopes.STAGE_SHARED_EXPERT,
-              scopes.STAGE_DIFFUSION_NOISE, scopes.STAGE_WINDOW_ATTENTION)
+              scopes.STAGE_DIFFUSION_NOISE, scopes.STAGE_WINDOW_ATTENTION,
+              scopes.STAGE_GATED_DELTA, scopes.STAGE_DELTA_RULE)
     assert all(stage not in text for stage in others)
     for stage in set(scopes.MODEL_STAGES) - set(others):
         assert stage in text, stage
@@ -828,4 +829,4 @@ def test_every_part_of_the_step_is_under_its_stage(trained):
     assert scopes.match_stage(
         "grace/forward_backward/jvp(grace/short_conv)/dot") \
         == scopes.STAGE_SHORT_CONV
-    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 28
+    assert len(set(scopes.ALL_STAGES)) == len(scopes.ALL_STAGES) == 30

@@ -41,6 +41,7 @@ T = 2 * TILE
 D = 64
 GQA, MLA = (64, 64), (192, 128)       # queries and keys | values
 SDAR = (128, 128)
+WIDE = (256, 256)                    # models/qwen3_next.py's full layers
 HKV = 2
 
 
@@ -99,8 +100,9 @@ def _gap(a, b):
 TOLERANCE = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
-@pytest.mark.parametrize("dims, group", [(GQA, 1), (GQA, 4), (MLA, 1)],
-                         ids=["mha", "gqa4", "mla192-128"])
+@pytest.mark.parametrize("dims, group", [(GQA, 1), (GQA, 4), (MLA, 1),
+                                         (WIDE, 2)],
+                         ids=["mha", "gqa4", "mla192-128", "gqa2-256"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 def test_kernel_agrees_with_the_plain_spelling(dtype, dims, group):
@@ -383,10 +385,15 @@ def test_a_mask_over_another_length_is_refused():
     (8192, SDAR, jnp.bfloat16, "cpu", False),
     (4096, (128, 64), jnp.bfloat16, "tpu", False),    # no pair of the three
     (32, (8, 8), jnp.float32, "tpu", False),          # sdar.tiny()
+    (16384, WIDE, jnp.bfloat16, "tpu", True),         # heads of 256 (PR 49)
+    (16384, WIDE, jnp.bfloat16, "cpu", False),
+    (4096, (256, 128), jnp.bfloat16, "tpu", False),   # no pair of the four
+    (32, (16, 16), jnp.float32, "tpu", False),        # qwen3_next.tiny()
 ], ids=["lfm2-cell", "one-tile-f32", "kanana-cell", "mla-one-tile-f32", "cpu",
         "here", "part-tile", "mla-part-tile", "tiny", "mla-tiny", "head8",
         "192-192", "128-128", "64-128", "float16", "sdar-cell", "sdar-cpu",
-        "128-64", "sdar-tiny"])
+        "128-64", "sdar-tiny", "qwen3-next-cell", "qwen3-next-cpu", "256-128",
+        "qwen3-next-tiny"])
 def test_who_takes_the_kernel(seq_len, dims, dtype, platform, taken):
     assert engages(seq_len, *dims, dtype, platform) is taken
 
@@ -865,6 +872,48 @@ def test_attention_without_head_norms_takes_the_kernel_under_its_mask(
     other = jax.jit(lambda p, u: jnp.sum(lfm2.attention(
         p, u, cfg, mask, rotate=not rotate) ** 2))(p, u)
     assert abs(float(other) - float(want)) > 1e-3 * abs(float(want))
+
+
+def test_gated_attention_at_heads_of_256_takes_the_kernel(monkeypatch):
+    """``lfm2.attention`` of a Qwen3-Next-shaped full layer (heads of 256,
+    zero-centred norms on the heads, the first 64 numbers rotated, the
+    query projection carrying the output gate): with ``engages`` answering
+    as on a TPU and the kernel interpreted it agrees with the plain path,
+    values and parameter gradients; the kernel is handed the queries alone
+    (256 wide, not the gate's 256 behind them), scaled by 1/16 exactly."""
+    from grace_tpu.models import qwen3_next
+    cfg = qwen3_next.tiny(head_dim=256, rotary_dim=64, num_attention_heads=2,
+                          num_key_value_heads=1, attn_q_block=512)
+    p = qwen3_next.init(jax.random.key(4), cfg)[0]["layers"][3]["op"]
+    p = jax.tree_util.tree_map(lambda x: x * 8 if x.ndim == 2 else x + 1.0, p)
+    assert p["q_proj"].shape == (cfg.hidden_size, 2 * 2 * 256)
+    u = jax.random.normal(jax.random.key(5), (1, TILE, cfg.hidden_size))
+
+    def loss(p, u):
+        return jnp.sum(lfm2.attention(p, u, cfg, rotary_dim=64,
+                                      gated=True) ** 2)
+
+    want, want_grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    calls = []
+
+    def interpreted(q, k, v):
+        calls.append((q.shape, k.shape))
+        return causal_gqa(q, k, v, interpret=True)
+
+    monkeypatch.setattr(pallas_attention, "engages",
+                        functools.partial(engages, platform="tpu"))
+    monkeypatch.setattr(pallas_attention, "causal_gqa", interpreted)
+    got, grads = jax.jit(jax.value_and_grad(loss))(p, u)
+    assert calls == [((1, 2, TILE, 256), (1, 1, TILE, 256))]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(grads),
+                                   jax.tree_util.tree_leaves(want_grads)):
+        assert _gap(got_leaf, want_leaf) < 2e-5
+    # the gate is part of the result: without it the layer is another one
+    ungated = jax.jit(lambda p, u: jnp.sum(lfm2.attention(
+        dict(p, q_proj=p["q_proj"].reshape(-1, 2, 2, 256)[:, :, 0].reshape(
+            -1, 512)), u, cfg, rotary_dim=64) ** 2))(p, u)
+    assert abs(float(ungated) - float(want)) > 1e-3 * abs(float(want))
 
 
 # ---------------------------------------------------------------------------

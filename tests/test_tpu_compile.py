@@ -37,7 +37,7 @@ import grace_tpu.ops
 from grace_tpu import grace_from_params
 from grace_tpu.compressors.topk import static_k
 from grace_tpu.memories import ResidualMemory
-from grace_tpu.models import (deepseek_v3, lfm2, resnet, sdar,
+from grace_tpu.models import (deepseek_v3, lfm2, qwen3_next, resnet, sdar,
                               smallthinker)
 from grace_tpu.ops import pallas_attention, sparse
 from grace_tpu.ops.pallas_quant import (quantize_pack_stochastic,
@@ -55,6 +55,7 @@ if REPO not in sys.path:        # benchmarks/: the LFM2 configuration's file
 from benchmarks import harness  # noqa: E402
 from benchmarks.models import deepseek_v3 as kanana  # noqa: E402
 from benchmarks.models import lfm2_moe  # noqa: E402
+from benchmarks.models import qwen3_next as qwen3_next_moe  # noqa: E402
 from benchmarks.models import sdar_moe  # noqa: E402
 from benchmarks.models import smallthinker_moe  # noqa: E402
 from benchmarks.reference import train as plain_train  # noqa: E402
@@ -1077,6 +1078,117 @@ def test_the_smallthinker_step_compiles_for_the_described_chip(
     print("smallthinker step holds", held)
     assert held + 370_547_200 * 4 < 16.91e9
     assert held > 0.25 * 16e9
+
+
+# ---------------------------------------------------------------------------
+# the two operators of the Qwen3-Next cell (PR 49), a part each
+# ---------------------------------------------------------------------------
+
+def _qwen3_next_part_text(one_chip, layer):
+    """The operator of layer ``layer`` of the benchmark's Qwen3-Next
+    configuration on one sequence of 16,384 positions: layer 0 a gated
+    delta layer (16 key and 32 value heads of 128), layer 3 full attention
+    (16 query heads over 2 key/value heads of 256, an output gate)."""
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "qwen3-next-80b-a3b-ep32.json")) as f:
+        sizes = json.load(f)
+    cfg = qwen3_next_moe.model_config(sizes)
+    assert (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+            cfg.rotary_dim, sizes["seq_length"]) == (16, 2, 256, 64, 16384)
+    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.delta_chunk) == (16, 32, 128, 64)
+    assert cfg.layer_types[layer] == ("full_attention" if layer == 3
+                                      else "linear_attention")
+    shapes = jax.eval_shape(lambda k: qwen3_next.init(k, cfg)[0],
+                            jax.random.key(0))
+    return _part_text(qwen3_next._operator_part(cfg.layer_types[layer], cfg),
+                      shapes["layers"][layer], cfg, one_chip,
+                      positions=sizes["seq_length"])
+
+
+def test_gated_attention_compiles_to_the_fused_kernel_at_heads_of_256(
+        one_chip, monkeypatch):
+    """Mosaic takes the kernel at heads of 256 | 256 in the accepted tiles
+    (1,024 queries, 1,024 keys copied, 512 multiplied at once), forward and
+    the fused backward, each under ``grace/attention``; the query projection
+    is 8,192 wide (queries and gate), the kernel reads 16 heads of 256 and
+    the gate multiplies what it wrote; no block of float32 scores is left."""
+    _as_on_the_chip(monkeypatch)
+    text = _qwen3_next_part_text(one_chip, 3)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == KEPT
+    assert all("grace/attention" in name for name in op_names), op_names
+    assert "bf16[16,16384,256]" in text and "bf16[2,16384,256]" in text
+    assert "f32[2048,8192]" in text                     # d W_q, gate and all
+    assert not re.search(r"f32\[(?:1,)?(?:16|2,8),1024,\d{4,5}\]", text)
+    assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
+
+
+def test_gated_attention_still_compiles_without_the_kernel(one_chip):
+    """The fallback (here: a CPU process asks ``engages``): blocks of 1,024
+    queries scored by XLA, no Pallas call."""
+    text = _qwen3_next_part_text(one_chip, 3)
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert re.search(r"f32\[(?:1,)?2,8,1024,\d{4,5}\]", text)
+
+
+def test_the_gated_delta_part_compiles_for_the_described_chip(one_chip):
+    """One gated delta layer's operator on a sequence of 16,384 positions,
+    forward, recomputation and backward: no Pallas call (plain XLA today),
+    the rule's operations under ``grace/delta_rule`` and the operator's
+    under ``grace/gated_delta``, the state carried in float32 (16 key heads
+    x 2 value heads each x 128 x 128), chunks of 64 (a chunk's float32
+    triangular system is 64 x 64), loops and not unrolled chunks (a span of
+    2,048 positions is 32 chunks, a sequence 8 spans), and nothing of
+    16,384 x 16,384 or of a state a token."""
+    text = _qwen3_next_part_text(one_chip, 0)
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    stages = {stage_of(name) for name in re.findall(r'op_name="([^"]*)"',
+                                                    text)}
+    assert {"grace/gated_delta", "grace/delta_rule"} <= stages
+    assert "grace/attention" not in stages
+    assert re.search(r"f32\[1,16,2,128,128\]", text)          # the state
+    assert re.search(r"f32\[1,16,2,32,64,64\]", text)         # a span's T
+    assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
+    assert not re.search(r"\[(?:\d+,)*16384,(?:\d+,)*128,128\]", text)
+    # the chunk scans and the span scans, forward, recomputed and backward:
+    # loops, a handful, whatever the length
+    loops = len(re.findall(r"\bwhile\(", text))
+    assert 4 <= loops <= 16, loops
+
+
+# Marked slow (outside tier-1, as the two causal cells' below): the whole
+# step takes 90 s to compile alone, beside the SDAR and SmallThinker steps
+# this file's worker already carries inside tier-1's time limit; the two
+# parts above are the cell's tier-1 compiles.
+@pytest.mark.slow
+def test_the_qwen3_next_step_compiles_for_the_described_chip(
+        topo, kernels_on, monkeypatch):
+    """The whole step of ``qwen3-next-80b-a3b-gdn16k-topk1pct-w1`` from the
+    CPU: the fused kernel twice (the one full layer's forward and fused
+    backward at heads of 256 | 256 over 16,384 positions, under
+    ``grace/attention``), the three gated delta layers in plain XLA under
+    their two stages, the shared expert and the router under theirs; no
+    block of float32 scores, nothing of 16,384 x 16,384, no state a token;
+    and the step leaves room on the chip for the harness's copy of the
+    start parameters (1.70 GB) under the runtime's 16.91 GB, and holds over
+    three quarters of the chip (13,916,858,880 B when this was written)."""
+    text, held = _whole_step("qwen3-next-80b-a3b-gdn16k-topk1pct-w1", topo,
+                             kernels_on, monkeypatch)
+    kernels, op_names = _kernel_calls(text)
+    assert kernels == KEPT
+    assert all("grace/attention" in name for name in op_names), op_names
+    assert "bf16[16,16384,256]" in text and "bf16[2,16384,256]" in text
+    for stage in ("grace/gated_delta", "grace/delta_rule",
+                  "grace/shared_expert", "grace/moe_router"):
+        assert stage in text, stage
+    assert "grace/window_attention" not in text
+    assert not re.search(r"\[(?:\d+,)*16384,16384\]", text)
+    assert not re.search(r"\[(?:\d+,)*16384,(?:\d+,)*128,128\]", text)
+    assert not re.search(r"f32\[(?:1,)?(?:16|2,8),1024,\d{4,5}\]", text)
+    print("qwen3-next step holds", held)
+    assert held + 424_340_544 * 4 < 16.91e9
+    assert held > 0.75 * 16e9
 
 
 # (kernel calls, bytes the compiled step holds) of the two causal decoder
